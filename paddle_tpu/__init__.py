@@ -55,4 +55,33 @@ def init(**kwargs):
     return _flags.FLAGS
 
 
+def compile_cache():
+    """Place JAX's persistent compilation cache for a process that will
+    compile for the device (the CLI, chip_smoke.py's children, bench.py,
+    the tools that time on the chip). Returns the directory in use, or
+    None when there is none.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, so this sets
+    nothing — no code in the repo names another directory. Unset: one
+    fixed directory inside the checkout (the path is part of the cache
+    key, so it must not move between runs). A process held to the CPU
+    (``JAX_PLATFORMS=cpu``: the test suite and its children) gets no
+    persistent cache, so that tier-1 never depends on what a directory
+    happens to hold."""
+    import os
+
+    import jax
+
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    if jax.config.jax_platforms == "cpu":
+        return None
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 batch = reader.minibatch_batch

@@ -642,6 +642,9 @@ def main(argv=None):
     from paddle_tpu.distributed import faults as _faults
 
     _faults.install_from_env()
+    import paddle_tpu
+
+    paddle_tpu.compile_cache()
     argv = list(sys.argv[1:]) if argv is None else list(argv)
     if argv[:1] == ["cluster_train"]:
         # forwarded verbatim: the launcher owns its own flags and the

@@ -6,7 +6,8 @@ VMEM-resident across the scan, each timestep costs two MXU matmuls +
 VPU gate math, and the backward kernel walks the grid in reverse
 accumulating dWg/dWc/db in VMEM scratch. The lax.scan formulation
 re-reads both weight matrices from HBM every tick and pays the scan's
-dynamic-slice machinery — profiled on the NMT encoder (PERF_r04.md).
+dynamic-slice machinery — profiled on the NMT encoder (r4, not
+re-measured).
 
 Cell semantics match layers/recurrent.py gru_cell exactly (reference
 GruCompute / GruLayer): gates [z, r] from x[:, :2H] + h@Wg, candidate

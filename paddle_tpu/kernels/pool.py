@@ -2,7 +2,7 @@
 
 XLA lowers max-pool backward to `select-and-scatter`, which on the bench
 chip runs at ~500 GB/s (vs ~700 for the surrounding fusions) and re-reads
-the pooled output — 1.7 ms of the ResNet-50 step (PERF_r04.md). The
+the pooled output — 1.7 ms of the ResNet-50 step (r4, not re-measured). The
 reference hand-writes the same kernel in CUDA for the same reason
 (paddle/cuda/src/hl_cuda_cnn.cu hl_maxpool_backward: each input position
 sums `outGrad * (in == out)` over the <=4 windows containing it). This is

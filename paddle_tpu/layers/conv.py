@@ -319,7 +319,8 @@ def _deconv3d(cfg, params, ins, ctx):
 # --- pooling --------------------------------------------------------------
 
 # max-pool backward implementation switch: "sas" = XLA select-and-
-# scatter (default; 61% of peak HBM BW on the ResNet stem, PERF_r04);
+# scatter (default; 61% of peak HBM BW on the ResNet stem — r4, not
+# re-measured);
 # "eq" = equality-based backward — grad_x[p] = sum over covering
 # windows of (x[p] == y[w]) * g[w], expressed as K*K dilated-pad
 # shifted views so XLA can fuse the whole thing into the adjacent
@@ -327,7 +328,7 @@ def _deconv3d(cfg, params, ins, ctx):
 # window cotangent — which DIVERGES from select-and-scatter (one winner)
 # on tied inputs, and post-ReLU feature maps tie at 0.0 constantly, so
 # this is NOT a drop-in for training; it lost the r5 A/B anyway
-# (BENCH_EXTRA_r05.md: 139.9 vs 96.3 ms/step — XLA does not fuse the
+# (139.9 vs 96.3 ms/step; r5, not re-measured — XLA does not fuse the
 # k*k shifted passes) and stays an opt-in documented experiment.
 MAXPOOL_BWD = "sas"
 
@@ -430,7 +431,7 @@ def _pool(cfg, params, ins, ctx):
         # semantics) but is NOT wired in: on this chip Mosaic rejects
         # bf16 compares in split layouts, and the forced f32 whole-image
         # working set (78MB VMEM stack) made it 14x slower than XLA's
-        # select-and-scatter (PERF_r04.md, negative result). An
+        # select-and-scatter (negative result; r4, not re-measured). An
         # equality-based fusable backward (MAXPOOL_BWD="eq") is the r5
         # experiment on the same op — see _maxpool_eq_bwd.
         if MAXPOOL_BWD == "eq":

@@ -122,7 +122,7 @@ def _xent_forward(cfg, params, ins, ctx):
         return Arg(cost[:, None])
     # gather FIRST, then upcast/clip/log on the [B(,T)] gathered vector —
     # upcasting the whole [B,T,V] prob tensor materialises a V-sized f32
-    # array (at V=30k that is a 921MB HBM pass per step; PERF_r04.md)
+    # array (at V=30k that is a 921MB HBM pass per step; r4 profile)
     p_lab = jnp.take_along_axis(probs.value, ids[..., None], axis=-1)[..., 0]
     nll = -jnp.log(jnp.clip(_f32up(p_lab), 1e-10, 1.0))
     cost = _reduce_seq(nll, probs.mask)

@@ -140,8 +140,8 @@ def _selfc_params(cfg, in_infos):
 # Two crossover regimes, both measured end-to-end (train-step harness):
 # - PLAIN autodiff (no sparse_update / plain jax.grad): the gather
 #   path's dW is a dense [C, D] zero-init + scatter-add and loses to the
-#   dense mask through C=1M (r5: 36.3 vs 10.9 ms at 1M,
-#   BENCH_EXTRA_r05.md) — conservative crossover stays 2M.
+#   dense mask through C=1M (36.3 vs 10.9 ms at 1M; r5, not
+#   re-measured) — conservative crossover stays 2M.
 # - SPARSE dW (weight has sparse_update=True and the step runs through
 #   make_train_step's tangent-slot protocol): dW is a (rows, values)
 #   SparseRowGrad applied per-row by the optimizer — no [C, D] buffer
@@ -166,7 +166,7 @@ def _selective_fc(cfg, params, ins, ctx):
     (id list, -1 padded) are kept — non-selected outputs are masked to
     -inf (softmax) / 0.
 
-    Two paths, crossover measured on the chip (BENCH_EXTRA_r04.md): the
+    Two paths, crossover measured on a v5e (r4, not re-measured): the
     dense matmul + mask wins through ~100k outputs (the MXU eats the
     matmul; masking is one fused elementwise), while at NCE/hsigmoid-
     scale vocabs (>=256k) the reference's reason for existing kicks in —

@@ -170,6 +170,23 @@ def _default_gru_acts(cfg):
             and cfg.attr("active_gate_type", "sigmoid") == "sigmoid")
 
 
+def _take_fused(cfg, kernel, default_acts, supported, B, n):
+    """Whether this recurrent layer runs its fused Pallas kernel
+    (kernels/_pallas_util.take_pallas decides and logs). ``supported`` is
+    the kernel's own gate, asked about the batch one shard sees."""
+    from paddle_tpu.kernels._pallas_util import batch_shards, take_pallas
+
+    if not default_acts:
+        return take_pallas(cfg.name, kernel, False,
+                           "the kernel hardcodes sigmoid/tanh")
+    shards = batch_shards()
+    if B % shards or not supported(B // shards, n):
+        return take_pallas(cfg.name, kernel, False,
+                           f"B={B} over {shards} shard(s), H={n}: outside "
+                           "the kernel's gate (B%8, H%128, VMEM estimate)")
+    return take_pallas(cfg.name, kernel)
+
+
 @register_layer("lstmemory", infer=_lstm_infer, params=_lstm_params)
 def _lstmemory(cfg, params, ins, ctx):
     a = ins[0]
@@ -185,11 +202,12 @@ def _lstmemory(cfg, params, ins, ctx):
     # fused Pallas path (hl_gpu_lstm.cuh analog): one kernel for the whole
     # recurrence with W resident in VMEM — the scan path refetches W from
     # HBM every timestep and is bandwidth-bound
+    from paddle_tpu.kernels._pallas_util import call_kernel
     from paddle_tpu.kernels.lstm import fused_lstm, fused_lstm_supported
 
     reset = _packed_resets(a, ctx, reverse)
-    if (_default_lstm_acts(cfg) and fused_lstm_supported(B, n)
-            and jax.default_backend() == "tpu"):
+    if _take_fused(cfg, "fused_lstm", _default_lstm_acts(cfg),
+                   fused_lstm_supported, B, n):
         x4 = a.value
         mask = a.mask if a.mask is not None else \
             jnp.ones(x4.shape[:2], jnp.float32)
@@ -202,7 +220,8 @@ def _lstmemory(cfg, params, ins, ctx):
             if reset is not None:
                 reset = jnp.flip(reset, axis=1)
         b7 = bias if bias is not None else jnp.zeros((7 * n,), x4.dtype)
-        hs_b, cs_b = fused_lstm(x4, W, b7, mask, reset)
+        hs_b, cs_b = call_kernel(fused_lstm, (x4, W, b7, mask, reset),
+                                 batch_argnums=(0, 3, 4))
         if reverse:
             hs_b = jnp.flip(hs_b, axis=1)
             cs_b = jnp.flip(cs_b, axis=1)
@@ -296,12 +315,13 @@ def _gated_recurrent(cfg, params, ins, ctx):
 
     # fused Pallas path (kernels/gru.py; same design as the LSTM kernel):
     # default activations only — the kernel hardcodes sigmoid/tanh
+    from paddle_tpu.kernels._pallas_util import call_kernel
     from paddle_tpu.kernels.gru import fused_gru, fused_gru_supported
 
     B = a.value.shape[0]
     reset = _packed_resets(a, ctx, reverse)
-    if (_default_gru_acts(cfg) and fused_gru_supported(B, n)
-            and jax.default_backend() == "tpu"):
+    if _take_fused(cfg, "fused_gru", _default_gru_acts(cfg),
+                   fused_gru_supported, B, n):
         x3 = a.value
         mask = a.mask if a.mask is not None else \
             jnp.ones(x3.shape[:2], jnp.float32)
@@ -311,7 +331,8 @@ def _gated_recurrent(cfg, params, ins, ctx):
             if reset is not None:
                 reset = jnp.flip(reset, axis=1)
         b3 = bias if bias is not None else jnp.zeros((3 * n,), x3.dtype)
-        hs = fused_gru(x3, Wg, Wc, b3, mask, reset)
+        hs = call_kernel(fused_gru, (x3, Wg, Wc, b3, mask, reset),
+                         batch_argnums=(0, 4, 5))
         if reverse:
             hs = jnp.flip(hs, axis=1)
         if a.mask is not None:

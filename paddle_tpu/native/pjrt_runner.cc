@@ -9,7 +9,7 @@
 // plugin-less fallback.
 //
 // Build: make pjrt  (header-only dependency: xla/pjrt/c/pjrt_c_api.h,
-// located via the installed tensorflow include tree; see Makefile).
+// vendored under third_party/; see Makefile).
 //
 // C ABI (ctypes-friendly; declared in capi.h):
 //   ptpu_pjrt_create(plugin_so, mlir_bytes, len)  -> handle | NULL
@@ -475,10 +475,25 @@ int execute_n_impl(Runner* r, int32_t prog_i, const ptpu_pjrt_tensor* args,
       t.rank = int32_t(a.num_dims);
       for (size_t d = 0; d < a.num_dims; ++d) t.dims[d] = a.dims[d];
     }
+    // ask for dense row-major bytes: with no host layout the plugin
+    // hands back the DEVICE's dimension order, and a TPU keeps e.g. an
+    // [8,2] f32 result column-major (seen on a v5e: /v1/infer answered
+    // the transpose of every [rows, classes] output)
+    std::vector<int64_t> minor_to_major(size_t(t.rank));
+    for (int32_t d = 0; d < t.rank; ++d)
+      minor_to_major[size_t(d)] = t.rank - 1 - d;
+    PJRT_Buffer_MemoryLayout row_major;
+    memset(&row_major, 0, sizeof(row_major));
+    row_major.struct_size = PJRT_Buffer_MemoryLayout_STRUCT_SIZE;
+    row_major.type = PJRT_Buffer_MemoryLayout_Type_Tiled;
+    row_major.tiled.struct_size = PJRT_Buffer_MemoryLayout_Tiled_STRUCT_SIZE;
+    row_major.tiled.minor_to_major = minor_to_major.data();
+    row_major.tiled.minor_to_major_size = minor_to_major.size();
     PJRT_Buffer_ToHostBuffer_Args a;
     memset(&a, 0, sizeof(a));
     a.struct_size = PJRT_Buffer_ToHostBuffer_Args_STRUCT_SIZE;
     a.src = outputs[i];
+    a.host_layout = &row_major;
     CHECK_PJRT_RC(api, api->PJRT_Buffer_ToHostBuffer(&a));  // size query
     int64_t needed = int64_t(a.dst_size);
     if (needed > t.size_bytes || t.data == nullptr) {
@@ -489,6 +504,7 @@ int execute_n_impl(Runner* r, int32_t prog_i, const ptpu_pjrt_tensor* args,
     memset(&a, 0, sizeof(a));
     a.struct_size = PJRT_Buffer_ToHostBuffer_Args_STRUCT_SIZE;
     a.src = outputs[i];
+    a.host_layout = &row_major;
     a.dst = t.data;
     a.dst_size = size_t(needed);
     CHECK_PJRT_RC(api, api->PJRT_Buffer_ToHostBuffer(&a));

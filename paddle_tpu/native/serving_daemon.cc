@@ -26,8 +26,8 @@
 //   pjrt    the n-ary PJRT runner (pjrt_runner.cc): compiles the
 //           bundle's exported StableHLO module (signature-driven typed
 //           args/results) on a real PJRT plugin — libtpu.so on a TPU
-//           host. Compiled in when the PJRT C API header is available
-//           (-DPTPU_HAVE_PJRT; make prints the state).
+//           host. Always compiled in (the PJRT C API header is
+//           vendored under third_party/; the build fails without it).
 //   toy     a deterministic built-in decode model (no bundle needed):
 //           every tick runs a real [slots,H]x[H,H] matmul (the fixed
 //           per-tick cost of a compiled decode step, independent of how
@@ -386,14 +386,12 @@ struct Faults {
 
 Faults g_faults;
 
-#ifdef PTPU_HAVE_PJRT
 // PJRT execute — and runner creation during a hot-swap — serialized
 // per PROCESS, not per bundle: during a reload overlap, requests
 // holding the old bundle snapshot and requests on the new one target
 // the same device, and two concurrent executes (or a create racing an
 // execute) is exactly what this mutex has always prevented.
 std::mutex g_pjrt_device_mu;
-#endif
 
 // --- host-resident row store (meta.host_tables) ----------------------------
 //
@@ -1309,7 +1307,6 @@ struct BundleState {
   // sig input names carrying role "host_rows" ([R, D] staged tables —
   // their leading dim is the row budget R, never the batch)
   std::set<std::string> host_row_inputs;
-#ifdef PTPU_HAVE_PJRT
   void* pjrt = nullptr;           // ptpu_pjrt runner handle; all use
                                   // serialized under g_pjrt_device_mu
   std::vector<SigIO> sig_inputs, sig_outputs;
@@ -1325,22 +1322,18 @@ struct BundleState {
   // runner — the infer micro-batcher picks the smallest rung >= the
   // gathered row count and zero-pads up to it
   std::vector<std::pair<int, int>> ladder;
-#endif
 
   ~BundleState() {
     if (engine != nullptr) ptpu_engine_destroy(engine);
-#ifdef PTPU_HAVE_PJRT
     if (pjrt != nullptr) {
       // the drained old engine frees from whichever request thread
       // releases it last — possibly while the new runner executes
       std::lock_guard<std::mutex> l(g_pjrt_device_mu);
       ptpu_pjrt_destroy(pjrt);
     }
-#endif
   }
 };
 
-#ifdef PTPU_HAVE_PJRT
 // Map a decode request's feeds onto a bundle's recorded input specs:
 // every init input needs a per-request row ({"inputs": {...}} form);
 // the legacy {"src": [ids...]} form fills the FIRST i32 sequence feed
@@ -1834,7 +1827,6 @@ struct WholeLoopBackend : DecodeBackend {
 
   bool slot_failed(int slot) override { return fail[size_t(slot)]; }
 };
-#endif  // PTPU_HAVE_PJRT
 
 // One queued /v1/infer request inside a model's micro-batch gather
 // window: parsed typed feeds in, response JSON (or an error + HTTP
@@ -2148,7 +2140,6 @@ struct Daemon {
             merged.obj["host_tables"] = *ht2;
           st->signature_json = json_emit(merged);
         }
-#ifdef PTPU_HAVE_PJRT
         // dims reader: 'b' (the symbolic batch) resolves to `batch`;
         // inputs tagged role "host_rows" are remembered — their leading
         // dim is the staged-row budget R, which pjrt_execute must never
@@ -2194,10 +2185,12 @@ struct Daemon {
           }
           {
             // a reload compiles the new module while the old runner
-            // still serves — creation must not race an execute. NOTE:
-            // whether a TPU plugin allows a second client on a device
-            // the live client holds is plugin-dependent; on-silicon
-            // validation of pjrt hot-swap is a ROADMAP v5e item.
+            // still serves — creation must not race an execute. Whether
+            // a plugin allows a second client on a device the live
+            // client holds is plugin-dependent: libtpu 0.0.34 on a v5e
+            // did (tried once by hand at PR 21: /v1/reload of an infer
+            // bundle answered ok in 3.8 s and the next /v1/infer was
+            // right); it is not part of chip_smoke.py.
             std::lock_guard<std::mutex> l(g_pjrt_device_mu);
             st->pjrt = ptpu_pjrt_create_opts(
                 pjrt_plugin.c_str(), code.data(), int64_t(code.size()),
@@ -2292,18 +2285,6 @@ struct Daemon {
           *err = "bundle has no StableHLO export: " + skip->str;
           return nullptr;
         }
-#else
-      } else if (const JValue* skip = meta->get("stablehlo_skip_reason")) {
-        st->signature_json =
-            "{\"skip_reason\":\"" + ptpu::json_escape(skip->str) + "\"";
-        if (!st->quantize_json.empty())
-          st->signature_json += ",\"quantize\":" + st->quantize_json;
-        if (!st->param_bytes_json.empty())
-          st->signature_json += ",\"param_bytes\":" + st->param_bytes_json;
-        if (!st->host_tables_json.empty())
-          st->signature_json += ",\"host_tables\":" + st->host_tables_json;
-        st->signature_json += "}";
-#endif
       }
     }
     if (!is_reload && st->has_decode && !st->step_skip_reason.empty())
@@ -3449,11 +3430,7 @@ struct Daemon {
   // ---- /v1/infer over the execution backends ----
 
   static bool have_infer_backend(const BundleState* B) {
-#ifdef PTPU_HAVE_PJRT
     return B != nullptr && (B->engine != nullptr || B->pjrt != nullptr);
-#else
-    return B != nullptr && B->engine != nullptr;
-#endif
   }
 
   // Flatten an already-parsed {"inputs": {...}} object into typed
@@ -3523,7 +3500,6 @@ struct Daemon {
         for (int32_t v : f->i32) slot[v] = 0;
       int64_t touched = int64_t(slot.size());
       int64_t lead = std::max<int64_t>(touched, 1);
-#ifdef PTPU_HAVE_PJRT
       if (backend == "pjrt" && B->pjrt != nullptr) {
         int64_t budget = 0;
         for (const auto& io : B->sig_inputs)
@@ -3546,7 +3522,6 @@ struct Daemon {
         }
         lead = budget;
       }
-#endif
       int32_t next = 0;
       std::vector<int64_t> ids;
       ids.reserve(size_t(touched));
@@ -3649,9 +3624,7 @@ struct Daemon {
   // backend and emit the response JSON.
   std::string infer_feeds(const BundleState* B, std::vector<Feed>& feeds,
                           std::string* err) {
-#ifdef PTPU_HAVE_PJRT
     if (backend == "pjrt") return infer_pjrt(B, feeds, err);
-#endif
     std::vector<ptpu_pjrt_tensor> results;
     std::vector<std::vector<uint8_t>> bufs;
     int n_out = interp_execute(B, feeds, &results, &bufs, err);
@@ -3720,7 +3693,6 @@ struct Daemon {
     return o.str();
   }
 
-#ifdef PTPU_HAVE_PJRT
   // Execute signature-ordered typed args on the pjrt runner. The exec
   // batch E is the bucket shape: with use_ladder the smallest rung >=
   // req_batch among the compiled ladder programs and the static-batch
@@ -3926,7 +3898,6 @@ struct Daemon {
                  : "out" + std::to_string(i);
     });
   }
-#endif
 
   // ---- /v1/infer micro-batching (--batch_window_ms > 0) ----
 
@@ -3934,14 +3905,12 @@ struct Daemon {
   // the largest compiled batch shape (ladder rung or static batch).
   int64_t batch_cap(const BundleState* B) const {
     int64_t cap = batch_max;
-#ifdef PTPU_HAVE_PJRT
     if (backend == "pjrt" && B != nullptr) {
       int64_t best = B->sig_static_batch;
       for (const auto& [rung, p] : B->ladder)
         best = std::max<int64_t>(best, rung);
       if (best > 0) cap = std::min<int64_t>(cap, best);
     }
-#endif
     return std::max<int64_t>(cap, 1);
   }
 
@@ -4046,11 +4015,9 @@ struct Daemon {
     if (!staged) {
       n_out = -1;   // err already set by stage_host_rows
     }
-#ifdef PTPU_HAVE_PJRT
     else if (backend == "pjrt" && B != nullptr && B->pjrt != nullptr)
       n_out = pjrt_execute(B.get(), cat, rows, /*use_ladder=*/true,
                            &results, &bufs, &padded_to, &err);
-#endif
     else if (B != nullptr && B->engine != nullptr)
       n_out = interp_execute(B.get(), cat, &results, &bufs, &err);
     else
@@ -4075,12 +4042,10 @@ struct Daemon {
       return;
     }
     auto name_of = [&](int i) -> std::string {
-#ifdef PTPU_HAVE_PJRT
       if (backend == "pjrt" && B->pjrt != nullptr)
         return i < int(B->sig_outputs.size())
                    ? B->sig_outputs[size_t(i)].name
                    : "out" + std::to_string(i);
-#endif
       return std::string(ptpu_engine_output_name(B->engine, i));
     };
     int64_t off = 0;
@@ -4380,14 +4345,6 @@ int main(int argc, char** argv) {
       return 2;
     }
   }
-#ifndef PTPU_HAVE_PJRT
-  if (d.backend == "pjrt") {
-    fprintf(stderr,
-            "this binary was built without the PJRT C API header "
-            "(PTPU_HAVE_PJRT); rebuild with PJRT_INC set\n");
-    return 2;
-  }
-#endif
   g_faults.parse(getenv("PTPU_SERVING_FAULTS"));
   signal(SIGPIPE, SIG_IGN);
   if (do_selftest) return selftest(d);
@@ -4405,7 +4362,6 @@ int main(int argc, char** argv) {
       fprintf(stderr, "paddle_tpu_serving: %s\n", err.c_str());
       return 1;
     }
-#ifdef PTPU_HAVE_PJRT
     // real-model decode over the bundle (pjrt backend): continuous
     // per-tick step decode when the bundle exported step modules,
     // else the drain-batch whole-loop fallback with the recorded
@@ -4437,7 +4393,6 @@ int main(int argc, char** argv) {
         }
       }
     }
-#endif
   }
   if (d.sched.backend) {
     d.sched.drain_mode = d.drain_batch;

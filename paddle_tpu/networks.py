@@ -268,7 +268,7 @@ def gru_encoder_decoder(src_word_id, trg_embedding=None, src_dict_dim=30000,
     enc_in = layer.StaticInput(input=encoded)
     proj_in = layer.StaticInput(input=encoded_proj)
     if not is_generating:
-        # TPU-first hoists (mathematically identical; PERF_r04.md):
+        # TPU-first hoists (mathematically identical; profiled at r4):
         # 1. the target-embedding half of the dec_in projection is
         #    time-independent — one [B,T,D]@W1 matmul outside the scan
         #    (weight shared by name with the generation-mode two-input fc,

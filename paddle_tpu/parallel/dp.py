@@ -83,6 +83,8 @@ class DataParallelTrainer(SGD):
         return NamedSharding(self.mesh, P())
 
     def _build_train_step(self):
+        from paddle_tpu.kernels._pallas_util import batch_sharded_kernels
+
         step = super()._build_train_step()
         mesh = self.mesh
         batch_sh = NamedSharding(mesh, P(self._batch_axes()))
@@ -101,6 +103,9 @@ class DataParallelTrainer(SGD):
                             None if a.seg_ids is None else
                             jax.lax.with_sharding_constraint(a.seg_ids, batch_sh))
                      for k, a in feeds.items()}
-            return step(params, opt_state, rng, feeds)
+            # XLA partitions everything in this program but the Pallas
+            # kernels, which run per batch shard (kernels/_pallas_util)
+            with batch_sharded_kernels(mesh, self._batch_axes()):
+                return step(params, opt_state, rng, feeds)
 
         return jax.jit(sharded)

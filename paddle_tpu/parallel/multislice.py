@@ -40,11 +40,11 @@ from typing import Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core.arg import Arg, as_arg
 from paddle_tpu.observability import metrics as obs_metrics
-from paddle_tpu.parallel._compat import shard_map
 from paddle_tpu.parallel.mesh import make_mesh
 from paddle_tpu.trainer.trainer import SGD, _compute_metrics
 from paddle_tpu.utils import logger

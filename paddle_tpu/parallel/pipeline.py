@@ -22,8 +22,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from paddle_tpu.parallel._compat import axis_size, shard_map
 
 
 def schedule_ticks(num_micro: int, num_stages: int) -> int:
@@ -55,11 +55,11 @@ def pipeline_schedule(step_fn: Callable, emit_fn: Callable, zero, s,
     Returns the emissions stacked over ticks (leading dim M + S - 1).
 
     The emissions ride the scan's ``ys`` outputs and are reduced by the
-    CALLER after the scan, never accumulated in the carry: this jax
-    version's shard_map cannot transpose a scan whose carry mixes a
-    ppermuted boundary with a locally-accumulated value (the _SpecError
-    that blocked ``jax.grad`` of the heterogeneous pipeline until r13 —
-    see parallel/_compat.py).
+    CALLER after the scan, never accumulated in the carry: the jax of
+    r13 could not transpose, under shard_map, a scan whose carry mixes a
+    ppermuted boundary with a locally-accumulated value (_SpecError; it
+    blocked ``jax.grad`` of the heterogeneous pipeline until then). Not
+    re-tried on jax 0.9.0; the ys form transposes on both.
     """
     fwd_perm = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 
@@ -91,7 +91,7 @@ def gpipe(block_fn: Callable, stacked_params, xs: jax.Array, mesh: Mesh,
     fn = jax.checkpoint(block_fn) if remat else block_fn
 
     def local(params, xs):
-        S = axis_size(axis_name)
+        S = jax.lax.axis_size(axis_name)
         s = jax.lax.axis_index(axis_name)
         M = xs.shape[0]
         p_local = jax.tree_util.tree_map(lambda a: a[0], params)

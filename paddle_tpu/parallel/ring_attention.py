@@ -23,8 +23,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from paddle_tpu.parallel._compat import axis_size, shard_map
 
 
 def _online_block(q, k, v, o, m, l, q_pos, k_pos, causal, scale,
@@ -73,7 +73,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
     segged = seg_q is not None
 
     def local(q, k, v, *segs):
-        p = axis_size(axis_name)
+        p = jax.lax.axis_size(axis_name)
         idx = jax.lax.axis_index(axis_name)
         B, Tq, H, Dh = q.shape
         Tk = k.shape[1]
@@ -130,7 +130,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
     segged = seg_q is not None
 
     def local(q, k, v, *segs):
-        p = axis_size(axis_name)
+        p = jax.lax.axis_size(axis_name)
         B, Tl, H, Dh = q.shape
 
         def scatter_heads(x):

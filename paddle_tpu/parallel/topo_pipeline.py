@@ -41,8 +41,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from paddle_tpu.parallel._compat import shard_map
 from paddle_tpu.parallel.pipeline import pipeline_schedule, schedule_ticks
 
 from paddle_tpu.core.arg import Arg, as_arg

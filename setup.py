@@ -22,7 +22,8 @@ setup(
                 "v2 API surface (JAX/XLA compute, native C++ runtime)",
     packages=find_packages(include=["paddle_tpu", "paddle_tpu.*"]),
     package_data={
-        "paddle_tpu.native": ["*.cc", "*.h", "Makefile"],
+        "paddle_tpu.native": ["*.cc", "*.h", "Makefile",
+                              "third_party/xla/pjrt/c/*.h"],
     },
     python_requires=">=3.10",
     install_requires=[

@@ -1,0 +1,228 @@
+"""The main path's Pallas kernels compile for the chip — checked without one.
+
+The TPU's compiler is installed here and compiles for a v5e that is
+DESCRIBED, not attached (jax.experimental.topologies): what it refuses — a
+slice off the tiling, too much VMEM, a kernel it cannot partition — costs a
+test run instead of chip time. Nothing executes: a pass says "compiles",
+never "runs" or "is right" (chip_smoke.py says those, on the chip).
+
+This is the ONE test file that loads the TPU library. The topology is
+described inside a module-scoped fixture, after collection, never at
+import: only one process at a time may hold libtpu, and under xdist every
+worker imports every test file but only one runs this one. The libtpu
+dlopen + PJRT version negotiation test (formerly tests/test_pjrt_runner.py)
+lives here for the same reason. Compiles run in this process, with the
+persistent compile cache off around them.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    """Compile for the described device(s); returns (compiled, number of
+    Mosaic kernels in the program)."""
+    lowered = jax.jit(fn).lower(*args)
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.output_size_in_bytes \
+        + ma.temp_size_in_bytes < HBM_BYTES, ma
+    return compiled, lowered.as_text().count("tpu_custom_call")
+
+
+def _gru_args(B, H, T, dtype, batch_sh, repl_sh):
+    return (_sds((B, T, 3 * H), dtype, batch_sh),
+            _sds((H, 2 * H), dtype, repl_sh), _sds((H, H), dtype, repl_sh),
+            _sds((3 * H,), dtype, repl_sh),
+            _sds((B, T), jnp.float32, batch_sh))
+
+
+def _lstm_args(B, H, T, dtype, sh):
+    return (_sds((B, T, 4 * H), dtype, sh), _sds((H, 4 * H), dtype, sh),
+            _sds((7 * H,), dtype, sh), _sds((B, T), jnp.float32, sh))
+
+
+def _gru_train(x3, wg, wc, b, mask):
+    from paddle_tpu.kernels.gru import fused_gru
+
+    def loss(x3, wg, wc, b):
+        return jnp.sum(fused_gru(x3, wg, wc, b, mask).astype(jnp.float32))
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(x3, wg, wc, b)
+
+
+def _lstm_train(x4, w, b, mask):
+    from paddle_tpu.kernels.lstm import fused_lstm
+
+    def loss(x4, w, b):
+        hs, cs = fused_lstm(x4, w, b, mask)
+        return jnp.sum(hs.astype(jnp.float32)) + jnp.sum(
+            cs.astype(jnp.float32))
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(x4, w, b)
+
+
+# (B, H, T, dtype, f32 matmul precision): the NMT encoder in training
+# (bf16) and as chip_smoke.py's parity check traces it (f32 at 'highest')
+@pytest.mark.parametrize("B,H,T,dtype,precision", [
+    (256, 512, 30, jnp.bfloat16, None),
+    (256, 512, 30, jnp.float32, "highest"),
+])
+def test_fused_gru_train_compiles(one_chip, B, H, T, dtype, precision):
+    with jax.default_matmul_precision(precision or "default"):
+        _c, n = _compile(_gru_train,
+                         *_gru_args(B, H, T, dtype, one_chip, one_chip))
+    assert n == 2                                   # forward + backward
+
+
+# the LSTM classifier (B64/H512/T100) and the split backward past the
+# in-kernel-dW VMEM gate (H1280)
+@pytest.mark.parametrize("B,H,T,dtype,precision", [
+    (64, 512, 100, jnp.bfloat16, None),
+    (64, 512, 100, jnp.float32, "highest"),
+    (128, 1280, 100, jnp.bfloat16, None),
+])
+def test_fused_lstm_train_compiles(one_chip, B, H, T, dtype, precision):
+    with jax.default_matmul_precision(precision or "default"):
+        _c, n = _compile(_lstm_train, *_lstm_args(B, H, T, dtype, one_chip))
+    assert n == 2
+
+
+# forward only, f32, at the serving daemon's static batch: what the
+# exported classifier / NMT whole-loop modules hold on a TPU host
+@pytest.mark.parametrize("kind,B,H,T", [("lstm", 8, 512, 100),
+                                        ("gru", 8, 512, 16)])
+def test_fused_forward_at_serving_batch_compiles(one_chip, kind, B, H, T):
+    from paddle_tpu.kernels.gru import fused_gru
+    from paddle_tpu.kernels.lstm import fused_lstm
+
+    if kind == "gru":
+        fn, args = fused_gru, _gru_args(B, H, T, jnp.float32, one_chip,
+                                        one_chip)
+    else:
+        fn, args = fused_lstm, _lstm_args(B, H, T, jnp.float32, one_chip)
+    _c, n = _compile(lambda *a: fn(*a), *args)
+    assert n == 1
+
+
+@pytest.mark.parametrize("B,T,L", [(32, 2048, 64), (32, 512, 64),
+                                   (64, 256, 10)])
+def test_crf_logz_train_compiles(one_chip, B, T, L):
+    from paddle_tpu.layers.crf_ctc import crf_logz_pallas
+
+    def train(emit, mask, w):
+        return jax.value_and_grad(
+            lambda e, w: jnp.sum(crf_logz_pallas(e, mask, w)),
+            argnums=(0, 1))(emit, w)
+
+    _c, n = _compile(train, _sds((B, T, L), jnp.float32, one_chip),
+                     _sds((B, T), jnp.float32, one_chip),
+                     _sds((L + 2, L), jnp.float32, one_chip))
+    assert n >= 2
+
+
+# the shapes tools/ctc_bench.py timed the (unwired, CTC_IMPL="pallas")
+# CTC kernel at
+@pytest.mark.parametrize("B,T,C,U", [(32, 512, 128, 20)])
+def test_ctc_train_compiles(one_chip, B, T, C, U):
+    from paddle_tpu.kernels.ctc import ctc_nll_pallas
+
+    def train(logits, labels, in_mask, label_mask):
+        return jax.value_and_grad(lambda l: jnp.sum(
+            ctc_nll_pallas(l, labels, in_mask, label_mask)))(logits)
+
+    _c, n = _compile(train, _sds((B, T, C), jnp.float32, one_chip),
+                     _sds((B, U), jnp.int32, one_chip),
+                     _sds((B, T), jnp.float32, one_chip),
+                     _sds((B, U), jnp.float32, one_chip))
+    assert n >= 2
+
+
+def test_fused_gru_under_data_parallel_compiles(topo):
+    """GSPMD cannot partition a Mosaic kernel; DataParallelTrainer's step
+    wraps each in a shard_map over the batch (kernels/_pallas_util). The
+    same jit + sharding-annotation program, on the four described chips."""
+    from paddle_tpu.kernels._pallas_util import (batch_sharded_kernels,
+                                                 call_kernel)
+    from paddle_tpu.kernels.gru import fused_gru
+
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    batch, repl = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+
+    def train(x3, wg, wc, b, mask):
+        def loss(x3, wg, wc, b):
+            hs = call_kernel(fused_gru, (x3, wg, wc, b, mask),
+                             batch_argnums=(0, 4))
+            return jnp.sum(hs.astype(jnp.float32))
+
+        with batch_sharded_kernels(mesh, "data"):
+            return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(
+                x3, wg, wc, b)
+
+    args = _gru_args(256, 512, 30, jnp.bfloat16, batch, repl)
+    compiled, n = _compile(train, *args)
+    assert n == 2
+    assert "all-reduce" in compiled.as_text()       # the weight gradients
+    # and without the wrapper the partitioner refuses, which is why
+    with pytest.raises(NotImplementedError, match="partitioned"):
+        jax.jit(_gru_train).lower(*args)
+
+
+def test_libtpu_api_negotiation():
+    """libtpu.so exports GetPjrtApi and speaks the vendored header's PJRT
+    API major version; on this chip-less host client creation then fails
+    with the TPU runtime's own error (proving dlopen + version check +
+    PJRT_Plugin_Initialize all ran). On a TPU host it succeeds."""
+    import importlib.util
+
+    from paddle_tpu import native
+
+    spec = importlib.util.find_spec("libtpu")
+    if spec is None:
+        pytest.skip("no libtpu package installed")
+    libtpu = os.path.join(list(spec.submodule_search_locations)[0],
+                          "libtpu.so")
+    native.build("pjrt")
+    try:
+        r = native.PjrtRunner(libtpu)
+        assert r.device_count >= 1
+        r.close()
+    except RuntimeError as e:
+        # past dlopen/dlsym/version checks, into the TPU runtime proper
+        assert "dlopen" not in str(e) and "version mismatch" not in str(e), e
+        assert "TPU" in str(e) or "device" in str(e), e

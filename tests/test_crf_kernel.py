@@ -1,7 +1,7 @@
 """Pallas CRF forward-backward kernel (VERDICT r4 item 4): parity with
 the lax.scan recursion, f64 FD check in interpret mode, padding paths.
-Silicon parity + the T-sweep timing table: tools/ctc_bench.py /
-TPU_PARITY_r05.md.
+On-chip parity + the T-sweep timing table: tools/ctc_bench.py (r5
+figures in layers/crf_ctc.py, not re-measured).
 """
 
 import jax

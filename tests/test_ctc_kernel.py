@@ -1,7 +1,8 @@
 """Pallas CTC forward-backward kernel (VERDICT r4 item 4): parity with
 the lax.scan recursion (layers/crf_ctc.ctc_nll), finite-difference check
 in f64 interpret mode, and edge cases. Silicon parity + the T-sweep
-timing table live in tools/ctc_bench.py / TPU_PARITY_r05.md.
+timing table: tools/ctc_bench.py (r5 figures in layers/crf_ctc.py,
+not re-measured).
 """
 
 import jax

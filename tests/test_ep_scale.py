@@ -1,7 +1,7 @@
 """EP embedding at realistic vocab scale (VERDICT r3 missing #5, part 2):
 a vocab >= 1M sparse_update table EP-sharded over the 'model' axis of the
 8-device mesh trains one step. (Round 3's dryrun used vocab=256; the real
-chip's step time for the same config goes in BENCH_EXTRA_r04.md.)"""
+chip's step time for the same config has not been re-measured.)"""
 
 import time
 

@@ -115,3 +115,47 @@ def test_layer_path_uses_scan_equivalence():
             want = want[:, ::-1]
         want = want * mask[..., None]
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_batch_sharded_call_matches_single_device():
+    """The route a kernel takes inside DataParallelTrainer's GSPMD step
+    (kernels/_pallas_util.call_kernel under batch_sharded_kernels: one
+    shard_map over the batch, weights whole on every shard): same values
+    and same gradients — the weight gradients summed over the shards —
+    as the plain call."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.kernels._pallas_util import (batch_sharded_kernels,
+                                                 batch_shards, call_kernel)
+    from paddle_tpu.parallel import make_mesh
+
+    B, T, H = 32, 6, 128
+    x3, Wg, Wc, b, mask = _data(B, T, H, seed=5)
+    cot = jnp.asarray(np.random.RandomState(6).randn(B, T, H), jnp.float32)
+
+    def loss(x3, Wg, Wc, b, mask):
+        hs = call_kernel(
+            lambda x3, Wg, Wc, b, mask: fused_gru(x3, Wg, Wc, b, mask, None,
+                                                  True),
+            (x3, Wg, Wc, b, mask), batch_argnums=(0, 4))
+        return jnp.sum(hs * mask[..., None] * cot)
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2, 3))
+    want = jax.jit(grad)(x3, Wg, Wc, b, mask)
+
+    mesh = make_mesh(data=4, devices=jax.devices()[:4])
+    assert batch_shards() == 1
+
+    def sharded(*args):
+        with batch_sharded_kernels(mesh, "data"):
+            assert batch_shards() == 4
+            return grad(*args)
+
+    batch = NamedSharding(mesh, P("data"))
+    got = jax.jit(sharded)(jax.device_put(x3, batch), Wg, Wc, b,
+                           jax.device_put(mask, batch))
+    assert len(got[1][0].sharding.device_set) == 4
+    for a, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)

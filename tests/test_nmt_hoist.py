@@ -1,5 +1,5 @@
 """The r4 NMT hoists (vocab projection + target-embedding projection
-moved out of the decoder scan, PERF_r04.md) must be numerically
+moved out of the decoder scan) must be numerically
 IDENTICAL to the reference per-step formulation with shared params, and
 parameter names must stay mode-portable (training <-> generation)."""
 

@@ -1,7 +1,7 @@
 """Fused vocab-projection + softmax-xent kernel (kernels/vocab_xent.py):
 values/grads match the materializing baseline exactly; silicon timing is
-a measured WASH at NMT shapes (documented in the module docstring +
-BENCH_EXTRA_r05.md), so the kernel is a library function, not wired into any layer path.
+a measured WASH at NMT shapes (documented in the module docstring; r5,
+not re-measured), so the kernel is a library function, not wired into any layer path.
 """
 
 import jax

@@ -96,4 +96,7 @@ def main():
 
 
 if __name__ == "__main__":
+    import paddle_tpu
+
+    paddle_tpu.compile_cache()
     main()

@@ -7,15 +7,15 @@ reference runs it per registered Function). Here the two "backends" are:
 - reference: op-by-op eager evaluation pinned to the host CPU
   (``jax.disable_jit`` + ``jax.default_device(cpu)``) — the interpreter;
 - candidate: the SAME program under ``jax.jit`` on the default device —
-  on the bench host that's the TPU chip, in the CPU-pinned test suite
-  it's the compiled-CPU path.
+  the TPU chip where there is one, the compiled-CPU path in the
+  CPU-pinned test suite.
 
 Each case builds a small topology, runs forward on every output and the
 gradient of a scalar loss w.r.t. every float parameter, and asserts
 numerical agreement. ``jax.default_matmul_precision('highest')`` keeps
 TPU matmuls in fp32 so tolerances stay tight.
 
-Run standalone on the bench host (real TPU):
+Run standalone on a machine with a TPU:
     python tools/tpu_parity.py [case ...]
 """
 
@@ -487,4 +487,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    import paddle_tpu
+
+    paddle_tpu.compile_cache()
     sys.exit(main())
