@@ -198,6 +198,7 @@ def _alpha_call(em, mask_tb, start, trans, interpret):
     kernel = functools.partial(_fwd_kernel, C=_CHUNK)
     alphas = pl.pallas_call(
         kernel,
+        name="crf_fwd",
         grid=(Tp // _CHUNK,),
         in_specs=[
             pl.BlockSpec((_CHUNK, B, L), lambda s: (s, 0, 0),
@@ -241,6 +242,7 @@ def _crf_bwd(interpret, res, ct):
     kernel = functools.partial(_bwd_kernel, C=_CHUNK)
     demit, acc = pl.pallas_call(
         kernel,
+        name="crf_bwd",
         grid=(NC,),
         in_specs=[
             pl.BlockSpec((_CHUNK, B, L), rev, memory_space=pltpu.VMEM),

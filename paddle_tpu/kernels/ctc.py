@@ -153,6 +153,7 @@ def _alphas(em, mask_tb, skip, ok, interpret):
     kernel = functools.partial(_fwd_kernel, C=_CHUNK)
     alphas = pl.pallas_call(
         kernel,
+        name="ctc_fwd",
         grid=(Tp // _CHUNK,),
         in_specs=[
             pl.BlockSpec((_CHUNK, B, S), lambda s: (s, 0, 0),
@@ -198,6 +199,7 @@ def _ctc_fb_bwd(interpret, res, ct):
     rev = lambda s: (NC - 1 - s, 0, 0)
     demit = pl.pallas_call(
         kernel,
+        name="ctc_bwd",
         grid=(NC,),
         in_specs=[
             pl.BlockSpec((_CHUNK, B, S), rev, memory_space=pltpu.VMEM),

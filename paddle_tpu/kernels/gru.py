@@ -186,6 +186,7 @@ def _fwd_call(x3_tm, wg, wc, b, mask_tm, reset_tm, interpret):
                                  memory_space=pltpu.VMEM)] if R else [])
     return pl.pallas_call(
         kernel,
+        name="fused_gru_fwd",
         grid=(T // C,),
         in_specs=[
             pl.BlockSpec((C, B, H3), lambda s: (s, 0, 0),
@@ -230,6 +231,7 @@ def _bwd_call(wg, wc, mask_tm, reset_tm, gates, hs_prev, g_hs, interpret):
                    if R else [])
     return pl.pallas_call(
         kernel,
+        name="fused_gru_bwd",
         grid=(NC,),
         in_specs=[
             pl.BlockSpec((H, 2 * H), lambda s: (0, 0),

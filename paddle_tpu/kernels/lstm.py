@@ -346,6 +346,7 @@ def _bwd_call_nodw(w, b, mask_tm, reset_tm, gates, cs, cs_prev, g_hs, g_cs,
                    if R else [])
     return pl.pallas_call(
         kernel,
+        name="fused_lstm_bwd_dx",
         grid=(NC,),
         in_specs=[
             pl.BlockSpec((H, H4), lambda s: (0, 0),
@@ -388,6 +389,7 @@ def _fwd_call(x4_tm, w, b, mask_tm, reset_tm, interpret):
                                  memory_space=pltpu.VMEM)] if R else [])
     return pl.pallas_call(
         kernel,
+        name="fused_lstm_fwd",
         grid=(T // C,),
         in_specs=[
             pl.BlockSpec((C, B, H4), lambda s: (s, 0, 0),
@@ -437,6 +439,7 @@ def _bwd_call(w, b, mask_tm, reset_tm, gates, cs, cs_prev, hs_prev, g_hs,
                    if R else [])
     return pl.pallas_call(
         kernel,
+        name="fused_lstm_bwd",
         grid=(NC,),
         in_specs=[
             pl.BlockSpec((H, H4), lambda s: (0, 0),

@@ -120,6 +120,7 @@ def _maxpool_bwd_pallas(x, dy, interpret=False):
             vmem_limit_bytes=100 * 1024 * 1024)
     return pl.pallas_call(
         _bwd_kernel,
+        name="maxpool_bwd",
         grid=(B,),
         in_specs=[pl.BlockSpec((1, H, W, C), lambda b: (b, 0, 0, 0)),
                   pl.BlockSpec((1, HO, WO, C), lambda b: (b, 0, 0, 0))],

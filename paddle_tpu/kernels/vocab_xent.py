@@ -177,6 +177,7 @@ def _fwd(x, w, b, labels, interpret):
     kernel = functools.partial(_fwd_kernel, V=V, VC=_VC)
     nll, lse = pl.pallas_call(
         kernel,
+        name="vocab_xent_fwd",
         grid=(Np // _ROWS, Vp // _VC),
         in_specs=[
             pl.BlockSpec((_ROWS, D), lambda r, v: (r, 0),
@@ -246,6 +247,7 @@ def _vjp_bwd(interpret, res, ct):
     vmap_ = lambda r, v: (0, v)
     dx = pl.pallas_call(
         functools.partial(_bwd_dx_kernel, V=V, VC=_VC),
+        name="vocab_xent_bwd_dx",
         grid=(Np // _ROWS, Vp // _VC),
         in_specs=with_maps([rmap, vmap_, vmap_, rmap, rmap, rmap]),
         out_specs=pl.BlockSpec((_ROWS, D), lambda r, v: (r, 0),
@@ -260,6 +262,7 @@ def _vjp_bwd(interpret, res, ct):
     vr_v = lambda v, r: (0, v)
     dw, db = pl.pallas_call(
         functools.partial(_bwd_dw_kernel, V=V, VC=_VC),
+        name="vocab_xent_bwd_dw",
         grid=(Vp // _VC, Np // _ROWS),
         in_specs=with_maps([vr_r, vr_v, vr_v, vr_r, vr_r, vr_r]),
         out_specs=[

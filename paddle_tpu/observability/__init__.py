@@ -5,9 +5,10 @@ Three small host-side modules (docs/observability.md is the catalog):
 - ``metrics``  — thread-safe typed registry (Counter/Gauge/Histogram with
   fixed log-spaced buckets, optional labels), consistent snapshots,
   delta-since-last-scrape, Prometheus text + JSON exposition,
-- ``trace``    — span tracer emitting Chrome trace-event JSON, sharing
-  one namespace with utils.stat timer_scope names and jax.named_scope
-  XLA annotations,
+- ``trace``    — the cheap host-only span log (Chrome trace-event JSON),
+  fed by utils.stat.timer_scope — the same call that writes each span
+  into the profiler's own trace (jax.profiler.TraceAnnotation), where it
+  lies beside the device's ops,
 - ``exporter`` — opt-in background HTTP server (/metrics, /healthz,
   /trace) + periodic file exporter for headless runs.
 

@@ -1,14 +1,15 @@
 """Span tracer emitting Chrome trace-event JSON (Perfetto-loadable).
 
-Host-side spans share ONE namespace with the existing profiling surface:
-
-- ``span(name)`` records wall-clock into ``utils.stat.global_stat`` under
-  the same name (so StatSet reports include traced spans),
-- it opens a ``jax.named_scope`` (via stat's cached probe) so any XLA
-  trace captured concurrently carries the same names,
-- when tracing is enabled, ``utils.stat.timer_scope``'s sink hook feeds
-  every existing ``timer_scope``/``register_timer`` site into the same
-  event buffer — the legacy names are subsumed, not duplicated.
+The cheap host-only log. ``span(name, **args)`` IS
+``utils.stat.timer_scope``: one implementation records wall-clock into
+``utils.stat.global_stat``, opens a ``jax.profiler.TraceAnnotation`` (so a
+profile taken with ``jax.profiler.start_trace`` holds the same span beside
+the device's ops, on the profiler's clock), and — while a tracer is
+enabled — feeds this event buffer through the sink hook. Every
+``timer_scope``/``register_timer`` site and the train loop's ``paddle:``
+phases (docs/observability.md) therefore land here under the names the
+profile carries. The timestamps here are wall-clock epoch microseconds;
+to lay host phases beside device ops, read the profile, not this file.
 
 Events are Chrome trace-event "complete" records (ph="X", microsecond
 ts/dur) inside ``{"traceEvents": [...]}`` — loadable in Perfetto /
@@ -18,7 +19,6 @@ left on for a week of training cannot OOM the host.
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
@@ -64,9 +64,10 @@ class Tracer:
         _stat.set_trace_sink(None)
 
     # --- recording --------------------------------------------------------
-    def _sink(self, name: str, t0: float, dur: float):
-        """timer_scope completion hook (name, perf_counter start, secs)."""
-        self.add_complete(name, t0, dur)
+    def _sink(self, name: str, t0: float, dur: float, args: dict):
+        """timer_scope completion hook (name, perf_counter start, secs,
+        the span's args)."""
+        self.add_complete(name, t0, dur, args or None)
 
     def add_complete(self, name: str, t0_perf: float, dur_s: float,
                      args: Optional[dict] = None):
@@ -97,28 +98,11 @@ class Tracer:
                 self._dropped += 1
             self._events.append(ev)
 
-    @contextlib.contextmanager
     def span(self, name: str, **args):
-        """Traced scope: StatSet + jax.named_scope + trace event. The
-        named scope means a concurrently-captured XLA profile carries the
-        same name this host span does."""
-        scope = None
-        ns = _stat._resolve_named_scope()
-        if ns:
-            try:
-                scope = ns(name)
-                scope.__enter__()
-            except Exception:
-                scope = None
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - t0
-            _stat.global_stat.get(name).add(dur)
-            self.add_complete(name, t0, dur, args or None)
-            if scope is not None:
-                scope.__exit__(None, None, None)
+        """Traced scope = ``utils.stat.timer_scope(name, **args)``: its
+        completion reaches the enabled tracer through the sink that
+        ``enable()`` installed."""
+        return _stat.timer_scope(name, **args)
 
     # --- export -----------------------------------------------------------
     def to_chrome_trace(self) -> dict:
@@ -163,6 +147,6 @@ def disable():
 
 def span(name: str, **args):
     """Module-level convenience over the global tracer. Works (as a plain
-    stat timer + named scope) even when tracing is disabled, so call
-    sites never need to guard."""
+    stat timer + profiler annotation) even when tracing is disabled, so
+    call sites never need to guard."""
     return global_tracer.span(name, **args)

@@ -34,17 +34,19 @@ from paddle_tpu.trainer.feeder import DataFeeder, resolve_pack_flags
 from paddle_tpu.utils import logger
 from paddle_tpu.utils.error import enforce
 from paddle_tpu.utils.flags import FLAGS
-from paddle_tpu.utils.stat import global_stat, timer_scope
+from paddle_tpu.utils.stat import timer_scope
 
 # --- train-loop telemetry (host-side only: all of these time AROUND the
 # jitted step, never inside it, so the compiled program is untouched —
 # pinned by tests/test_observability.py jaxpr tests) ----------------------
 _M_STEP_SECONDS = obs_metrics.histogram(
     "paddle_train_step_seconds",
-    "Per-batch wall time by phase: data_wait (reader next), feed (host "
-    "batch->device args + prefetch device_put), dispatch (jitted step "
-    "enqueue), drain (blocked fetching that batch's cost), compute "
-    "(dispatch+drain — non-overlapped device time once pipelined)",
+    "Per-batch wall time by phase: data_wait (reader next), feed (batch "
+    "in hand -> feeds ready for the step) with its children feed_convert "
+    "(feeder stacking) and feed_h2d (device_put until the call returns), "
+    "compile (build + first call of a new shape key; its count is the "
+    "compile counter), dispatch (jitted step enqueue of a known shape), "
+    "drain (blocked fetching that batch's cost)",
     labels=("phase",))
 _M_BATCHES = obs_metrics.counter(
     "paddle_train_batches_total", "Batches trained by SGD.train")
@@ -74,25 +76,45 @@ _M_PREEMPTIONS = obs_metrics.counter(
     "Preemption requests honored at a batch boundary")
 
 
+class _phase(timer_scope):
+    """One phase of the train loop, timed once and written three ways from
+    this one call site: the span ``paddle:<phase>`` in the profiler's own
+    trace (host line, the device's clock) and in the Chrome ``Tracer``
+    when enabled, and ``paddle_train_step_seconds{phase}``. ``step`` is
+    the global step of the batch the phase works on: the spans of one
+    batch share it. A phase left by an exception (the reader's
+    StopIteration) is not a completed phase: no observation."""
+
+    __slots__ = ("_hist",)
+
+    def __init__(self, phase, step, **args):
+        super().__init__("paddle:" + phase, step=step, **args)
+        self._hist = _M_STEP_SECONDS.labels(phase=phase)
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            self._hist.observe(self.seconds)
+        return False
+
+
 class _TimedBatches:
-    """Iterator adapter timing each ``next`` on the underlying reader —
-    the consumer-side data-wait half of the step-time split."""
+    """Iterator adapter: each ``next`` on the underlying reader is the
+    ``data_wait`` phase of the batch it returns (``step_of()`` = that
+    batch's global step at the dispatch frontier)."""
 
-    __slots__ = ("_it", "last_wait")
+    __slots__ = ("_it", "_step_of")
 
-    def __init__(self, it):
+    def __init__(self, it, step_of):
         self._it = it
-        self.last_wait = 0.0
+        self._step_of = step_of
 
     def __iter__(self):
         return self
 
     def __next__(self):
-        t0 = time.perf_counter()
-        item = next(self._it)
-        self.last_wait = time.perf_counter() - t0
-        _M_STEP_SECONDS.labels(phase="data_wait").observe(self.last_wait)
-        return item
+        with _phase("data_wait", self._step_of()):
+            return next(self._it)
 
 
 class _InFlight:
@@ -102,13 +124,17 @@ class _InFlight:
     NOT part of the donated param/opt pytrees — so they stay valid while
     later steps consume (and invalidate) the params they came from."""
 
-    __slots__ = ("batch_id", "cost", "metrics", "n_examples", "dispatch_s",
-                 "step_flops", "param_stats", "host_token", "host_grads")
+    __slots__ = ("batch_id", "step", "cost", "metrics", "n_examples",
+                 "dispatch_s", "step_flops", "param_stats", "host_token",
+                 "host_grads")
 
-    def __init__(self, batch_id, cost, metrics, n_examples, dispatch_s,
+    def __init__(self, batch_id, step, cost, metrics, n_examples, dispatch_s,
                  step_flops, param_stats=None, host_token=None,
                  host_grads=None):
         self.batch_id = batch_id
+        # global step of this batch: its drain span carries the number
+        # its data_wait/feed/dispatch spans did
+        self.step = step
         self.cost = cost
         self.metrics = metrics
         self.n_examples = n_examples
@@ -1080,7 +1106,7 @@ class SGD:
                     if next(batch_iter, _DRAINED) is _DRAINED:
                         break
             snapshots_on = bool(save_every_n_batches and snapshot_dir)
-            timed_iter = _TimedBatches(batch_iter)
+            timed_iter = _TimedBatches(batch_iter, lambda: disp_step + 1)
 
             # --- drain side of the pipeline: fire batch N's events with
             # exact values once its dispatched step has (been forced to)
@@ -1100,15 +1126,11 @@ class SGD:
                     # legacy timing for handlers doing pre-batch setup)
                     event_handler(v2_event.BeginIteration(pass_id,
                                                           ent.batch_id))
-                t_dr = time.perf_counter()
-                with timer_scope("drainBatch", use_named_scope=False):
+                with _phase("drain", ent.step) as drain:
                     # the float() fetch forces the dispatched step to
                     # finish — everything enqueued through it has executed
                     cost = float(ent.cost)
-                drain_s = time.perf_counter() - t_dr
-                _M_STEP_SECONDS.labels(phase="drain").observe(drain_s)
-                _M_STEP_SECONDS.labels(phase="compute").observe(
-                    ent.dispatch_s + drain_s)
+                drain_s = drain.seconds
                 _M_BATCHES.inc()
                 now = time.perf_counter()
                 wall_s = now - drain_clock[0]
@@ -1180,10 +1202,11 @@ class SGD:
                                                   start=batch_start):
                 if depth <= 1:
                     event_handler(v2_event.BeginIteration(pass_id, batch_id))
-                t_feed = time.perf_counter()
+                step = disp_step + 1
                 staged = None
-                with timer_scope("feedBatch", use_named_scope=False):
-                    feeds = self._prepare_feeds(feeder(data_batch))
+                with _phase("feed", step):
+                    with _phase("feed_convert", step):
+                        feeds = self._prepare_feeds(feeder(data_batch))
                     if self._host_rt is not None:
                         # host-resident tables: exact staleness drains
                         # the pipeline when this batch touches a row an
@@ -1196,28 +1219,39 @@ class SGD:
                         staged = self._host_rt.stage(
                             feeds, overlapped=bool(inflight))
                         feeds = staged.feeds
-                    if depth > 1:
-                        # start the H2D copy now so it overlaps the
-                        # still-executing previous step (async device_put)
-                        feeds = self._device_put_feeds(feeds)
-                    if staged is not None:
-                        # the row cache rides the same async H2D lane
-                        sh = self._host_cache_sharding()
-                        for pname, cache in staged.caches.items():
-                            params[pname] = (
-                                jax.device_put(cache) if sh is None
-                                else jax.device_put(cache, sh))
-                feed_s = time.perf_counter() - t_feed
-                _M_STEP_SECONDS.labels(phase="feed").observe(feed_s)
+                    if depth > 1 or staged is not None:
+                        # host time until the calls return: device_put is
+                        # async, the copy itself lands under later spans
+                        with _phase("feed_h2d", step):
+                            if depth > 1:
+                                # start the H2D copy now so it overlaps
+                                # the still-executing previous step
+                                feeds = self._device_put_feeds(feeds)
+                            if staged is not None:
+                                # the row cache rides the same async lane
+                                sh = self._host_cache_sharding()
+                                for pname, cache in staged.caches.items():
+                                    params[pname] = (
+                                        jax.device_put(cache) if sh is None
+                                        else jax.device_put(cache, sh))
                 key = self._shape_key(feeds)
-                if key not in self._step_fns:
-                    logger.info("compiling train step for shapes %s", key)
-                    self._step_fns[key] = self._build_train_step()
-                train_fn = self._step_fns[key]
                 rng, step_rng = jax.random.split(rng)
-                t_cmp = time.perf_counter()
                 hgrads = None
-                with timer_scope("trainBatch", use_named_scope=False):
+                compiling = key not in self._step_fns
+                if compiling:
+                    # a new shape: building the step AND its first call
+                    # (which traces and compiles) are one `compile` span;
+                    # the count of {phase=compile} is the compile counter
+                    logger.info("compiling train step for shapes %s", key)
+                    run = _phase("compile", step, key=str(key))
+                else:
+                    # a StepTraceAnnotation (step_num): the profile groups
+                    # the device's ops by the program's own steps
+                    run = _phase("dispatch", step, step_num=step)
+                with run:
+                    if compiling:
+                        self._step_fns[key] = self._build_train_step()
+                    train_fn = self._step_fns[key]
                     # async dispatch: returns once enqueued; step N+1 can
                     # enqueue against step N's device-resident donated
                     # outputs without any host sync
@@ -1228,19 +1262,17 @@ class SGD:
                     else:
                         params, opt_state, cost, metrics = out
                     if depth <= 1:
-                        # synchronous mode keeps the legacy 'trainBatch'
-                        # Stat/trace semantics: the fetch forces the step
-                        # to finish, so the span means executed, not
+                        # synchronous mode: the fetch forces the step to
+                        # finish, so the span means executed, not
                         # enqueued (drain_one's float() is then a no-op)
                         cost = float(cost)
-                dispatch_s = time.perf_counter() - t_cmp
-                _M_STEP_SECONDS.labels(phase="dispatch").observe(dispatch_s)
+                dispatch_s = run.seconds
                 disp_step += 1
                 stats_dev = None
                 if stats_period and disp_step % stats_period == 0:
                     stats_dev = self._param_stats(params)
                 inflight.append(_InFlight(
-                    batch_id, cost, metrics,
+                    batch_id, step, cost, metrics,
                     len(data_batch) if hasattr(data_batch, "__len__") else 0,
                     dispatch_s, self._flops_for(key, feeds), stats_dev,
                     host_token=staged, host_grads=hgrads))

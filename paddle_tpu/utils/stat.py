@@ -1,48 +1,33 @@
-"""Hierarchical wall-clock stats + named profiler scopes.
+"""Hierarchical wall-clock stats + profiler trace spans.
 
 Analog of paddle/utils/Stat.h:114-246 (Stat/StatSet/TimerOnce,
 REGISTER_TIMER_INFO) and the GPU-profiler bridge (Stat.cpp:155). On TPU the
-device-side analog is jax.profiler / jax.named_scope: ``timer_scope`` both
-records host wall-clock into the global StatSet and opens a
-``jax.named_scope`` so XLA traces carry the same names the host stats do.
+profiler is jax.profiler: ``timer_scope`` records host wall-clock into the
+global StatSet and opens a ``jax.profiler.TraceAnnotation`` (a TraceMe), so
+a profile taken with ``jax.profiler.start_trace`` holds the span on a host
+line, on the same clock as the device's ops. With no profiler session the
+annotation is one atomic check.
 
-The observability subsystem rides the same namespace: when a tracer is
-active (observability.trace.enable), every ``timer_scope`` completion also
-lands as a Chrome trace-event span via the ``set_trace_sink`` hook — host
-spans, StatSet names, and XLA annotations stay one vocabulary.
+The observability subsystem rides the same call: when a tracer is active
+(observability.trace.enable), every ``timer_scope`` completion also lands
+as a Chrome trace-event span via the ``set_trace_sink`` hook — StatSet
+names, profiler spans and Chrome events are one vocabulary, written from
+one call site.
 """
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
 from typing import Callable, Dict, Optional
 
-#: jax.named_scope, probed ONCE at first use: None = not yet probed,
-#: False = unavailable (import failed — e.g. a stripped-down host env).
-#: The old code re-attempted (and silently re-failed) the import on every
-#: timer_scope call.
-_named_scope = None
-
-#: observability hook: fn(name, start_perf_counter, duration_seconds),
-#: installed by observability.trace when tracing is enabled. Kept as a
-#: plain module global so the no-tracer hot path is one None check.
-_trace_sink: Optional[Callable[[str, float, float], None]] = None
+#: observability hook: fn(name, start_perf_counter, duration_seconds,
+#: args), installed by observability.trace when tracing is enabled. Kept
+#: as a plain module global so the no-tracer hot path is one None check.
+_trace_sink: Optional[Callable[[str, float, float, dict], None]] = None
 
 
-def _resolve_named_scope():
-    global _named_scope
-    if _named_scope is None:
-        try:
-            import jax
-            _named_scope = jax.named_scope
-        except Exception:
-            _named_scope = False
-    return _named_scope
-
-
-def set_trace_sink(fn: Optional[Callable[[str, float, float], None]]):
+def set_trace_sink(fn: Optional[Callable[[str, float, float, dict], None]]):
     """Install (or clear, with None) the span sink timer_scope feeds."""
     global _trace_sink
     _trace_sink = fn
@@ -119,30 +104,42 @@ class StatSet:
 global_stat = StatSet()
 
 
-@contextlib.contextmanager
-def timer_scope(name: str, use_named_scope: bool = True):
-    """REGISTER_TIMER_INFO analog: host wall-clock stat + XLA named scope
-    (+ a Chrome trace span when observability tracing is enabled)."""
-    scope = None
-    if use_named_scope:
-        ns = _resolve_named_scope()
-        if ns:
-            try:
-                scope = ns(name)
-                scope.__enter__()
-            except Exception:
-                scope = None
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dur = time.perf_counter() - t0
-        global_stat.get(name).add(dur)
+class timer_scope:
+    """REGISTER_TIMER_INFO analog, as a context manager: host wall-clock
+    stat + a span in the profiler's own trace (+ a Chrome trace span when
+    observability tracing is enabled). ``args`` become the span's stats
+    in the profile and the Chrome event's ``args``; an arg named
+    ``step_num`` makes the span a ``StepTraceAnnotation``, which is what
+    the profiler groups device ops by. ``seconds`` holds the duration
+    after exit (one clock read per edge: callers that feed a histogram
+    read it instead of timing the interval again)."""
+
+    __slots__ = ("name", "args", "seconds", "_t0", "_ann")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+        self.seconds = 0.0
+
+    def __enter__(self):
+        # jax at first use, not at module import: utils.stat stays
+        # importable (and cheap) in processes that never touch a device
+        from jax import profiler
+        cls = (profiler.StepTraceAnnotation if "step_num" in self.args
+               else profiler.TraceAnnotation)
+        self._ann = cls(self.name, **self.args)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = dur = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        global_stat.get(self.name).add(dur)
         sink = _trace_sink
         if sink is not None:
-            sink(name, t0, dur)
-        if scope is not None:
-            scope.__exit__(None, None, None)
+            sink(self.name, self._t0, dur, self.args)
+        return False
 
 
 def register_timer(name: str):

@@ -16,6 +16,7 @@ persistent compile cache off around them.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,6 +111,28 @@ def test_fused_gru_train_compiles(one_chip, B, H, T, dtype, precision):
     assert n == 2                                   # forward + backward
 
 
+def _mosaic_instructions(compiled):
+    """Names of the compiled program's Mosaic custom-call instructions:
+    what the device trace shows as an op's name (benchmark/trace_reduce
+    `short_name` keeps exactly this)."""
+    return re.findall(r'^\s*%?([\w.\-]+) = [^\n]*custom-call\([^\n]*'
+                      r'custom_call_target="tpu_custom_call"',
+                      compiled.as_text(), re.M)
+
+
+def test_gru_kernels_keep_their_names_in_the_compiled_step(one_chip):
+    """`pl.pallas_call(name=...)` survives JAX's transform stack into the
+    instruction names (`jvp_fused_gru_fwd_.N`, `transpose_jvp_fused_gru_
+    bwd__.N`), at the NMT cell's size (B512 T32 H512, bf16): the kernel
+    layer's roofline metric finds its kernels by these substrings."""
+    compiled, n = _compile(_gru_train, *_gru_args(512, 512, 32, jnp.bfloat16,
+                                                  one_chip, one_chip))
+    names = _mosaic_instructions(compiled)
+    assert n == 2 and len(names) == 2, names
+    assert sum("fused_gru_fwd" in x for x in names) == 1, names
+    assert sum("fused_gru_bwd" in x for x in names) == 1, names
+
+
 # the LSTM classifier (B64/H512/T100) and the split backward past the
 # in-kernel-dW VMEM gate (H1280)
 @pytest.mark.parametrize("B,H,T,dtype,precision", [
@@ -197,6 +220,10 @@ def test_fused_gru_under_data_parallel_compiles(topo):
     args = _gru_args(256, 512, 30, jnp.bfloat16, batch, repl)
     compiled, n = _compile(train, *args)
     assert n == 2
+    # the shard_map wrapper adds to the names, the kernels' own stay
+    names = _mosaic_instructions(compiled)
+    assert any("fused_gru_fwd" in x for x in names) \
+        and any("fused_gru_bwd" in x for x in names), names
     assert "all-reduce" in compiled.as_text()       # the weight gradients
     # and without the wrapper the partitioner refuses, which is why
     with pytest.raises(NotImplementedError, match="partitioned"):

@@ -12,17 +12,23 @@ Pinned here:
   ``timer_scope`` names are subsumed into the same trace buffer,
 - utils/stat thread-safety (the satellite fix: Stat.add was unlocked) and
   the previously-dead ``min`` field surfacing in repr/to_dict,
-- the jax.named_scope probe is cached at module level (no per-call
-  re-import),
+- the train loop's phases (trainer.py ``_phase``): one call site lands
+  each ``paddle:`` span in the profiler's own trace (read back with
+  ``ProfileData``), the step histogram (``feed_convert`` / ``feed_h2d`` /
+  ``compile`` count, no ``compute``) and the Chrome tracer, with its step,
+- every ``pl.pallas_call`` of paddle_tpu/kernels carries a literal,
+  package-unique ``name=``,
 - END-TO-END: a short SGD.train run reports nonzero data-wait and
-  compute splits,
+  drain splits,
 - ACCEPTANCE: instrumentation changes NO jaxpr (train and decode steps
   bit-identical with the exporter/tracer on vs off), and one scrape after
   a real fault-injected training run returns Prometheus text carrying
   step-time, data-wait, checkpoint-latency, and retry-counter series.
 """
 
+import glob
 import json
+import os
 import re
 import socketserver
 import threading
@@ -290,8 +296,9 @@ def test_timer_scope_names_subsumed_into_trace():
     tracer.clear()
     tracer.enable()
     try:
-        with timer_scope("legacy_scope", use_named_scope=False):
+        with timer_scope("legacy_scope", shard=3) as scope:
             pass
+        assert scope.seconds > 0
 
         @register_timer("legacy_deco")
         def f():
@@ -302,8 +309,12 @@ def test_timer_scope_names_subsumed_into_trace():
             pass
     finally:
         tracer.disable()
-    names = [e["name"] for e in tracer.to_chrome_trace()["traceEvents"]]
+    events = tracer.to_chrome_trace()["traceEvents"]
+    names = [e["name"] for e in events]
     assert {"legacy_scope", "legacy_deco", "new_span"} <= set(names)
+    # the sink passes a scope's args on to the Chrome event
+    legacy = next(e for e in events if e["name"] == "legacy_scope")
+    assert legacy["args"] == {"shard": 3}
     d = global_stat.to_dict()
     assert d["legacy_scope"]["count"] >= 1
     assert d["new_span"]["count"] >= 1
@@ -358,17 +369,6 @@ def test_stat_add_thread_safe_and_min_surfaced():
         t.join()
 
 
-def test_named_scope_probe_cached():
-    from paddle_tpu.utils import stat as stat_mod
-
-    with stat_mod.timer_scope("probe_me"):
-        pass
-    # after one call the probe is resolved (jax importable here) and
-    # pinned at module level — no per-call import attempt remains
-    assert stat_mod._named_scope is jax.named_scope
-    assert stat_mod._resolve_named_scope() is jax.named_scope
-
-
 # --- end-to-end through the trainer ---------------------------------------
 
 def _tiny_trainer():
@@ -392,9 +392,9 @@ def _tiny_reader(n=48, batch=8):
     return paddle.batch(synthetic.classification(8, 3, n), batch)
 
 
-def test_sgd_train_reports_data_wait_and_compute_split():
+def test_sgd_train_reports_data_wait_and_drain_split():
     """Tier-1 e2e (satellite): a short SGD.train run produces NONZERO
-    data-wait and compute phase observations in the step histogram."""
+    data-wait and drain phase observations in the step histogram."""
     from paddle_tpu.reader.decorator import buffered
 
     reg = obs_metrics.default_registry
@@ -402,10 +402,10 @@ def test_sgd_train_reports_data_wait_and_compute_split():
                               labels=("phase",))
     before = {p: (step_hist.labels(phase=p).count,
                   step_hist.labels(phase=p).sum)
-              for p in ("data_wait", "compute")}
+              for p in ("data_wait", "drain")}
     trainer = _tiny_trainer()
     trainer.train(buffered(_tiny_reader(), 4, name="e2e"), num_passes=2)
-    for phase in ("data_wait", "compute"):
+    for phase in ("data_wait", "drain"):
         hist = step_hist.labels(phase=phase)
         assert hist.count - before[phase][0] == 12, phase
         assert hist.sum - before[phase][1] > 0, phase
@@ -413,6 +413,142 @@ def test_sgd_train_reports_data_wait_and_compute_split():
                         labels=("reader",)).labels(reader="e2e")
     assert items.value == 12
     assert reg.gauge("paddle_train_examples_per_sec").value > 0
+
+
+# --- the loop's phases: one call site, three sinks ------------------------
+
+PHASES = ("data_wait", "feed", "feed_convert", "feed_h2d", "compile",
+          "dispatch", "drain")
+
+
+@pytest.fixture(scope="module")
+def phase_run(tmp_path_factory):
+    """Three steps of SGD.train at depth 2 on a fresh trainer, under the
+    profiler AND with the Chrome tracer on: what each of the three sinks
+    of ``_phase`` holds afterwards."""
+    from jax.profiler import ProfileData
+
+    hist = obs_metrics.default_registry.histogram(
+        "paddle_train_step_seconds", labels=("phase",))
+    before = {p: hist.labels(phase=p).count for p in PHASES}
+    trainer = _tiny_trainer()
+    first = trainer._batch_counter + 1
+    tracer = obs_trace.global_tracer
+    tracer.clear()
+    tracer.enable()
+    prof_dir = str(tmp_path_factory.mktemp("phase_profile"))
+    jax.profiler.start_trace(prof_dir)
+    try:
+        trainer.train(_tiny_reader(24, 8), num_passes=1, pipeline_depth=2)
+    finally:
+        jax.profiler.stop_trace()
+        tracer.disable()
+    chrome = tracer.to_chrome_trace()["traceEvents"]
+    tracer.clear()
+    (path,) = glob.glob(os.path.join(prof_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            spans = [(e.name[len("paddle:"):], e.start_ns,
+                      e.start_ns + e.duration_ns, dict(e.stats))
+                     for e in line.events if e.name.startswith("paddle:")]
+            if spans:
+                lines.append(spans)
+    counts = {p: hist.labels(phase=p).count - before[p] for p in before}
+    return {"lines": lines, "chrome": chrome, "counts": counts,
+            "steps": [first, first + 1, first + 2]}
+
+
+def test_phases_land_in_the_profilers_own_trace(phase_run):
+    """The seven ``paddle:`` spans sit on ONE host line of the profile
+    (so on the clock the device's ops are on), each with its batch's
+    step; the feed's children lie inside it; a batch's drain carries the
+    step of its dispatch; the one new shape compiled exactly once."""
+    (spans,) = phase_run["lines"]
+    steps = phase_run["steps"]
+    by = {}
+    for name, t0, t1, stats in spans:
+        by.setdefault(name, {}).setdefault(stats["step"], []).append(
+            (t0, t1, stats))
+    for name in ("data_wait", "feed", "feed_convert", "feed_h2d", "drain"):
+        for step in steps:
+            assert len(by[name][step]) == 1, (name, step)
+    # first step of a fresh trainer compiles; the others dispatch, as
+    # step annotations (`step_num`) so the profile groups by them
+    assert list(by["compile"]) == [steps[0]]
+    ((_, _, stats),) = by["compile"][steps[0]]
+    assert stats["key"] == str((("label", (8, 1), False),
+                                ("pixel", (8, 8), False)))
+    assert sorted(by["dispatch"]) == steps[1:]
+    for step in steps[1:]:
+        ((_, _, stats),) = by["dispatch"][step]
+        assert stats["step_num"] == step
+    for step in steps:
+        ((f0, f1, _),) = by["feed"][step]
+        for child in ("feed_convert", "feed_h2d"):
+            ((c0, c1, _),) = by[child][step]
+            assert f0 <= c0 <= c1 <= f1, (child, step)
+        ((conv0, conv1, _),) = by["feed_convert"][step]
+        ((h0, _, _),) = by["feed_h2d"][step]
+        assert conv1 <= h0
+        # the drain of a batch comes after its dispatch / compile
+        run = by["compile" if step == steps[0] else "dispatch"][step]
+        ((_, r1, _),) = run
+        ((d0, _, _),) = by["drain"][step]
+        assert r1 <= d0, step
+
+
+def test_phase_histogram_has_the_split_and_the_compile_count(phase_run):
+    counts = phase_run["counts"]
+    assert counts["compile"] == 1 and counts["dispatch"] == 2
+    for p in ("data_wait", "feed", "feed_convert", "feed_h2d", "drain"):
+        assert counts[p] == 3, p
+    text = obs_metrics.default_registry.to_prometheus()
+    assert 'paddle_train_step_seconds_count{phase="compile"}' in text
+    assert 'phase="compute"' not in text
+
+
+def test_phases_land_in_the_chrome_tracer_with_their_step(phase_run):
+    got = {}
+    for e in phase_run["chrome"]:
+        if e["name"].startswith("paddle:"):
+            got.setdefault(e["name"][len("paddle:"):], []).append(
+                e["args"]["step"])
+    steps = phase_run["steps"]
+    for p in ("feed", "feed_convert", "feed_h2d", "drain"):
+        assert got[p] == steps, p
+    # one more `next` on the reader than batches: the one that ended it
+    assert got["data_wait"][:3] == steps
+    assert got["compile"] == steps[:1] and got["dispatch"] == steps[1:]
+
+
+def test_every_pallas_call_has_a_literal_unique_name():
+    """The device trace names a Mosaic call after its ``name=`` (wrapped
+    by JAX's transform stack): without one the kernel layer's metrics
+    cannot find their kernels. Literal, so a reader can grep for it."""
+    import ast
+
+    kdir = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                        "paddle_tpu", "kernels")
+    names = []
+    for fn in sorted(os.listdir(kdir)):
+        if not fn.endswith(".py"):
+            continue
+        with open(os.path.join(kdir, fn)) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) \
+                    and isinstance(node.func, ast.Attribute) \
+                    and node.func.attr == "pallas_call":
+                kw = {k.arg: k.value for k in node.keywords}
+                assert isinstance(kw.get("name"), ast.Constant) \
+                    and isinstance(kw["name"].value, str), \
+                    f"{fn}:{node.lineno}: pallas_call without a literal name="
+                names.append(kw["name"].value)
+    assert len(names) >= 13
+    assert len(set(names)) == len(names), sorted(names)
+    assert {"fused_gru_fwd", "fused_gru_bwd"} <= set(names)
 
 
 # --- acceptance: jaxpr bit-identity + fault-injected scrape ---------------
@@ -607,12 +743,15 @@ def test_cli_flags_trace_and_file_exporter(tmp_path, monkeypatch):
     with open(trace_path) as f:
         doc = json.load(f)
     names = {e["name"] for e in doc["traceEvents"]}
-    assert "trainBatch" in names and "feedBatch" in names
+    assert {"paddle:data_wait", "paddle:feed", "paddle:feed_convert",
+            "paddle:compile", "paddle:dispatch", "paddle:drain"} <= names
     from tools.metrics_dump import load_file
     snap = load_file(os.path.join(trace_dir, "metrics.jsonl"))
     series = snap["paddle_train_step_seconds"]["series"]
     assert series["phase=data_wait"]["count"] > 0
-    assert series["phase=compute"]["count"] > 0
+    assert series["phase=dispatch"]["count"] > 0
+    assert series["phase=drain"]["count"] > 0
+    assert "phase=compute" not in series
 
 
 class _StubMasterHandler(socketserver.StreamRequestHandler):
@@ -678,7 +817,7 @@ def test_acceptance_fault_injected_run_scrape(tmp_path):
 
     # step-time + the data-wait/compute split
     assert "# TYPE paddle_train_step_seconds histogram" in text
-    for phase in ("data_wait", "compute"):
+    for phase in ("data_wait", "dispatch", "drain"):
         m = re.search(
             rf'paddle_train_step_seconds_count\{{phase="{phase}"\}} (\d+)',
             text)
