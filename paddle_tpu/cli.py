@@ -36,7 +36,7 @@ def cmd_train(args):
 
     for fname in ("log_period", "test_period",
                   "show_parameter_stats_period", "saving_period",
-                  "pipeline_depth", "use_staging_arena",
+                  "pipeline_depth",
                   "pack_sequences", "pack_max_len", "bucket_rounding",
                   "host_table_min_rows", "host_cache_rows"):
         v = getattr(args, fname, None)
@@ -525,11 +525,6 @@ def build_parser():
                    help="pad sequence length to a multiple of N instead "
                         "of the next power of two (bounds per-batch "
                         "waste at N-1 steps; default power-of-two)")
-    t.add_argument("--use_staging_arena", action="store_true",
-                   help="assemble host batches in reusable native-arena "
-                        "buffers (zero steady-state allocation; rotated "
-                        "across pipeline_depth generations — "
-                        "docs/pipeline.md)")
     t.add_argument("--host_table_min_rows", type=int, default=None,
                    help="train sparse_update tables with at least this "
                         "many rows HOST-resident: host-RAM row store + "

@@ -2,7 +2,7 @@
 
 The reference's native components (SURVEY §2 bold rows) that survive the
 TPU redesign as host-side C++: RecordIO data chunk IO, the buddy
-allocator (host staging arena; HBM itself is PJRT-managed), and the
+allocator (a host arena kept for parity; HBM itself is PJRT-managed), and the
 fault-tolerant master task-queue service. Loaded lazily; ``make``
 brings the binaries up to date with their sources first, and a build
 that fails raises with the compiler's output — nothing falls back to a
@@ -152,7 +152,7 @@ class NativeRecordIOReader:
 
 
 class BuddyAllocator:
-    """Host staging-arena allocator (paddle/memory buddy parity)."""
+    """Host arena allocator (paddle/memory buddy parity)."""
 
     def __init__(self, arena_size: int = 1 << 24, min_block: int = 256):
         self._lib = lib = load()

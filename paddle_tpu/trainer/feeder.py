@@ -54,6 +54,59 @@ _M_PADDED_LEN = _obs.gauge(
     "Padded sequence length (T) chosen for the last converted batch of "
     "this feed slot — the paddle_feed_pad_fraction exemplar",
     labels=("feed", "packed"))
+# Where each array a feeder fills came from. A train loop's steady state
+# reads 100% result="reused"; a reader that changes batch size or sequence
+# bucket every step shows up as result="allocated" (as does every bare
+# feeder, which owns no pool).
+_M_BUFFER = _obs.counter(
+    "paddle_feed_buffer_total",
+    "Host arrays a DataFeeder filled, by origin: reused = a kept buffer "
+    "of the owning loop's pool, allocated = fresh memory "
+    "(docs/pipeline.md 'Host-buffer rotation')",
+    labels=("feed", "result"))
+_M_POOL_BYTES = _obs.gauge(
+    "paddle_feed_buffer_bytes",
+    "Host bytes held by the feed buffer pools of this process's train "
+    "loops: generations x one buffer per distinct (slot, role, shape, "
+    "dtype) fed so far")
+
+
+class FeedBufferPool:
+    """The host buffers a train loop's feeder writes its batches into.
+
+    Owned by whoever owns the loop (``SGD`` keeps one across ``train()``
+    calls), because only the owner knows when a batch has been consumed:
+    the feeder rotates through ``rotate_buffers`` generations and the
+    loop guarantees that a generation's step has been forced to finish
+    before that generation comes round again (docs/pipeline.md). One
+    buffer per (slot, role, generation, shape, dtype), plain numpy
+    memory of whatever size the batch needs, never released: the number
+    of distinct feed shapes is what bucketing already bounds for the
+    compiled steps."""
+
+    def __init__(self):
+        self._bufs: Dict[tuple, np.ndarray] = {}
+
+    def get(self, slot, role, gen, shape, dtype):
+        """(array, reused): the buffer of this key; its contents are
+        whatever the key's last user left there."""
+        dtype = np.dtype(dtype)
+        key = (slot, role, gen, tuple(shape), dtype.str)
+        arr = self._bufs.get(key)
+        if arr is not None:
+            return arr, True
+        arr = self._bufs[key] = np.empty(shape, dtype)
+        _M_POOL_BYTES.inc(arr.nbytes)
+        return arr, False
+
+    @property
+    def nbytes(self) -> int:
+        return sum(a.nbytes for a in self._bufs.values())
+
+    def __del__(self):
+        held = self.nbytes
+        if held:
+            _M_POOL_BYTES.dec(held)
 
 
 def _bucket(n: int, bucketing: bool, rounding: Optional[int] = None) -> int:
@@ -117,7 +170,8 @@ def resolve_pack_flags(pack_sequences=None, pack_max_len=None,
 
 class DataFeeder:
     def __init__(self, data_types: Sequence, feeding: Optional[Dict[str, int]] = None,
-                 bucket_seq_len: bool = True, use_staging_arena: bool = False,
+                 bucket_seq_len: bool = True,
+                 buffers: Optional[FeedBufferPool] = None,
                  rotate_buffers: int = 1, pack_sequences: bool = False,
                  pack_max_len: Optional[int] = None,
                  bucket_rounding: Optional[int] = None,
@@ -154,22 +208,19 @@ class DataFeeder:
         exists to prevent on T. 1 disables (exact R; unit-test scale);
         None = the default of 8.
 
-        use_staging_arena: assemble batches into reusable buffers carved
-        from the native buddy-allocator arena (io/staging.py) — the
-        reference's Matrix-reuse behaviour; steady-state batch assembly
-        then allocates nothing. OPT-IN because recycled buffers alias
-        across batches: only enable when every batch is consumed (copied
-        to device) within ``rotate_buffers`` assemblies, and no other
-        feeder shares this feed name. Falls back to numpy when the
-        native library isn't built.
-
-        rotate_buffers: arena-buffer generations to cycle through. The
-        pipelined trainer (docs/pipeline.md) assembles batch N+1 while
-        batch N's async H2D copy may still be in flight, so it creates
-        its feeder with ``rotate_buffers=pipeline_depth``: a buffer is
-        only reused once its batch is >= depth assemblies old, by which
-        point the bounded drain has forced that step (and its input
-        copy) to completion. No-op without the arena.
+        buffers / rotate_buffers: who may hold a feed array, and for how
+        long. A bare ``DataFeeder(...)`` (``buffers=None``) returns
+        arrays of its own on every call; hold as many batches as you
+        like. A feeder built by the owner of a loop (``SGD.train``)
+        writes every array into ``buffers``, a :class:`FeedBufferPool`
+        it keeps, so steady-state assembly allocates nothing; the arrays
+        of call k are then OVERWRITTEN by call k + ``rotate_buffers``.
+        The owner passes the number of batches it keeps unconsumed:
+        ``SGD.train`` passes ``pipeline_depth``, since after dispatching
+        step N it drains until at most depth-1 steps are in flight, so
+        the step that read generation (N+1) % depth has been forced to
+        finish (and its H2D copy with it) before batch N+1 is assembled
+        (docs/pipeline.md "Host-buffer rotation").
         """
         self.data_types = list(data_types)
         if feeding is None:
@@ -193,46 +244,43 @@ class DataFeeder:
                         f"pack_sequences: feed slot {name!r} must be a "
                         "plain index/dense SEQUENCE input (non-sequence, "
                         "nested and sparse slots cannot be packed)")
+        self._pool = buffers
         self._rotate = max(1, int(rotate_buffers))
         self._gen = 0
-        self._arena = None
-        self._arena_overflowed = False
-        if use_staging_arena:
-            from paddle_tpu.io.staging import shared_arena
-            self._arena = shared_arena()
 
-    def _arena_overflow(self, slot):
-        # arena full: plain heap fallback — warn ONCE, because the
-        # opt-in zero-allocation promise just quietly stopped holding
-        # (rotate_buffers multiplies the footprint by the pipeline
-        # depth; resize the arena or lower the depth to get it back)
-        if not self._arena_overflowed:
-            self._arena_overflowed = True
-            from paddle_tpu.utils import logger
-            logger.warning(
-                "staging arena exhausted at feed slot %r (gen %d of %d): "
-                "falling back to per-batch heap allocation", slot,
-                self._gen, self._rotate)
+    def _buffer(self, shape, dtype, slot, role="v", fill=None):
+        """The array a batch is assembled in: the pool's buffer of this
+        (slot, role, generation, shape, dtype) — role tells apart the
+        same-shape arrays of one slot, e.g. a sequence's int32 value and
+        its int32 seg_ids — or fresh memory for a bare feeder. ``fill``
+        None is for callers that overwrite every element."""
+        if self._pool is None:
+            reused = False
+            if fill is None:
+                arr = np.empty(shape, dtype)
+            elif fill == 0:
+                arr = np.zeros(shape, dtype)
+            else:
+                arr = np.full(shape, fill, dtype)
+        else:
+            arr, reused = self._pool.get(slot, role, self._gen, shape,
+                                         dtype)
+            if fill is not None:
+                arr.fill(fill)
+        _M_BUFFER.labels(feed=slot or "unnamed",
+                         result="reused" if reused else "allocated").inc()
+        return arr
 
-    def _zeros(self, shape, dtype, slot, role="v"):
-        # role disambiguates same-shape/dtype buffers of one feed slot
-        # (e.g. a sequence's int32 value vs its int32 seg_ids)
-        if self._arena is not None:
-            try:
-                return self._arena.buffer(f"{slot}:{role}", shape, dtype,
-                                          gen=self._gen)
-            except MemoryError:
-                self._arena_overflow(slot)
-        return np.zeros(shape, dtype)
-
-    def _full(self, shape, fill, dtype, slot, role="v"):
-        if self._arena is not None:
-            try:
-                return self._arena.full(f"{slot}:{role}", shape,
-                                        fill, dtype, gen=self._gen)
-            except MemoryError:
-                self._arena_overflow(slot)
-        return np.full(shape, fill, dtype)
+    def _stack(self, rows, dtype, slot) -> np.ndarray:
+        """``np.asarray(rows, dtype)`` into a kept buffer. One assignment
+        of the whole list runs numpy's own discovery and per-row casting
+        copy, so float32 / uint8 / float64 arrays, nested lists and flat
+        Python lists give the bit-identical batch, and a ragged row
+        raises numpy's ValueError. The buffer takes its row shape from
+        the first row: assigning to any other shape would broadcast."""
+        arr = self._buffer((len(rows),) + np.shape(rows[0]), dtype, slot)
+        arr[...] = rows
+        return arr
 
     def __call__(self, batch: List[Sequence]) -> Dict[str, Arg]:
         self._gen = (self._gen + 1) % self._rotate
@@ -248,9 +296,8 @@ class DataFeeder:
     def _convert_packed(self, batch: List[Sequence]) -> Dict[str, Arg]:
         """Packed-feed conversion: one shared first-fit-decreasing plan
         across slots, then per-slot fill of [R, T] value/mask/seg_ids
-        arrays (arena-backed when enabled — same roles as the unpacked
-        path, so rotate_buffers generations keep pipelined assembly from
-        aliasing an in-flight H2D copy)."""
+        arrays (the same pool, roles and generations as the unpacked
+        path)."""
         cols = {name: self.feeding[name] for name, _ in self.data_types}
         lengths = {name: [len(sample[cols[name]]) for sample in batch]
                    for name, _ in self.data_types}
@@ -293,11 +340,12 @@ class DataFeeder:
 
     def _fill_packed_slot(self, rows, itype, plan, cap, slot, R) -> Arg:
         if itype.kind == "index":
-            value = self._zeros((R, cap), np.int32, slot)
+            value = self._buffer((R, cap), np.int32, slot, fill=0)
         else:
-            value = self._zeros((R, cap, itype.dim), np.float32, slot)
-        mask = self._zeros((R, cap), np.float32, slot, role="mask")
-        seg = self._full((R, cap), -1, np.int32, slot, role="seg")
+            value = self._buffer((R, cap, itype.dim), np.float32, slot,
+                                 fill=0)
+        mask = self._buffer((R, cap), np.float32, slot, "mask", fill=0)
+        seg = self._buffer((R, cap), np.int32, slot, "seg", fill=-1)
         real = 0
         for r, members in enumerate(plan):
             off = 0
@@ -319,25 +367,27 @@ class DataFeeder:
         return Arg(value, mask, seg)
 
     def convert_one(self, rows, itype, slot="") -> Arg:
-        # slot tags arena buffers; callers converting several feeds must
-        # pass distinct slots or same-shape feeds alias one buffer
+        # slot tags the pool's buffers; callers converting several feeds
+        # must pass distinct slots or same-shape feeds alias one buffer
         if not isinstance(itype, InputType):
             # raw ArgInfo from data layers declared with shape only
-            arr = np.asarray(rows, np.float32)
-            return Arg(arr)
+            return Arg(self._stack(rows, np.float32, slot))
         if itype.seq_type == SeqType.NO_SEQUENCE:
             return self._convert_flat(rows, itype, slot)
         return self._convert_seq(rows, itype, slot)
 
     def _convert_flat(self, rows, itype, slot="") -> Arg:
         if itype.kind == "dense":
-            return Arg(np.asarray(rows, np.float32).reshape(len(rows), -1))
+            return Arg(self._stack(rows, np.float32, slot)
+                       .reshape(len(rows), -1))
         if itype.kind == "index":
-            return Arg(np.asarray(rows, np.int32).reshape(len(rows), 1))
+            return Arg(self._stack(rows, np.int32, slot)
+                       .reshape(len(rows), 1))
         # sparse: rows are id lists (or (id, value) lists) -> padded ids
         K = itype.max_ids
-        ids = self._full((len(rows), K), -1, np.int32, slot, role="ids")
-        vals = self._zeros((len(rows), K), np.float32, slot, role="vals")
+        ids = self._buffer((len(rows), K), np.int32, slot, "ids", fill=-1)
+        vals = self._buffer((len(rows), K), np.float32, slot, "vals",
+                            fill=0)
         for i, r in enumerate(rows):
             if itype.kind == "sparse_value":
                 pairs = list(r)[:K]
@@ -381,16 +431,16 @@ class DataFeeder:
                 1.0 - real / float(B * T))
             _M_PADDED_LEN.labels(feed=slot or "unnamed", packed="0").set(T)
         if itype.kind == "index":
-            value = self._zeros((B, T), np.int32, slot)
-            mask = self._zeros((B, T), np.float32, slot, role="mask")
+            value = self._buffer((B, T), np.int32, slot, fill=0)
+            mask = self._buffer((B, T), np.float32, slot, "mask", fill=0)
             for i, r in enumerate(rows):
                 t = min(len(r), T)
                 value[i, :t] = np.asarray(r[:t], np.int32).reshape(t)
                 mask[i, :t] = 1.0
         else:
             dim = itype.dim
-            value = self._zeros((B, T, dim), np.float32, slot)
-            mask = self._zeros((B, T), np.float32, slot, role="mask")
+            value = self._buffer((B, T, dim), np.float32, slot, fill=0)
+            mask = self._buffer((B, T), np.float32, slot, "mask", fill=0)
             for i, r in enumerate(rows):
                 t = min(len(r), T)
                 if t:
@@ -398,7 +448,7 @@ class DataFeeder:
                 mask[i, :t] = 1.0
         seg_ids = None
         if nested:
-            seg_ids = self._full((B, T), -1, np.int32, slot, role="seg")
+            seg_ids = self._buffer((B, T), np.int32, slot, "seg", fill=-1)
             for i, segs in enumerate(seg_rows):
                 t = min(len(segs), T)
                 seg_ids[i, :t] = segs[:t]

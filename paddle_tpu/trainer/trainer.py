@@ -30,7 +30,8 @@ from paddle_tpu.core.topology import Topology
 from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.optimizer import Optimizer
 from paddle_tpu.trainer import event as v2_event
-from paddle_tpu.trainer.feeder import DataFeeder, resolve_pack_flags
+from paddle_tpu.trainer.feeder import (DataFeeder, FeedBufferPool,
+                                       resolve_pack_flags)
 from paddle_tpu.utils import logger
 from paddle_tpu.utils.error import enforce
 from paddle_tpu.utils.flags import FLAGS
@@ -451,6 +452,9 @@ class SGD:
         self._opt_state = None
         self._step_fns: Dict[tuple, Callable] = {}
         self._test_fns: Dict[tuple, Callable] = {}
+        # host buffers train()'s feeder assembles batches in; kept across
+        # train() calls (like _step_fns) so a second call starts warm
+        self._feed_buffers = FeedBufferPool()
         self._donate = donate_params
         self._batch_counter = 0
         # local gradient accumulation (num_batches_per_send_parameter,
@@ -854,7 +858,6 @@ class SGD:
               save_every_n_batches: int = 0, snapshot_dir: str = None,
               resume_state: dict = None, preempt_event=None,
               keep_snapshots: int = 3, pipeline_depth: Optional[int] = None,
-              use_staging_arena: Optional[bool] = None,
               pack_sequences: Optional[bool] = None,
               pack_max_len: Optional[int] = None,
               bucket_rounding: Optional[int] = None,
@@ -893,12 +896,12 @@ class SGD:
         preemption boundaries drain the queue fully first). 0/1 restore
         the strictly synchronous loop.
 
-        ``use_staging_arena`` (None -> the ``use_staging_arena`` flag,
-        default off) assembles host batches in reusable native-arena
-        buffers (io/staging.py — zero steady-state allocation); under
-        pipelining the feeder rotates through ``depth`` buffer
-        generations so an in-flight H2D copy is never aliased. Falls
-        back to numpy when the native library isn't built.
+        The loop's feeder assembles every batch in host buffers this
+        trainer keeps, rotating through ``depth`` generations (the drain
+        bound above is what makes a generation free again by the time it
+        comes round), so steady-state assembly allocates no host memory.
+        A feed array handed to the step is therefore only valid until
+        that step has drained (docs/pipeline.md "Host-buffer rotation").
 
         ``pack_sequences`` (None -> the ``pack_sequences`` flag, default
         off; docs/packing.md): the feeder packs several ragged samples
@@ -955,12 +958,10 @@ class SGD:
         if pipeline_depth is None:
             pipeline_depth = FLAGS.get("pipeline_depth", 2)
         depth = max(1, int(pipeline_depth))
-        if use_staging_arena is None:
-            use_staging_arena = bool(FLAGS.get("use_staging_arena", False))
         pack_sequences, pack_max_len, bucket_rounding = resolve_pack_flags(
             pack_sequences, pack_max_len, bucket_rounding)
         feeder = DataFeeder(self.topology.data_type(), feeding,
-                            use_staging_arena=use_staging_arena,
+                            buffers=self._feed_buffers,
                             rotate_buffers=depth,
                             pack_sequences=pack_sequences,
                             pack_max_len=pack_max_len,

@@ -100,10 +100,6 @@ define_flag("pipeline_depth", 2,
             "train-loop software pipeline depth: up to depth-1 dispatched "
             "steps stay in flight while the host feeds the next batch; "
             "0/1 = strictly synchronous (docs/pipeline.md)")
-define_flag("use_staging_arena", False,
-            "assemble host batches in reusable native buddy-allocator "
-            "buffers (io/staging.py, zero steady-state allocation); "
-            "generation-rotated under pipelining")
 define_flag("host_table_min_rows", 0,
             "sparse_update tables with at least this many rows train "
             "host-resident: host-RAM store + per-batch device row cache "

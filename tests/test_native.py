@@ -204,37 +204,35 @@ def test_master_reader_end_to_end(tmp_path):
         c.close()
 
 
-def test_staging_arena_reuses_buffers():
-    """DataFeeder batch assembly runs over the native buddy-allocator
-    arena: same slot+shape reuses the SAME storage (Matrix-reuse analog),
-    distinct roles never alias, heap fallback preserves values."""
+def test_feed_pool_reuses_buffers():
+    """A loop's feeder assembles batches in its FeedBufferPool: the same
+    slot+role+shape is the SAME storage on the next call (Matrix-reuse
+    analog) and is filled again, distinct roles never alias, and the
+    pool accounts for every byte it holds."""
     import numpy as np
-    import pytest
 
-    from paddle_tpu.io.staging import StagingArena
+    from paddle_tpu.trainer.feeder import DataFeeder, FeedBufferPool
 
-    try:
-        arena = StagingArena(1 << 20)
-    except Exception:
-        pytest.skip("native allocator unavailable")
-    a1 = arena.buffer("x:v", (4, 8), np.float32)
+    pool = FeedBufferPool()
+    f = DataFeeder([], buffers=pool)
+    a1 = f._buffer((4, 8), np.float32, "x", fill=0)
     a1[:] = 7.0
-    a2 = arena.buffer("x:v", (4, 8), np.float32)    # same key: same memory
+    a2 = f._buffer((4, 8), np.float32, "x", fill=0)  # same key: same memory
     assert a2.ctypes.data == a1.ctypes.data
-    assert (a2 == 0).all()                          # re-zeroed per batch
-    b = arena.buffer("x:seg", (4, 8), np.float32)   # other role: distinct
-    assert b.ctypes.data != a1.ctypes.data
-    st = arena.stats()
-    assert st["buffers"] == 2 and st["used"] > 0
-    arena.close()
+    assert (a2 == 0).all()                          # re-filled per batch
+    b = f._buffer((4, 8), np.float32, "x", "seg", fill=-1)  # other role
+    assert b.ctypes.data != a1.ctypes.data and (b == -1).all()
+    c = f._buffer((4, 8), np.float32, "x")          # no fill: left as is
+    assert c.ctypes.data == a1.ctypes.data
+    assert pool.nbytes == 2 * 4 * 8 * 4
 
 
-def test_feeder_arena_batches_match_numpy():
-    """Arena-staged feeds == plain-numpy feeds for every field kind."""
+def test_feeder_pooled_batches_match_fresh():
+    """Pool-assembled feeds == a bare feeder's for every field kind."""
     import numpy as np
 
     from paddle_tpu import data_type
-    from paddle_tpu.trainer.feeder import DataFeeder
+    from paddle_tpu.trainer.feeder import DataFeeder, FeedBufferPool
 
     types = [("d", data_type.dense_vector(3)),
              ("i", data_type.integer_value(5)),
@@ -244,11 +242,8 @@ def test_feeder_arena_batches_match_numpy():
         ([0.1, 0.2, 0.3], 2, [[1.0, 2.0], [3.0, 4.0]], [[1, 2], [3]]),
         ([0.4, 0.5, 0.6], 4, [[5.0, 6.0]], [[4]]),
     ]
-    fa = DataFeeder(types, use_staging_arena=True)
-    fb = DataFeeder(types, use_staging_arena=False)
-    if fa._arena is None:
-        import pytest
-        pytest.skip("native allocator unavailable")
+    fa = DataFeeder(types, buffers=FeedBufferPool())
+    fb = DataFeeder(types)
     for _ in range(3):  # repeated batches: reuse must not corrupt
         ra, rb = fa(batch), fb(batch)
         for k in ("d", "i", "s", "n"):

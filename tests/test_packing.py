@@ -29,7 +29,8 @@ from paddle_tpu.data_type import SeqType
 from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.reader.decorator import checkpointable, sort_within_buffer
 from paddle_tpu.trainer import event as v2_event
-from paddle_tpu.trainer.feeder import DataFeeder, _bucket, _pack_plan
+from paddle_tpu.trainer.feeder import DataFeeder, FeedBufferPool, \
+    _bucket, _pack_plan
 from paddle_tpu.trainer.trainer import SGD
 from paddle_tpu.utils.error import Error
 
@@ -223,15 +224,17 @@ def test_pack_row_rounding_bounds_feed_shapes():
         len(exact.last_pack_plan)
 
 
-def test_feeder_packed_arena_matches_numpy():
+def test_feeder_packed_pooled_matches_fresh():
     types = [("w", data_type.integer_value_sequence(V)),
              ("l", data_type.integer_value_sequence(C))]
     batch = [s for s in SAMPLES[:10]]
     plain = DataFeeder(types, pack_sequences=True)(batch)
-    arena = DataFeeder(types, pack_sequences=True, use_staging_arena=True,
-                       rotate_buffers=2)
+    pooled = DataFeeder(types, pack_sequences=True,
+                        buffers=FeedBufferPool(), rotate_buffers=2)
+    # a denser batch first, so reused buffers hold stale steps to clear
+    pooled([s for s in SAMPLES[10:20]])
     for _ in range(3):          # rotated generations stay correct
-        got = arena(batch)
+        got = pooled(batch)
     for k in plain:
         np.testing.assert_array_equal(np.asarray(plain[k].value),
                                       np.asarray(got[k].value))

@@ -365,29 +365,44 @@ def test_feed_pad_fraction_histogram():
     assert arg.value.shape == (2, 8)
     assert child.count - before[0] == 1
     assert child.sum - before[1] == pytest.approx(0.5)
-    # rotate_buffers is a no-op without the staging arena: conversions
+    # rotate_buffers is a no-op for a bare feeder (no pool): conversions
     # stay correct across consecutive calls
     arg2 = feeder(batch)["w"]
     np.testing.assert_array_equal(np.asarray(arg.value),
                                   np.asarray(arg2.value))
 
 
-def test_staging_arena_pipelined_bit_identical():
-    """use_staging_arena plumbs through SGD.train: batches assembled in
-    generation-rotated arena buffers (or the numpy fallback when the
-    native lib isn't built) still produce the synchronous trajectory
-    bit for bit at any depth."""
-    def run(depth):
-        t = _make_trainer()
-        t.train(paddle.batch(_sample_reader, BATCH), num_passes=2,
-                pipeline_depth=depth, use_staging_arena=True)
-        return _final(t)
+def test_feed_pool_kept_across_train_calls_bit_identical():
+    """SGD.train assembles every batch in the trainer's own pool,
+    rotated through ``depth`` generations, and keeps the pool across
+    train() calls: the second call allocates nothing, and the
+    trajectory is the one a trainer fed from fresh arrays gives, bit
+    for bit, at any depth."""
+    ctr = obs_metrics.default_registry.counter(
+        "paddle_feed_buffer_total", labels=("feed", "result"))
 
-    ref, _ = _run(0)                        # plain numpy feeder reference
-    a, b = run(0), run(3)
-    for k in ref:
-        np.testing.assert_array_equal(a[k], ref[k])
-        np.testing.assert_array_equal(b[k], ref[k])
+    def allocated():
+        return sum(ctr.labels(feed=f, result="allocated").value
+                   for f in ("x", "y"))
+
+    def run(depth, pooled):
+        t = _make_trainer()
+        if not pooled:
+            t._feed_buffers = None          # bare feeder: fresh arrays
+        t.train(paddle.batch(_sample_reader, BATCH), num_passes=1,
+                pipeline_depth=depth)
+        before = allocated()
+        t.train(paddle.batch(_sample_reader, BATCH), num_passes=1,
+                pipeline_depth=depth)
+        return _final(t), allocated() - before
+
+    ref, fresh_allocs = run(0, pooled=False)
+    assert fresh_allocs == 2 * (N // BATCH)     # two arrays a batch
+    for depth in (0, 3):
+        got, allocs = run(depth, pooled=True)
+        assert allocs == 0
+        for k in ref:
+            np.testing.assert_array_equal(got[k], ref[k])
 
 
 def test_prefetch_latch_is_per_shape():
