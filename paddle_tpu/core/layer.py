@@ -121,6 +121,34 @@ def register_layer(type_name: str, infer=None, params=None):
     return deco
 
 
+# Per-step statistics a layer hands out of the jitted step for the operator's
+# counters. A forward puts an array under
+# ctx.extras["step_stats"][<layer type>][<layer name>]; Topology.loss_fn
+# hands the dict out beside the outputs ("#step_stats"), the train loop
+# fetches it where it drains the cost and gives each array to the publisher
+# its layer type registered here. No core code names a layer type.
+STEP_STATS_PUBLISHERS: Dict[str, Callable[[str, Any], None]] = {}
+
+
+def register_step_stats(type_name: str):
+    """Decorator registering publish(layer_name, host array) for the step
+    statistics of one layer type."""
+
+    def deco(publish):
+        STEP_STATS_PUBLISHERS[type_name] = publish
+        return publish
+
+    return deco
+
+
+def publish_step_stats(stats):
+    """{layer type: {layer name: array}} of ONE drained step into the
+    publishers' counters (one device_get for all of it)."""
+    for type_name, by_layer in jax.device_get(stats).items():
+        for lname, value in by_layer.items():
+            STEP_STATS_PUBLISHERS[type_name](lname, value)
+
+
 def _infer_identity(cfg, in_infos):
     enforce(len(in_infos) >= 1, f"layer {cfg.name} needs >=1 input")
     return in_infos[0]

@@ -387,6 +387,12 @@ class Topology:
                 else:
                     total = total + jnp.sum(v) / v.shape[0]  # mean over batch
             aux = self.aux_updates(ctx)
+            if "step_stats" in ctx.extras:
+                # what layers hand to the operator's counters
+                # (core/layer.py register_step_stats); '#' keeps the key
+                # out of the layer-name space, the train step hands it
+                # out beside its metrics
+                outs = {**outs, "#step_stats": ctx.extras["step_stats"]}
             if sparse_tangents is not None:
                 # reserved key popped by make_train_step; only present when
                 # the caller opted into the sparse-grad protocol, so plain

@@ -95,6 +95,51 @@ def _beam_inner_numel(l) -> int:
     return total
 
 
+def _numel(shapes, *suffixes) -> int:
+    return sum(int(np.prod(shapes[s])) for s in suffixes)
+
+
+def _gated_attention_flops(l, shapes, T) -> float:
+    """Per row: the four projections, and the causal scores and weighted
+    sum (each query against the keys at or before it)."""
+    H, D = l.attr("num_heads"), l.attr("head_dim")
+    proj = 2.0 * T * _numel(shapes, "wq", "wk", "wv", "wo")
+    return proj + 2 * 2.0 * H * D * T * (T + 1) / 2
+
+
+def _gated_delta_net_flops(l, shapes, T) -> float:
+    """Per row: the projections, the depthwise convolution, and the delta
+    rule as its recurrence defines it (three dk x dv matrix-vector products
+    a token and value head: read, write, query), not as the chunked form
+    computes it."""
+    Hv = l.attr("num_v_heads")
+    dk, dv = l.attr("head_k_dim"), l.attr("head_v_dim")
+    proj = 2.0 * T * _numel(shapes, "wqkvz", "wba", "wout", "conv")
+    return proj + 2.0 * T * Hv * 3 * dk * dv
+
+
+def _moe_ffn_flops(l, shapes, T) -> float:
+    """Per row: the router over all experts, the shared expert and its
+    gate, and the routed experts a token reaches HERE: of its top_k choices
+    the share experts_held / num_experts in expectation, three d x I
+    products each. Not all the weights held."""
+    E, held, k = l.attr("num_experts"), l.attr("experts_held"), l.attr("top_k")
+    dense = _numel(shapes, "router", "shared_gate", "shared_wg", "shared_wu",
+                   "shared_wd")
+    one_expert = _numel(shapes, "wg", "wu", "wd") / held
+    return 2.0 * T * (dense + k * held / E * one_expert)
+
+
+# per-row forward FLOPs of the decoder-block layers, whose work is not
+# "positions x weights held": (layer, {suffix: shape}, seq_len) -> FLOPs
+_DECODER_FLOPS = {
+    "rms_norm": lambda l, shapes, T: 0.0,        # elementwise
+    "gated_attention": _gated_attention_flops,
+    "gated_delta_net": _gated_delta_net_flops,
+    "moe_ffn": _moe_ffn_flops,
+}
+
+
 def layer_fwd_flops(topo, l, batch: int, seq_len: int = 1,
                     decode_ticks: Optional[int] = None) -> float:
     """Forward multiply-add FLOPs ONE layer contributes to a batch — the
@@ -107,6 +152,11 @@ def layer_fwd_flops(topo, l, batch: int, seq_len: int = 1,
         # gathers are omitted" made concrete (pricing the [V, D]
         # table as a dense multiply would swamp real decode work)
         return 0.0
+    if l.type in _DECODER_FLOPS:
+        specs = topo.param_specs()
+        shapes = {sfx: specs[pn].shape
+                  for sfx, pn in topo._layer_params[l.name].items()}
+        return float(batch * _DECODER_FLOPS[l.type](l, shapes, seq_len))
     numel = _weight_numels(topo, l.name)
     if numel == 0 and l.type not in ("recurrent_layer_group",
                                      "beam_search"):

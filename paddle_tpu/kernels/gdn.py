@@ -1,0 +1,267 @@
+"""The gated delta rule in chunks (Gated DeltaNet, arXiv:2412.06464).
+
+Per head, with state S [dk, dv] from zero:
+
+    S <- exp(g_t) S;  S <- S + k_t (beta_t (v_t - S^T k_t))^T;  o_t = S^T q_t
+
+computed C tokens at a time in the WY / UT-transform form. With gamma the
+running sum of g inside a chunk and decay[i, j] = exp(gamma_i - gamma_j):
+
+    inside a chunk (plain batched products, ``chunk_prepare``)
+        A   = tril(beta K K^T * decay, -1)
+        T   = (I + A)^-1
+        U   = T (beta V)             W   = T (beta K exp(gamma))
+        Aqk = tril(Q K^T * decay)    Q~  = Q exp(gamma)
+        K~  = K exp(gamma_C - gamma) d   = exp(gamma_C)
+    across chunks (the state pass, sequential)
+        Vn  = U - W S;  O = Q~ S + Aqk Vn;  S <- d S + K~^T Vn
+
+The state pass is the part that is a recurrence: ``state_pass_scan`` is its
+``lax.scan`` form (the path everywhere but the TPU, and what the CPU tests
+pin the kernels to); ``state_pass_kernel`` runs it as the Mosaic kernels
+``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` under a ``jax.custom_vjp`` with S
+resident in VMEM over a (batch x head) row's chunks.
+
+g, gamma, the decay products, T and S are float32 (wider under an f64
+gradient check); the products take their operands in the dtype q, k, v come
+in (bf16 under the mixed-precision policy) and accumulate in float32.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.kernels._pallas_util import VMEM_LIMIT_BYTES
+
+CHUNK = 64
+
+
+def _mm(a, b, dtype, acc):
+    return jnp.matmul(a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=acc)
+
+
+def chunk_prepare(q, k, v, g, beta, chunk):
+    """q, k [B, T, H, dk] (q already scaled), v [B, T, H, dv], g, beta
+    [B, T, H] -> (W, U, Qt, Kt, Aqk, d) laid out [B*H, NC, C, .] (d:
+    [B*H, NC, 1, 1]) for the state pass; T is padded to whole chunks with
+    tokens that write nothing (k = 0, beta = 0, g = 0)."""
+    B, T, H, dk = k.shape
+    dv, C = v.shape[-1], chunk
+    dtype = v.dtype
+    acc = jnp.promote_types(dtype, jnp.float32)
+    NC = -(-T // C)
+
+    def lay(x):
+        x = jnp.pad(x, [(0, 0), (0, NC * C - T)] + [(0, 0)] * (x.ndim - 2))
+        x = jnp.moveaxis(x, 2, 1)                       # [B, H, T, ...]
+        return x.reshape((B * H, NC, C) + x.shape[3:])
+
+    q, k, v = lay(q), lay(k), lay(v)
+    g, beta = lay(g.astype(acc)), lay(beta.astype(acc))
+    gamma = jnp.cumsum(g, axis=-1)                      # [BH, NC, C]
+    i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    diff = gamma[..., :, None] - gamma[..., None, :]
+    decay = jnp.exp(jnp.where(i >= j, diff, -jnp.inf))  # 0 above the diagonal
+    kb = k.astype(acc) * beta[..., None]
+    kk = _mm(kb, jnp.swapaxes(k, -1, -2), dtype, acc)
+    A = jnp.where(i > j, kk * decay, 0.0)
+    eye = jnp.eye(C, dtype=acc)
+    Tm = jax.scipy.linalg.solve_triangular(
+        A + eye, jnp.broadcast_to(eye, A.shape), lower=True,
+        unit_diagonal=True)
+    U = _mm(Tm, v.astype(acc) * beta[..., None], dtype, acc)
+    W = _mm(Tm, kb * jnp.exp(gamma)[..., None], dtype, acc)
+    Aqk = _mm(q, jnp.swapaxes(k, -1, -2), dtype, acc) * decay
+    Qt = q.astype(acc) * jnp.exp(gamma)[..., None]
+    last = gamma[..., -1:]
+    Kt = k.astype(acc) * jnp.exp(last - gamma)[..., None]
+    d = jnp.exp(last)[..., None]                        # [BH, NC, 1, 1]
+    return tuple(x.astype(dtype) for x in (W, U, Qt, Kt, Aqk)) + (d,)
+
+
+def state_pass_scan(W, U, Qt, Kt, Aqk, d):
+    """O [BH, NC, C, dv] from the chunk quantities, chunk after chunk."""
+    dtype = U.dtype
+    acc = jnp.promote_types(dtype, jnp.float32)
+    BH, dk, dv = W.shape[0], W.shape[-1], U.shape[-1]
+
+    def step(S, xs):
+        w, u, qt, kt, aqk, dd = xs
+        vn = u.astype(acc) - _mm(w, S, dtype, acc)
+        o = _mm(qt, S, dtype, acc) + _mm(aqk, vn, dtype, acc)
+        S = dd * S + _mm(jnp.swapaxes(kt, -1, -2), vn, dtype, acc)
+        return S, o.astype(dtype)
+
+    xs = tuple(jnp.moveaxis(x, 1, 0) for x in (W, U, Qt, Kt, Aqk, d))
+    _, O = jax.lax.scan(step, jnp.zeros((BH, dk, dv), acc), xs)
+    return jnp.moveaxis(O, 0, 1)
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, state_pass=state_pass_scan):
+    """o [B, T, H, dv] of the recurrence in the module docstring."""
+    B, T, H, _ = k.shape
+    dv = v.shape[-1]
+    O = state_pass(*chunk_prepare(q, k, v, g, beta, chunk))
+    return jnp.moveaxis(O.reshape(B, H, -1, dv)[:, :, :T], 1, 2)
+
+
+# ---- the state pass as Mosaic kernels --------------------------------------
+
+def _nt(a, b):
+    """a [m, k] x b [n, k]^T -> [m, n], float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _fwd_kernel(w_ref, u_ref, qt_ref, ktT_ref, aqk_ref, d_ref,
+                o_ref, s0_ref, s_scr):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_scr[:] = jnp.zeros_like(s_scr)
+
+    dt = u_ref.dtype
+    S = s_scr[:]
+    s0_ref[0, 0] = S
+    Sb = S.astype(dt)
+    vn = u_ref[0, 0].astype(jnp.float32) - _dot(w_ref[0, 0], Sb)
+    vnb = vn.astype(dt)
+    o = _dot(qt_ref[0, 0], Sb) + _dot(aqk_ref[0, 0], vnb)
+    o_ref[0, 0] = o.astype(o_ref.dtype)
+    s_scr[:] = d_ref[0, 0] * S + _dot(ktT_ref[0, 0], vnb)
+
+
+def _bwd_kernel(w_ref, wT_ref, u_ref, qtT_ref, kt_ref, aqkT_ref, d_ref,
+                s0_ref, do_ref,
+                dw_ref, du_ref, dqt_ref, dkt_ref, daqk_ref, dd_ref, ds_scr):
+    @pl.when(pl.program_id(1) == 0)          # the LAST chunk: grid runs back
+    def _():
+        ds_scr[:] = jnp.zeros_like(ds_scr)
+
+    dt = u_ref.dtype
+    S = s0_ref[0, 0]
+    Sb = S.astype(dt)
+    dS = ds_scr[:]                           # gradient of the state AFTER
+    dSb = dS.astype(dt)
+    do = do_ref[0, 0]
+    vnb = (u_ref[0, 0].astype(jnp.float32) - _dot(w_ref[0, 0], Sb)).astype(dt)
+    dvn = _dot(aqkT_ref[0, 0], do) + _dot(kt_ref[0, 0], dSb)
+    dvnb = dvn.astype(dt)
+    du_ref[0, 0] = dvnb
+    daqk_ref[0, 0] = _nt(do, vnb).astype(daqk_ref.dtype)
+    dqt_ref[0, 0] = _nt(do, Sb).astype(dqt_ref.dtype)
+    dkt_ref[0, 0] = _nt(vnb, dSb).astype(dkt_ref.dtype)
+    dw_ref[0, 0] = (-_nt(dvnb, Sb)).astype(dw_ref.dtype)
+    dd_ref[0, 0] = jnp.sum(dS * S, axis=0, keepdims=True)
+    ds_scr[:] = d_ref[0, 0] * dS + _dot(qtT_ref[0, 0], do) \
+        - _dot(wT_ref[0, 0], dvnb)
+
+
+def _block(shape, rev_of=None):
+    """One (row, chunk) block of an array [BH, NC, ...]."""
+    if rev_of is None:
+        index = lambda b, c: (b, c, 0, 0)
+    else:
+        index = lambda b, c: (b, rev_of - 1 - c, 0, 0)
+    return pl.BlockSpec((1, 1) + tuple(shape[2:]), index,
+                        memory_space=pltpu.VMEM)
+
+
+def _params(interpret):
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        dimension_semantics=("parallel", "arbitrary"))}
+
+
+def _dvec(d, dv):
+    return jnp.broadcast_to(d.astype(jnp.float32), d.shape[:3] + (dv,))
+
+
+def _fwd_call(W, U, Qt, Kt, Aqk, d, interpret):
+    BH, NC, C, dk = W.shape
+    dv = U.shape[-1]
+    ins = (W, U, Qt, jnp.swapaxes(Kt, -1, -2), Aqk, _dvec(d, dv))
+    outs = (jax.ShapeDtypeStruct((BH, NC, C, dv), U.dtype),
+            jax.ShapeDtypeStruct((BH, NC, dk, dv), jnp.float32))
+    return pl.pallas_call(
+        _fwd_kernel, name="gdn_chunk_fwd", grid=(BH, NC),
+        in_specs=[_block(x.shape) for x in ins],
+        out_specs=[_block(x.shape) for x in outs], out_shape=list(outs),
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        interpret=interpret, **_params(interpret))(*ins)
+
+
+def _bwd_call(W, U, Qt, Kt, Aqk, d, S0, dO, interpret):
+    BH, NC, C, dk = W.shape
+    dv, dt = U.shape[-1], U.dtype
+    T_ = lambda x: jnp.swapaxes(x, -1, -2)
+    ins = (W, T_(W), U, T_(Qt), Kt, T_(Aqk), _dvec(d, dv), S0, dO.astype(dt))
+    outs = (jax.ShapeDtypeStruct(W.shape, dt), jax.ShapeDtypeStruct(U.shape, dt),
+            jax.ShapeDtypeStruct(Qt.shape, dt), jax.ShapeDtypeStruct(Kt.shape, dt),
+            jax.ShapeDtypeStruct(Aqk.shape, dt),
+            jax.ShapeDtypeStruct((BH, NC, 1, dv), jnp.float32))
+    return pl.pallas_call(
+        _bwd_kernel, name="gdn_chunk_bwd", grid=(BH, NC),
+        in_specs=[_block(x.shape, rev_of=NC) for x in ins],
+        out_specs=[_block(x.shape, rev_of=NC) for x in outs],
+        out_shape=list(outs),
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        interpret=interpret, **_params(interpret))(*ins)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def state_pass_kernel(W, U, Qt, Kt, Aqk, d, interpret=False):
+    """``state_pass_scan`` as the Mosaic kernels ``gdn_chunk_fwd`` /
+    ``gdn_chunk_bwd``: grid (rows, chunks), S (and in the backward pass its
+    gradient) resident in VMEM over a row's chunks. The forward call also
+    hands out every chunk's starting state (float32) for the backward one."""
+    return _fwd_call(W, U, Qt, Kt, Aqk, d, interpret)[0]
+
+
+def _state_pass_fwd(W, U, Qt, Kt, Aqk, d, interpret):
+    O, S0 = _fwd_call(W, U, Qt, Kt, Aqk, d, interpret)
+    return O, (W, U, Qt, Kt, Aqk, d, S0)
+
+
+def _state_pass_bwd(interpret, res, dO):
+    W, U, Qt, Kt, Aqk, d, S0 = res
+    dW, dU, dQt, dKt, dAqk, dd = _bwd_call(W, U, Qt, Kt, Aqk, d, S0, dO,
+                                           interpret)
+    return dW, dU, dQt, dKt, dAqk, \
+        jnp.sum(dd, axis=-1, keepdims=True).astype(d.dtype)
+
+
+state_pass_kernel.defvjp(_state_pass_fwd, _state_pass_bwd)
+
+
+def kernel_supported(dk, dv, chunk, dtype):
+    return dk % 128 == 0 and dv % 128 == 0 and chunk % 16 == 0 \
+        and dtype in (jnp.bfloat16, jnp.float32)
+
+
+def pick_state_pass(who, dk, dv, chunk, dtype):
+    """The state pass a layer takes: the Mosaic kernels on the TPU where
+    their gate passes, the scan elsewhere (and under a data-parallel GSPMD
+    step, whose batch the row-by-row mixer does not hand to ``call_kernel``
+    shard by shard)."""
+    from paddle_tpu.kernels._pallas_util import (batch_shards, call_kernel,
+                                                 take_pallas)
+
+    ok = kernel_supported(dk, dv, chunk, dtype)
+    why = f"dk {dk}, dv {dv}, chunk {chunk}, {jnp.dtype(dtype).name} is " \
+        "outside the kernel's gate" if not ok else \
+        "the batch is sharded over a mesh"
+    if take_pallas(who, "gdn_chunk_fwd/bwd", ok and batch_shards() == 1, why):
+        return lambda *xs: call_kernel(state_pass_kernel, xs, range(6))
+    return state_pass_scan

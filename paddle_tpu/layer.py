@@ -827,6 +827,55 @@ def multi_head_attention(query, key_value=None, size=None, num_heads=8,
 __all__ += ["multi_head_attention"]
 
 
+# --- decoder-only blocks (docs/qwen3_next.md) -----------------------------
+
+def rms_norm(input, eps=1e-6, name=None, param_attr=None, layer_attr=None):
+    """x * rsqrt(mean(x^2) + eps) * (1 + w) over the features, w from 0."""
+    return Layer("rms_norm", [input], name=name, eps=eps,
+                 param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
+
+
+def gated_attention(input, num_heads, num_kv_heads, head_dim, rotary_dim,
+                    rope_theta=10000.0, eps=1e-6, query_block=512, scope=None,
+                    name=None, param_attr=None, layer_attr=None):
+    """Causal grouped-query self-attention with per-head q/k RMS norm,
+    rotary positions on the first ``rotary_dim`` of each head and a sigmoid
+    output gate; computed ``query_block`` queries at a time."""
+    return Layer("gated_attention", [input], name=name, num_heads=num_heads,
+                 num_kv_heads=num_kv_heads, head_dim=head_dim,
+                 rotary_dim=rotary_dim, rope_theta=rope_theta, eps=eps,
+                 query_block=query_block, scope=scope,
+                 param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
+
+
+def gated_delta_net(input, num_k_heads, num_v_heads, head_k_dim, head_v_dim,
+                    conv_kernel=4, eps=1e-6, chunk=64, scope=None, name=None,
+                    param_attr=None, layer_attr=None):
+    """Gated DeltaNet linear-attention mixer (the chunked gated delta rule
+    behind a causal depthwise convolution)."""
+    return Layer("gated_delta_net", [input], name=name,
+                 num_k_heads=num_k_heads, num_v_heads=num_v_heads,
+                 head_k_dim=head_k_dim, head_v_dim=head_v_dim,
+                 conv_kernel=conv_kernel, eps=eps, chunk=chunk, scope=scope,
+                 param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
+
+
+def moe_ffn(input, num_experts, top_k, expert_size, shared_size,
+            experts_held=None, first_expert=0, tile=256, scope=None, name=None,
+            param_attr=None, layer_attr=None):
+    """Top-k mixture of gated-MLP experts with a gated shared expert. The
+    router is over all ``num_experts``; the layer holds (and computes) the
+    experts [first_expert, first_expert + experts_held) only."""
+    return Layer("moe_ffn", [input], name=name, num_experts=num_experts,
+                 top_k=top_k, expert_size=expert_size, shared_size=shared_size,
+                 experts_held=experts_held or num_experts,
+                 first_expert=first_expert, tile=tile, scope=scope,
+                 param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
+
+
+__all__ += ["rms_norm", "gated_attention", "gated_delta_net", "moe_ffn"]
+
+
 # --- detection (SSD) ------------------------------------------------------
 
 def priorbox(input, image=None, min_size=None, max_size=None,

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.arg import Arg, ArgInfo
 from paddle_tpu.core.layer import ParamSpec, register_layer
+from paddle_tpu.layers.norm import const_init, rms_normalize
 from paddle_tpu.utils.error import enforce
 
 
@@ -111,3 +112,101 @@ def _mha_forward(cfg, params, ins, ctx):
     if q_in.mask is not None:
         out = out * q_in.mask[..., None].astype(out.dtype)
     return Arg(out, q_in.mask, q_in.seg_ids)
+
+
+# --- gated grouped-query attention ------------------------------------------
+
+def _gattn_params(cfg, in_infos):
+    d = in_infos[0].size
+    H, Hkv, D = cfg.attr("num_heads"), cfg.attr("num_kv_heads"), cfg.attr("head_dim")
+    a = cfg.param_attr(0)
+    return {
+        "wq": ParamSpec((d, H * 2 * D), a, fan_in=d),
+        "wk": ParamSpec((d, Hkv * D), a, fan_in=d),
+        "wv": ParamSpec((d, Hkv * D), a, fan_in=d),
+        "wo": ParamSpec((H * D, d), a, fan_in=H * D),
+        "q_norm": ParamSpec((D,), const_init(a, 0.0), fan_in=D),
+        "k_norm": ParamSpec((D,), const_init(a, 0.0), fan_in=D),
+    }
+
+
+def rotary(x, theta, rot):
+    """Rotate-half rotary positions 0..T-1 on the first ``rot`` of the last
+    axis of x [B, T, heads, D]; angles in float32."""
+    T, half = x.shape[1], rot // 2
+    inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None]
+    xr, xp = x[..., :rot], x[..., rot:]
+    turned = jnp.concatenate([-xr[..., half:], xr[..., :half]], -1)
+    out = xr * cos.astype(x.dtype) + turned * sin.astype(x.dtype)
+    return jnp.concatenate([out, xp], -1)
+
+
+def causal_gqa(q, k, v, block):
+    """Causal softmax attention, q [B, T, Hkv, G, D] against k, v
+    [B, T, Hkv, D], one block of ``block`` queries at a time against the
+    keys at or before the block's last query: the scores alive at once are
+    [B, Hkv, G, block, <=T], float32, and every block computes its own
+    again in the backward pass."""
+    B, T, N, G, D = q.shape
+    acc = jnp.promote_types(q.dtype, jnp.float32)
+    scale = D ** -0.5
+
+    @jax.checkpoint
+    def one(qb, kb, vb, start):
+        s = jnp.einsum("bqngd,bknd->bngqk", qb, kb,
+                       preferred_element_type=acc) * scale
+        qpos = start + jnp.arange(qb.shape[1])
+        keep = qpos[:, None] >= jnp.arange(kb.shape[1])[None, :]
+        a = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+        return jnp.einsum("bngqk,bknd->bqngd", a.astype(vb.dtype), vb)
+
+    outs = []
+    for start in range(0, T, block):
+        end = min(start + block, T)
+        outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
+    return jnp.concatenate(outs, axis=1)
+
+
+def rows_one_at_a_time(mixer, x, params):
+    """``mixer(row [T, d], params)`` over the rows of x [B, T, d], one after
+    the other, each row's forward computed again in its backward pass: what
+    is alive at once is one row's activations, not the batch's (a row of
+    4096 tokens fills the MXU on its own)."""
+    return jax.lax.map(jax.checkpoint(lambda row: mixer(row, params)), x)
+
+
+@register_layer("gated_attention", params=_gattn_params)
+def _gated_attention_forward(cfg, params, ins, ctx):
+    """Causal grouped-query self-attention with per-head q/k RMS norm
+    (1 + w), partial rotary positions and a sigmoid output gate read from
+    the query projection. No bias. Padding follows the real tokens of a
+    row, so causality keeps it out of them."""
+    enforce(not getattr(ctx, "packed", False),
+            f"gated_attention {cfg.name}: packed rows need a segment mask "
+            "this layer does not have")
+    x = ins[0].value
+    B, T, _ = x.shape
+    H, Hkv, D = cfg.attr("num_heads"), cfg.attr("num_kv_heads"), cfg.attr("head_dim")
+    eps, rot = cfg.attr("eps", 1e-6), cfg.attr("rotary_dim")
+
+    def mixer(x, p):
+        """One row [T, d]."""
+        qg = jnp.matmul(x, p["wq"]).reshape(1, T, H, 2 * D)
+        q, gate = qg[..., :D], qg[..., D:]
+        k = jnp.matmul(x, p["wk"]).reshape(1, T, Hkv, D)
+        v = jnp.matmul(x, p["wv"]).reshape(1, T, Hkv, D)
+        q = rms_normalize(q, eps) * (1 + p["q_norm"]).astype(x.dtype)
+        k = rms_normalize(k, eps) * (1 + p["k_norm"]).astype(x.dtype)
+        q = rotary(q, cfg.attr("rope_theta"), rot)
+        k = rotary(k, cfg.attr("rope_theta"), rot)
+        o = causal_gqa(q.reshape(1, T, Hkv, H // Hkv, D), k, v,
+                       cfg.attr("query_block", 512))
+        o = o.reshape(T, H * D) * jax.nn.sigmoid(gate.reshape(T, H * D))
+        return jnp.matmul(o, p["wo"])
+
+    with jax.named_scope(cfg.attr("scope") or cfg.name):
+        out = rows_one_at_a_time(mixer, x, params)
+    return ins[0].with_value(out)

@@ -1,0 +1,238 @@
+"""Sparse mixture of experts, as one chip's share of an expert-parallel
+layer (docs/qwen3_next.md).
+
+    p = softmax(x Wr) over ALL num_experts (float32); the top k of p,
+    renormalised to sum 1; routed = sum over the chosen experts e THAT THIS
+    LAYER HOLDS, [first_expert, first_expert + experts_held), of
+    p_e (silu(x Wg_e) * (x Wu_e)) Wd_e;
+    shared = sigmoid(x w_sg) * (silu(x Wg_s) * (x Wu_s)) Wd_s;
+    out = routed + shared
+
+What the absent experts would add is left out: the other shares of the layer
+hold them, and on one chip nothing stands in for the exchange that would
+bring their tokens here and send these results back.
+
+Dispatch is drop-free with one compiled shape. The (token, chosen and held
+expert) pairs are laid out expert by expert (a counting sort: a pair's place
+is its expert's offset plus its rank among that expert's pairs), each
+expert's run padded to whole tiles of ``tile`` rows, in a buffer sized for
+the worst case (every token choosing min(k, held) held experts). The grouped
+products walk only the tiles in use, a loop whose trip count is data:
+gather the tile's tokens, three products with the tile's expert, weighted
+scatter-add back. Its backward pass is written out under a custom_vjp (a
+loop with a data-dependent trip count has no automatic transpose).
+
+Why not jax.lax.ragged_dot over the sorted rows: on the TPU its cost follows
+the rows of the buffer, which are static, and not the group sizes, which are
+data; a drop-free buffer is the worst case, 32 times the expected load at
+16 of 512 experts held. Measured on a v5e at 16,384 tokens, forward +
+backward of the routed part (docs/qwen3_next.md has the table): 58.1 ms
+against this loop's 17.3 ms at the expected 5,174 pairs. It also leaves the
+rows past the last group undefined, in its lhs gradient too. Per row computed
+it is the faster of the two (36.6 ms against 63.2 ms at 60,421 pairs in a
+65,536-row buffer): a choice among a few buffer sizes by the pairs in hand
+is left to a perf_opt issue (PERF.md section 7).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core.layer import (ParamSpec, register_layer,
+                                   register_step_stats)
+from paddle_tpu.observability import metrics as obs_metrics
+
+STATS = ("held", "elsewhere", "load_max_over_mean", "dropped")
+
+_M_TOKENS = obs_metrics.counter(
+    "paddle_moe_tokens_total",
+    "(token, chosen expert) pairs a moe_ffn layer routed, by where the "
+    "expert lives: held (computed by this layer) or elsewhere (an expert "
+    "of another share; its contribution is left out here)",
+    labels=("layer", "result"))
+_M_LOAD = obs_metrics.gauge(
+    "paddle_moe_expert_load_max_over_mean",
+    "Pairs of the busiest held expert over the mean of the held experts, "
+    "in the last drained step", labels=("layer",))
+_M_DROPPED = obs_metrics.counter(
+    "paddle_moe_dropped_total",
+    "Held (token, expert) pairs the dispatch buffer had no row for; the "
+    "buffer is sized for the worst case, so this stays 0")
+
+
+@register_step_stats("moe_ffn")
+def _publish_stats(lname, vec):
+    """One drained step's STATS vector of one layer into the counters."""
+    held, elsewhere, load, dropped = (float(v) for v in vec)
+    _M_TOKENS.labels(layer=lname, result="held").inc(held)
+    _M_TOKENS.labels(layer=lname, result="elsewhere").inc(elsewhere)
+    _M_LOAD.labels(layer=lname).set(load)
+    _M_DROPPED.inc(dropped)
+
+
+def _moe_params(cfg, in_infos):
+    d = in_infos[0].size
+    E, held = cfg.attr("num_experts"), cfg.attr("experts_held")
+    I, Is = cfg.attr("expert_size"), cfg.attr("shared_size")
+    a = cfg.param_attr(0)
+    return {
+        "router": ParamSpec((d, E), a, fan_in=d),
+        "wg": ParamSpec((held, d, I), a, fan_in=d),
+        "wu": ParamSpec((held, d, I), a, fan_in=d),
+        "wd": ParamSpec((held, I, d), a, fan_in=I),
+        "shared_gate": ParamSpec((d, 1), a, fan_in=d),
+        "shared_wg": ParamSpec((d, Is), a, fan_in=d),
+        "shared_wu": ParamSpec((d, Is), a, fan_in=d),
+        "shared_wd": ParamSpec((Is, d), a, fan_in=Is),
+    }
+
+
+def _acc(dtype):
+    return jnp.promote_types(dtype, jnp.float32)
+
+
+def _tile_rows(rows, t, tile):
+    return jax.lax.dynamic_slice_in_dim(rows, t * tile, tile)
+
+
+def _tile_forward(x, wg, wu, wd, tok, e):
+    """One tile's tokens through expert e: (xt, a, b, h, y), y in float32."""
+    acc = _acc(x.dtype)
+    xt = jnp.take(x, tok, axis=0, mode="clip")
+    a = jnp.dot(xt, wg[e], preferred_element_type=acc)
+    b = jnp.dot(xt, wu[e], preferred_element_type=acc)
+    h = (jax.nn.silu(a) * b).astype(x.dtype)
+    return xt, a, b, h, jnp.dot(h, wd[e], preferred_element_type=acc)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def grouped_ffn(x, wg, wu, wd, row_w, row_tok, tile_expert, n_tiles, tile):
+    """sum over rows r of row_w[r] * expert(x[row_tok[r]]) scattered to
+    token row_tok[r]: x [N, d]; wg, wu [G, d, I]; wd [G, I, d]; row_w, row_tok
+    [R] (padding rows: token N, weight 0); tile_expert [R / tile]; only the
+    first n_tiles tiles hold rows. Returns [N, d] in x's dtype."""
+    return _grouped_fwd(x, wg, wu, wd, row_w, row_tok, tile_expert, n_tiles,
+                        tile)[0]
+
+
+def _grouped_fwd(x, wg, wu, wd, row_w, row_tok, tile_expert, n_tiles, tile):
+    acc = _acc(x.dtype)
+
+    def body(t, y):
+        tok, w = _tile_rows(row_tok, t, tile), _tile_rows(row_w, t, tile)
+        yt = _tile_forward(x, wg, wu, wd, tok, tile_expert[t])[-1]
+        return y.at[tok].add(w[:, None].astype(acc) * yt, mode="drop")
+
+    with jax.named_scope("moe_grouped_ffn_fwd"):
+        y = jax.lax.fori_loop(0, n_tiles, body, jnp.zeros(x.shape, acc))
+    return y.astype(x.dtype), (x, wg, wu, wd, row_w, row_tok, tile_expert,
+                               n_tiles)
+
+
+def _grouped_bwd(tile, res, dy):
+    x, wg, wu, wd, row_w, row_tok, tile_expert, n_tiles = res
+    acc = _acc(x.dtype)
+
+    def body(t, carry):
+        dx, dwg, dwu, dwd, drow = carry
+        e = tile_expert[t]
+        tok, w = _tile_rows(row_tok, t, tile), _tile_rows(row_w, t, tile)
+        xt, a, b, h, yt = _tile_forward(x, wg, wu, wd, tok, e)
+        dyt = jnp.take(dy, tok, axis=0, mode="fill", fill_value=0).astype(acc)
+        drow = jax.lax.dynamic_update_slice_in_dim(
+            drow, jnp.sum(dyt * yt, -1), t * tile, 0)
+        dyw = (dyt * w[:, None].astype(acc)).astype(x.dtype)
+        dh = jnp.dot(dyw, wd[e].T, preferred_element_type=acc)
+        sig = jax.nn.sigmoid(a)
+        da = (dh * b * sig * (1 + a * (1 - sig))).astype(x.dtype)
+        db = (dh * jax.nn.silu(a)).astype(x.dtype)
+        dxt = jnp.dot(da, wg[e].T, preferred_element_type=acc) \
+            + jnp.dot(db, wu[e].T, preferred_element_type=acc)
+        dx = dx.at[tok].add(dxt, mode="drop")
+        dwg = dwg.at[e].add(jnp.dot(xt.T, da, preferred_element_type=acc))
+        dwu = dwu.at[e].add(jnp.dot(xt.T, db, preferred_element_type=acc))
+        dwd = dwd.at[e].add(jnp.dot(h.T, dyw, preferred_element_type=acc))
+        return dx, dwg, dwu, dwd, drow
+
+    init = (jnp.zeros(x.shape, acc), jnp.zeros(wg.shape, acc),
+            jnp.zeros(wu.shape, acc), jnp.zeros(wd.shape, acc),
+            jnp.zeros(row_w.shape, acc))
+    with jax.named_scope("moe_grouped_ffn_bwd"):
+        dx, dwg, dwu, dwd, drow = jax.lax.fori_loop(0, n_tiles, body, init)
+    return (dx.astype(x.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
+            dwd.astype(wd.dtype), drow.astype(row_w.dtype), None, None, None)
+
+
+grouped_ffn.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def dispatch_plan(idx, top, valid, first, held, tile):
+    """Where each (token, chosen expert) pair goes. idx, top [N, k]: the
+    chosen experts and their renormalised weights; valid [N] bool (real
+    tokens). Returns (row_w, row_tok, tile_expert, n_tiles, stats): the
+    buffer's rows, each tile's expert, the tiles in use, and the STATS
+    vector (float32)."""
+    N, k = idx.shape
+    R = -(-N * min(k, held) // tile) * tile + held * tile
+    local = idx - first
+    here = (local >= 0) & (local < held) & valid[:, None]
+    local = jnp.where(here, local, held).reshape(-1)              # [N * k]
+    onehot = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - 1,
+                               jnp.minimum(local, held - 1)[:, None], 1)[:, 0]
+    sizes = jnp.sum(onehot, axis=0)                               # [held]
+    tiles = -(-sizes // tile)
+    tile_end = jnp.cumsum(tiles)
+    offset = (tile_end - tiles) * tile
+    place = jnp.where(local < held,
+                      offset[jnp.minimum(local, held - 1)] + rank, R)
+    token = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
+    row_tok = jnp.full((R,), N, jnp.int32).at[place].set(token, mode="drop")
+    row_w = jnp.zeros((R,), top.dtype).at[place].set(top.reshape(-1),
+                                                     mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(R // tile), side="right"),
+        held - 1).astype(jnp.int32)
+    n_held = jnp.sum(sizes)
+    n_real = jnp.sum(valid.astype(jnp.int32)) * k
+    f32 = jnp.float32
+    mean = jnp.maximum(n_held.astype(f32) / held, 1e-9)
+    stats = jnp.stack([n_held.astype(f32), (n_real - n_held).astype(f32),
+                       jnp.max(sizes).astype(f32) / mean,
+                       jnp.sum((place >= R) & (local < held)).astype(f32)])
+    return row_w, row_tok, tile_expert, tile_end[-1], stats
+
+
+@register_layer("moe_ffn", params=_moe_params)
+def _moe_ffn_forward(cfg, params, ins, ctx):
+    x_in = ins[0].value
+    d = x_in.shape[-1]
+    k, held = cfg.attr("top_k"), cfg.attr("experts_held")
+    first, tile = cfg.attr("first_expert", 0), cfg.attr("tile", 256)
+    valid = jnp.ones(x_in.shape[:-1], bool) if ins[0].mask is None \
+        else ins[0].mask > 0
+
+    def moe(x, valid, p):
+        x = x.reshape(-1, d)
+        acc = _acc(x.dtype)
+        probs = jax.nn.softmax(jnp.matmul(x, p["router"]).astype(acc), axis=-1)
+        top, idx = jax.lax.top_k(probs, k)
+        top = top / jnp.sum(top, -1, keepdims=True)
+        row_w, row_tok, tile_expert, n_tiles, stats = dispatch_plan(
+            idx, top, valid.reshape(-1), first, held, tile)
+        routed = grouped_ffn(x, p["wg"], p["wu"], p["wd"], row_w, row_tok,
+                             tile_expert, n_tiles, tile)
+        gate = jax.nn.sigmoid(jnp.matmul(x, p["shared_gate"]).astype(acc))
+        h = jax.nn.silu(jnp.matmul(x, p["shared_wg"])) \
+            * jnp.matmul(x, p["shared_wu"])
+        shared = gate.astype(x.dtype) * jnp.matmul(h, p["shared_wd"])
+        return (routed + shared).reshape(x_in.shape), stats
+
+    with jax.named_scope(cfg.attr("scope") or cfg.name):
+        out, stats = jax.checkpoint(moe)(x_in, valid, params)
+    ctx.extras.setdefault("step_stats", {}).setdefault(
+        "moe_ffn", {})[cfg.name] = jax.lax.stop_gradient(stats)
+    return ins[0].with_value(out)

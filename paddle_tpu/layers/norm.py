@@ -14,6 +14,8 @@ whole thing stays one XLA computation.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
@@ -203,3 +205,37 @@ def _cross_channel_norm(cfg, params, ins, ctx):
     norm = jnp.sqrt(jnp.square(x).sum(axis=1, keepdims=True) + 1e-10)
     y = x / norm
     return Arg(y.reshape(v.shape), ins[0].mask)
+
+
+# --- RMS norm --------------------------------------------------------------
+
+def const_init(attr: ParamAttr, value: float) -> ParamAttr:
+    """``attr`` starting at the constant ``value`` unless it names an
+    initialisation of its own (norm weights, decay rates)."""
+    if attr.initial_strategy is not None or attr.initial_std is not None \
+            or attr.initial_mean is not None or attr.initial_max is not None:
+        return attr
+    return dataclasses.replace(attr, initial_strategy="constant",
+                               initial_value=float(value))
+
+
+def rms_normalize(x, eps):
+    """x * rsqrt(mean(x^2) + eps) over the last axis, statistics in float32
+    (wider under an f64 gradient check), in x's dtype."""
+    xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return y.astype(x.dtype)
+
+
+def _rms_params(cfg, in_infos):
+    d = in_infos[0].size
+    return {"w0": ParamSpec((d,), const_init(cfg.param_attr(0), 0.0), fan_in=d)}
+
+
+@register_layer("rms_norm", params=_rms_params)
+def _rms_norm_forward(cfg, params, ins, ctx):
+    """Zero-centred RMS norm over the feature axis:
+    x * rsqrt(mean(x^2) + eps) * (1 + w), w starting at 0."""
+    x = ins[0].value
+    y = rms_normalize(x, cfg.attr("eps", 1e-6)) * (1 + params["w0"]).astype(x.dtype)
+    return ins[0].with_value(y)

@@ -258,3 +258,62 @@ def nmt_stage_map(S, name="m"):
             f"{name}_out": 3, "cost": 3,
         }
     raise ValueError(f"nmt_stage_map supports S in (2, 4), got {S}")
+
+
+def qwen3_next_lm_cost(vocab_size=151936, hidden_size=2048,
+                       num_hidden_layers=48, num_attention_heads=16,
+                       num_key_value_heads=2, head_dim=256,
+                       partial_rotary_factor=0.25, rope_theta=10000000,
+                       full_attention_interval=4, linear_num_key_heads=16,
+                       linear_num_value_heads=32, linear_key_head_dim=128,
+                       linear_value_head_dim=128, linear_conv_kernel_dim=4,
+                       moe_intermediate_size=512,
+                       shared_expert_intermediate_size=512, num_experts=512,
+                       num_experts_per_tok=10, experts_held=None,
+                       first_expert=0, rms_norm_eps=1e-6, name="q"):
+    """Next-token cost of a Qwen3-Next decoder (docs/qwen3_next.md): blocks
+    of ``h = x + mixer(norm(x)); y = h + moe(norm(h))`` whose mixer is gated
+    attention in every ``full_attention_interval``-th block and Gated
+    DeltaNet in the others, a final norm and an untied head. Every block's
+    MoE routes over all ``num_experts`` and holds ``experts_held`` of them
+    from ``first_expert`` on: one chip's share of an expert-parallel layer
+    (all of them by default). Feeds: ids / next_ids integer sequences."""
+    ids = layer.data(name="ids",
+                     type=data_type.integer_value_sequence(vocab_size))
+    nxt = layer.data(name="next_ids",
+                     type=data_type.integer_value_sequence(vocab_size))
+    x = layer.embedding(input=ids, size=hidden_size, name=f"{name}_emb")
+    for l in range(num_hidden_layers):
+        b = f"{name}_l{l}"
+        normed = layer.rms_norm(input=x, eps=rms_norm_eps, name=f"{b}_in_norm")
+        if (l + 1) % full_attention_interval == 0:
+            mixed = layer.gated_attention(
+                input=normed, num_heads=num_attention_heads,
+                num_kv_heads=num_key_value_heads, head_dim=head_dim,
+                rotary_dim=int(head_dim * partial_rotary_factor),
+                rope_theta=rope_theta, eps=rms_norm_eps,
+                scope=f"qwen3next/l{l}/mixer", name=f"{b}_attn")
+        else:
+            mixed = layer.gated_delta_net(
+                input=normed, num_k_heads=linear_num_key_heads,
+                num_v_heads=linear_num_value_heads,
+                head_k_dim=linear_key_head_dim,
+                head_v_dim=linear_value_head_dim,
+                conv_kernel=linear_conv_kernel_dim, eps=rms_norm_eps,
+                scope=f"qwen3next/l{l}/mixer", name=f"{b}_gdn")
+        h = layer.addto(input=[x, mixed], act=act.Linear(), bias_attr=False,
+                        name=f"{b}_h")
+        normed = layer.rms_norm(input=h, eps=rms_norm_eps,
+                                name=f"{b}_post_norm")
+        ffn = layer.moe_ffn(
+            input=normed, num_experts=num_experts, top_k=num_experts_per_tok,
+            expert_size=moe_intermediate_size,
+            shared_size=shared_expert_intermediate_size,
+            experts_held=experts_held, first_expert=first_expert,
+            scope=f"qwen3next/l{l}/moe", name=f"{b}_moe")
+        x = layer.addto(input=[h, ffn], act=act.Linear(), bias_attr=False,
+                        name=f"{b}_out")
+    x = layer.rms_norm(input=x, eps=rms_norm_eps, name=f"{name}_final_norm")
+    probs = layer.fc(input=x, size=vocab_size, act=act.Softmax(),
+                     bias_attr=False, name=f"{name}_head")
+    return layer.classification_cost(input=probs, label=nxt, name="cost")

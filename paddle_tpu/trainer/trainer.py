@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.core.arg import Arg
+from paddle_tpu.core.layer import publish_step_stats
 from paddle_tpu.core.parameters import Parameters
 from paddle_tpu.core.topology import Topology
 from paddle_tpu.observability import metrics as obs_metrics
@@ -162,6 +163,10 @@ def _compute_metrics(evaluators, outs, loss, feeds):
     for name, ev in evaluators.items():
         ev.packed_feed = packed
         metrics[name] = ev.compute(outs)
+    if "#step_stats" in outs:
+        # operator counters, not an evaluator: the loop publishes them
+        # where it drains the cost (core/layer.py publish_step_stats)
+        metrics["#step_stats"] = outs["#step_stats"]
     return metrics
 
 
@@ -1174,6 +1179,8 @@ class SGD:
                         {k: np.asarray(v)
                          for k, v in ent.host_grads.items()},
                         self._batch_counter)
+                if "#step_stats" in ent.metrics:
+                    publish_step_stats(ent.metrics["#step_stats"])
                 result = {}
                 for name, ev in self.evaluators.items():
                     ev.accumulate(ent.metrics[name])

@@ -133,6 +133,30 @@ def test_gru_kernels_keep_their_names_in_the_compiled_step(one_chip):
     assert sum("fused_gru_bwd" in x for x in names) == 1, names
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_gdn_state_pass_train_compiles_and_keeps_its_names(one_chip, dtype):
+    """The delta rule's state pass at the Qwen3-Next cell's size (one row:
+    32 value heads, 64 chunks of 64 tokens, widths 128): forward and
+    backward kernels, found in the compiled program by their names."""
+    from paddle_tpu.kernels import gdn
+
+    BH, NC, C, dk, dv = 32, 64, 64, 128, 128
+    assert gdn.kernel_supported(dk, dv, C, dtype)
+    args = [_sds((BH, NC, C, n), dtype, one_chip) for n in (dk, dv, dk, dk, C)]
+    args.append(_sds((BH, NC, 1, 1), jnp.float32, one_chip))
+
+    def train(*xs):
+        return jax.value_and_grad(lambda *xs: jnp.sum(
+            gdn.state_pass_kernel(*xs).astype(jnp.float32)),
+            argnums=tuple(range(6)))(*xs)
+
+    compiled, n = _compile(train, *args)
+    assert n == 2
+    names = _mosaic_instructions(compiled)
+    assert any("gdn_chunk_fwd" in x for x in names), names
+    assert any("gdn_chunk_bwd" in x for x in names), names
+
+
 # the LSTM classifier (B64/H512/T100) and the split backward past the
 # in-kernel-dW VMEM gate (H1280)
 @pytest.mark.parametrize("B,H,T,dtype,precision", [

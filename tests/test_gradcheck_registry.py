@@ -766,6 +766,38 @@ def _b_mha():
             {"q": _seq(4, 8)})
 
 
+@build("rms_norm")
+def _b_rms_norm():
+    x = _data_seq("x", 6)
+    return layer.rms_norm(input=x), {"x": _seq(4, 6)}
+
+
+@build("gated_attention")
+def _b_gated_attention():
+    x = _data_seq("x", 6)
+    return (layer.gated_attention(input=x, num_heads=4, num_kv_heads=2,
+                                  head_dim=4, rotary_dim=2, query_block=2),
+            {"x": _seq(5, 6, ragged=False)})
+
+
+@build("gated_delta_net")
+def _b_gated_delta_net():
+    x = _data_seq("x", 6)
+    return (layer.gated_delta_net(input=x, num_k_heads=2, num_v_heads=4,
+                                  head_k_dim=4, head_v_dim=3, chunk=4),
+            {"x": _seq(6, 6, ragged=False)})
+
+
+@build("moe_ffn")
+def _b_moe_ffn():
+    # 2 of the 6 experts are held: the rest of a token's top 3 is left out
+    x = _data_seq("x", 6)
+    return (layer.moe_ffn(input=x, num_experts=6, top_k=3, expert_size=5,
+                          shared_size=4, experts_held=2, first_expert=1,
+                          tile=4),
+            {"x": _seq(5, 6)})
+
+
 @build("crf")
 def _b_crf():
     x = _data_seq("x", 3)

@@ -551,6 +551,20 @@ def test_every_pallas_call_has_a_literal_unique_name():
     assert {"fused_gru_fwd", "fused_gru_bwd"} <= set(names)
 
 
+def test_moe_routing_counters_are_declared():
+    """The moe_ffn layers' routing counts (operator-only: no benchmark
+    metric reads them yet): pairs routed to experts held here / elsewhere,
+    the held experts' load skew, and the drop counter that stays 0.
+    tests/test_qwen3_next.py drives them through SGD.train."""
+    import paddle_tpu.layers.moe  # noqa: F401  (declares them)
+
+    snap = obs_metrics.default_registry.snapshot()
+    assert snap["paddle_moe_tokens_total"]["type"] == "counter"
+    assert snap["paddle_moe_expert_load_max_over_mean"]["type"] == "gauge"
+    assert snap["paddle_moe_dropped_total"]["type"] == "counter"
+    assert sum(snap["paddle_moe_dropped_total"]["series"].values()) == 0
+
+
 # --- acceptance: jaxpr bit-identity + fault-injected scrape ---------------
 
 def _train_step_jaxpr():

@@ -1,0 +1,406 @@
+"""The Qwen3-Next layers (rms_norm, gated_attention, gated_delta_net, moe_ffn),
+the chunked delta rule and the whole tiny model, held to the plain reference
+of the benchmark (benchmark/reference/qwen3-next-80b-a3b-ep32.py: float32,
+the delta rule token by token, the MoE as a masked loop) on seeded weights.
+CPU, tiny widths, float32 at `highest`.
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import data_type, flops, layer
+from paddle_tpu.core.arg import Arg
+from paddle_tpu.core.layer import LAYER_REGISTRY
+from paddle_tpu.core.topology import Topology
+from paddle_tpu.kernels import gdn
+from paddle_tpu.models.text import qwen3_next_lm_cost
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ARGS = dict(vocab_size=50, hidden_size=16, num_hidden_layers=4,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            partial_rotary_factor=0.5, rope_theta=10000.0,
+            full_attention_interval=4, linear_num_key_heads=2,
+            linear_num_value_heads=4, linear_key_head_dim=8,
+            linear_value_head_dim=8, linear_conv_kernel_dim=4,
+            moe_intermediate_size=12, shared_expert_intermediate_size=12,
+            num_experts=8, num_experts_per_tok=3, experts_held=4,
+            first_expert=2, rms_norm_eps=1e-6)
+
+
+def _load(rel):
+    path = os.path.join(ROOT, rel)
+    spec = importlib.util.spec_from_file_location(
+        "ref_" + os.path.basename(path).replace("-", "_")[:-3], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return _load("benchmark/reference/qwen3-next-80b-a3b-ep32.py")
+
+
+@pytest.fixture(autouse=True)
+def _highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _ident(x):
+    return x
+
+
+def _normal(seed, *shape, scale=1.0):
+    return scale * jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
+
+
+def _close(got, want, tol=2e-4):
+    got, want = np.asarray(got), np.asarray(want)
+    scale = max(float(np.max(np.abs(want))), 1e-6)
+    assert float(np.max(np.abs(got - want))) <= tol * scale, \
+        (float(np.max(np.abs(got - want))), scale)
+
+
+def _seeded(table, seed):
+    """Every leaf of a reference table from the seed; the constants are
+    moved off their start so that their gradients are exercised."""
+    out = {}
+    for i, (name, (shape, (kind, v))) in enumerate(sorted(table.items())):
+        noise = _normal(seed * 1000 + i, *shape)
+        out[name] = v * noise if kind == "normal" else v + 0.1 * noise
+    return out
+
+
+# ---- each layer against the reference's function of the same name ---------
+
+def _layer_case(name, a):
+    """(layer built on a dense sequence input "x", the reference function as
+    f(params by suffix, x [T, d]))."""
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(
+        a["hidden_size"]))
+    if name == "rms_norm":
+        return layer.rms_norm(input=x, eps=a["rms_norm_eps"], name="l"), \
+            lambda ref, p, row: ref.rms_norm(row, p["w0"], a["rms_norm_eps"])
+    if name == "gated_attention":
+        return layer.gated_attention(
+            input=x, num_heads=a["num_attention_heads"],
+            num_kv_heads=a["num_key_value_heads"], head_dim=a["head_dim"],
+            rotary_dim=int(a["head_dim"] * a["partial_rotary_factor"]),
+            rope_theta=a["rope_theta"], query_block=8, name="l"), \
+            lambda ref, p, row: ref.gated_attention(p, row, a, _ident)
+    if name == "gated_delta_net":
+        return layer.gated_delta_net(
+            input=x, num_k_heads=a["linear_num_key_heads"],
+            num_v_heads=a["linear_num_value_heads"],
+            head_k_dim=a["linear_key_head_dim"],
+            head_v_dim=a["linear_value_head_dim"],
+            conv_kernel=a["linear_conv_kernel_dim"], chunk=8, name="l"), \
+            lambda ref, p, row: ref.gated_delta_net(p, row, a, _ident)
+    return layer.moe_ffn(
+        input=x, num_experts=a["num_experts"], top_k=a["num_experts_per_tok"],
+        expert_size=a["moe_intermediate_size"],
+        shared_size=a["shared_expert_intermediate_size"],
+        experts_held=a["experts_held"], first_expert=a["first_expert"],
+        tile=8, name="l"), \
+        lambda ref, p, row: ref.moe_ffn(p, row, a, _ident)
+
+
+@pytest.mark.parametrize("name", ["rms_norm", "gated_attention",
+                                  "gated_delta_net", "moe_ffn"])
+def test_layer_matches_the_reference(ref, name):
+    out, ref_fn = _layer_case(name, ARGS)
+    topo = Topology(out)
+    B, T, d = 2, 21, ARGS["hidden_size"]
+    params = {k: _normal(i, *s.shape, scale=0.3) + (1.0 if k.endswith(".norm") else 0.0)
+              for i, (k, s) in enumerate(sorted(topo.param_specs().items()))}
+    x = _normal(77, B, T, d)
+    proj = _normal(78, B, T, d)
+
+    def prog(params, x):
+        y = topo.forward(params, {"x": Arg(x, jnp.ones((B, T)))},
+                         training=True)["l"].value
+        return jnp.sum(y * proj), y
+
+    def plain(params, x):
+        p = {k.split(".", 1)[1]: v for k, v in params.items()}
+        y = jnp.stack([ref_fn(ref, p, x[r]) for r in range(B)])
+        return jnp.sum(y * proj), y
+
+    (_, y), g = jax.value_and_grad(prog, argnums=(0, 1), has_aux=True)(params, x)
+    (_, y_ref), g_ref = jax.value_and_grad(plain, argnums=(0, 1),
+                                           has_aux=True)(params, x)
+    _close(y, y_ref)
+    _close(g[1], g_ref[1])
+    for k in params:
+        _close(g[0][k], g_ref[0][k])
+
+
+# ---- the chunked delta rule against the token-by-token form ----------------
+
+def _rule_inputs(B, T, H, dk, dv, seed=0):
+    q = _normal(seed, B, T, H, dk)
+    k = _normal(seed + 1, B, T, H, dk)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = _normal(seed + 2, B, T, H, dv)
+    g = -jax.nn.softplus(_normal(seed + 3, B, T, H))
+    beta = jax.nn.sigmoid(_normal(seed + 4, B, T, H))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("chunk,T", [(4, 10), (64, 70), (64, 64), (8, 3)])
+def test_chunked_rule_matches_token_by_token(ref, chunk, T):
+    ins = _rule_inputs(2, T, 3, 8, 6)
+    proj = _normal(9, 2, T, 3, 6)
+
+    def chunked(*ins):
+        return jnp.sum(gdn.gated_delta_rule(*ins, chunk=chunk) * proj)
+
+    def tokens(*ins):
+        o = jnp.stack([ref.delta_rule(*(x[r] for x in ins)) for r in range(2)])
+        return jnp.sum(o * proj)
+
+    v, g = jax.value_and_grad(chunked, argnums=(0, 1, 2, 3, 4))(*ins)
+    v_ref, g_ref = jax.value_and_grad(tokens, argnums=(0, 1, 2, 3, 4))(*ins)
+    _close(v, v_ref)
+    for a, b in zip(g, g_ref):
+        _close(a, b)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_state_pass_kernels_match_the_scan(dtype, tol):
+    """gdn_chunk_fwd / gdn_chunk_bwd in interpret mode against the lax.scan
+    form, values and every input's gradient (float32: the same arithmetic;
+    bfloat16: the hand-written backward pass rounds at other points than the
+    scan's transpose)."""
+    ins = _rule_inputs(1, 40, 2, 128, 128)
+    ins = tuple(x.astype(dtype) for x in ins[:3]) + ins[3:]
+    xs = gdn.chunk_prepare(*ins, 16)
+    proj = _normal(9, *xs[1].shape)
+
+    def through(state_pass):
+        return jax.value_and_grad(lambda *xs: jnp.sum(
+            state_pass(*xs).astype(jnp.float32) * proj),
+            argnums=tuple(range(6)))(*xs)
+
+    v, g = through(lambda *xs: gdn.state_pass_kernel(*xs, True))
+    v_ref, g_ref = through(gdn.state_pass_scan)
+    _close(v, v_ref, tol)
+    for a, b in zip(g, g_ref):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        _close(a.astype(jnp.float32), b.astype(jnp.float32), tol)
+
+
+def test_kernel_gate():
+    assert gdn.kernel_supported(128, 128, 64, jnp.bfloat16)
+    assert not gdn.kernel_supported(8, 128, 64, jnp.bfloat16)
+    assert not gdn.kernel_supported(128, 128, 4, jnp.float32)
+    # held to the CPU here, every layer takes the scan
+    assert gdn.pick_state_pass("t", 128, 128, 64, jnp.bfloat16) \
+        is gdn.state_pass_scan
+
+
+# ---- the whole tiny model ---------------------------------------------------
+
+def _batch(ref, lens, seed=0):
+    rng = np.random.default_rng(seed)
+    rows = [(rng.integers(2, ARGS["vocab_size"], n).tolist(),
+             rng.integers(2, ARGS["vocab_size"], n).tolist()) for n in lens]
+    b = {k: jnp.asarray(v) for k, v in ref.pad(rows, ARGS).items()}
+    feeds = {"ids": Arg(b["ids"], b["ids_mask"]),
+             "next_ids": Arg(b["next_ids"], b["next_ids_mask"])}
+    return rows, b, feeds
+
+
+def test_model_declares_the_references_leaves(ref):
+    topo = Topology(qwen3_next_lm_cost(**ARGS))
+    table = ref.param_table(ARGS)
+    assert {k: tuple(s.shape) for k, s in topo.param_specs().items()} \
+        == {k: tuple(shape) for k, (shape, _) in table.items()}
+    # the program's own initialisation starts the constants where the
+    # reference's table does
+    mine = topo.init_params(jax.random.PRNGKey(0))
+    for k, (shape, (kind, v)) in table.items():
+        if kind == "const":
+            assert np.all(np.asarray(mine[k]) == v), k
+
+
+def test_model_loss_and_every_gradient_match_the_reference(ref):
+    topo = Topology(qwen3_next_lm_cost(**ARGS))
+    p = _seeded(ref.param_table(ARGS), 3)
+    _, b, feeds = _batch(ref, [70, 64, 33])
+    loss = topo.loss_fn()
+    (c, _), g = jax.jit(jax.value_and_grad(
+        lambda p: loss(p, feeds), has_aux=True))(p)
+    (c_ref, _), g_ref = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss(p, b, _ident, ARGS), has_aux=True))(p)
+    (c_blk, _), g_blk = ref.value_and_grad(p, b, _ident, ARGS)
+    assert abs(float(c) - float(c_ref)) <= 1e-5 * abs(float(c_ref))
+    assert abs(float(c_blk) - float(c_ref)) <= 1e-5 * abs(float(c_ref))
+    assert set(g) == set(g_ref) == set(g_blk)
+    for k in g_ref:
+        _close(g[k], g_ref[k], 5e-4)
+        _close(g_blk[k], g_ref[k], 5e-5)
+
+
+def test_four_shares_and_one_shared_make_the_uncut_layer(ref):
+    """8 experts in 4 shares of 2: each share's layer holds 2 experts and
+    routes over all 8; the shares' routed parts and ONE shared part add up
+    to the reference's layer that holds all 8."""
+    a = dict(ARGS, experts_held=8, first_expert=0)
+    d, T = a["hidden_size"], 40
+    table = {k.split("_moe.")[1]: v for k, v in ref.param_table(a).items()
+             if k.startswith("_q_l0_moe.")}
+    full = {k: _normal(i, *shape, scale=0.4)
+            for i, (k, (shape, _)) in enumerate(sorted(table.items()))}
+    x = _normal(5, 1, T, d)
+    want = ref.moe_ffn(full, x[0], a, _ident)
+    total = ref.shared(full, x[0], a, _ident)
+    for s in range(4):
+        inp = layer.data(name="x", type=data_type.dense_vector_sequence(d))
+        out = layer.moe_ffn(
+            input=inp, num_experts=8, top_k=a["num_experts_per_tok"],
+            expert_size=a["moe_intermediate_size"],
+            shared_size=a["shared_expert_intermediate_size"],
+            experts_held=2, first_expert=2 * s, tile=8, name="l")
+        mine = {"_l." + k: (v[2 * s:2 * s + 2] if k in ("wg", "wu", "wd") else v)
+                for k, v in full.items()}
+        outs, ctx = Topology(out).forward(
+            mine, {"x": Arg(x, jnp.ones((1, T)))}, training=True,
+            return_ctx=True)
+        part = outs["l"].value[0] - ref.shared(full, x[0], a, _ident)
+        _close(part, ref.routed(full, x[0], a, _ident, first=2 * s, held=2))
+        total = total + part
+        held, elsewhere, _, dropped = np.asarray(ctx.extras["step_stats"]["moe_ffn"]["l"])
+        assert held + elsewhere == T * a["num_experts_per_tok"]
+        assert dropped == 0
+    _close(total, want)
+
+
+def test_moe_counts_held_and_elsewhere_and_skips_padding():
+    d, T = 16, 12
+    inp = layer.data(name="x", type=data_type.dense_vector_sequence(d))
+    out = layer.moe_ffn(input=inp, num_experts=8, top_k=2, expert_size=8,
+                        shared_size=8, experts_held=8, tile=4, name="l")
+    topo = Topology(out)
+    params = topo.init_params(jax.random.PRNGKey(1))
+    mask = jnp.asarray([[1.0] * T, [1.0] * 5 + [0.0] * (T - 5)])
+    _, ctx = topo.forward(params, {"x": Arg(_normal(2, 2, T, d), mask)},
+                          training=True, return_ctx=True)
+    held, elsewhere, load, dropped = np.asarray(ctx.extras["step_stats"]["moe_ffn"]["l"])
+    # every expert is held: every real token's two choices are computed here
+    assert (held, elsewhere, dropped) == ((T + 5) * 2, 0, 0)
+    assert load >= 1.0
+
+
+def test_grouped_ffn_walks_only_the_tiles_in_use():
+    """The dispatch buffer is sized for the worst case; the tiles past the
+    last expert's run hold no row and are not visited."""
+    from paddle_tpu.layers import moe
+
+    N, k, held, tile = 32, 2, 4, 8
+    idx = jnp.tile(jnp.asarray([[0, 9]]), (N, 1))       # one held choice each
+    top = jnp.full((N, k), 0.5)
+    row_w, row_tok, tile_expert, n_tiles, stats = moe.dispatch_plan(
+        idx, top, jnp.ones((N,), bool), 0, held, tile)
+    assert row_tok.shape[0] == N * 2 + held * tile       # the worst case
+    assert int(n_tiles) == N // tile                     # what is in use
+    assert np.asarray(row_tok[:N]).tolist() == list(range(N))
+    assert np.all(np.asarray(row_tok[N:]) == N)          # padding rows
+    assert np.asarray(stats).tolist() == [N, N, 4.0, 0.0]
+
+
+# ---- through the public trainer ---------------------------------------------
+
+def test_trains_through_sgd_and_fills_the_moe_counters():
+    from paddle_tpu.observability import metrics as obs_metrics
+
+    cost = qwen3_next_lm_cost(**dict(ARGS, first_expert=0))
+    params = paddle.parameters.create(cost)
+    trainer = paddle.SGD(cost, params,
+                         paddle.optimizer.Adam(learning_rate=3e-3),
+                         mixed_precision=True)
+    rng = np.random.default_rng(0)
+    seqs = [rng.integers(2, 50, 24).tolist() for _ in range(4)]
+    rows = [([0] + s, s + [1]) for s in seqs]
+    costs = []
+
+    def snap():
+        fam = obs_metrics.default_registry.snapshot()
+        tok = {tuple(sorted(dict(k).items())): v for k, v in
+               fam.get("paddle_moe_tokens_total", {"series": {}})["series"].items()}
+        return tok, fam.get("paddle_moe_dropped_total")
+
+    before, _ = snap()
+    trainer.train(lambda: iter([rows] * 12), num_passes=1,
+                  event_handler=lambda ev: costs.append(ev.cost)
+                  if isinstance(ev, paddle.event.EndIteration) else None,
+                  feeding={"ids": 0, "next_ids": 1})
+    after, dropped = snap()
+    assert len(costs) == 12 and np.all(np.isfinite(costs))
+    assert costs[-1] < costs[0]
+    key = lambda result: (("layer", "q_l0_moe"), ("result", result))
+    held = after[key("held")] - before.get(key("held"), 0)
+    elsewhere = after[key("elsewhere")] - before.get(key("elsewhere"), 0)
+    assert held + elsewhere == 12 * 4 * 25 * ARGS["num_experts_per_tok"]
+    assert 0 < held < held + elsewhere
+    assert sum(dropped["series"].values()) == 0
+    load = obs_metrics.default_registry.snapshot()[
+        "paddle_moe_expert_load_max_over_mean"]["series"]
+    assert all(v >= 1.0 for v in load.values()) and len(load) >= 4
+
+
+def test_no_moe_layer_leaves_the_step_as_it_was():
+    """A model without moe_ffn hands out no extra metric: the step's outputs
+    are what they were (the NMT and ResNet cells' programs do not change)."""
+    from paddle_tpu import activation
+    from paddle_tpu.trainer.trainer import make_train_step
+
+    img = layer.data(name="pixel", type=data_type.dense_vector(8))
+    lab = layer.data(name="label", type=data_type.integer_value(3))
+    cost = layer.classification_cost(
+        input=layer.fc(input=img, size=3, act=activation.Softmax()), label=lab)
+    topo = Topology(cost)
+    params = topo.init_params(jax.random.PRNGKey(0))
+    opt = paddle.optimizer.Adam(learning_rate=1e-2)
+    step = make_train_step(topo.loss_fn(cost), opt, topo.static_map())
+    out = step(params, opt.init(params), jax.random.PRNGKey(1),
+               {"pixel": Arg(jnp.zeros((4, 8))),
+                "label": Arg(jnp.zeros((4, 1), jnp.int32))})
+    assert out[3] == {}
+
+
+# ---- registry and FLOP pricing ----------------------------------------------
+
+def test_the_four_types_are_registered():
+    for t in ("rms_norm", "gated_attention", "gated_delta_net", "moe_ffn"):
+        assert LAYER_REGISTRY.get(t) is not None
+
+
+def test_flops_price_the_new_layers_by_the_work_done_here():
+    a = dict(ARGS, first_expert=0)
+    topo = Topology(qwen3_next_lm_cost(**a))
+    T = 10
+    d, E, k, held = 16, a["num_experts"], 3, a["experts_held"]
+    I = a["moe_intermediate_size"]
+    by = {l.name: flops.layer_fwd_flops(topo, l, 1, T) for l in topo.layers}
+    assert by["q_l0_in_norm"] == 0.0
+    # router over all 8, shared gate, shared expert, 3 x 4/8 routed experts
+    assert by["q_l0_moe"] == 2.0 * T * (d * E + d + 3 * d * I
+                                        + k * held / E * 3 * d * I)
+    # q (+ gate), k, v, o projections and the causal scores
+    H, Hkv, D = 4, 2, 8
+    assert by["q_l3_attn"] == 2.0 * T * (d * H * 2 * D + 2 * d * Hkv * D
+                                         + H * D * d) \
+        + 2 * 2.0 * H * D * T * (T + 1) / 2
+    Hk, Hv, dk, dv, K = 2, 4, 8, 8, 4
+    assert by["q_l0_gdn"] == 2.0 * T * (
+        d * (2 * Hk * dk + 2 * Hv * dv) + d * 2 * Hv
+        + (2 * Hk * dk + Hv * dv) * K + Hv * dv * d) + 2.0 * T * Hv * 3 * dk * dv
