@@ -25,14 +25,29 @@ resident in VMEM over a (batch x head) row's chunks.
 g, gamma, the decay products, T and S are float32 (wider under an f64
 gradient check); the products take their operands in the dtype q, k, v come
 in (bf16 under the mixed-precision policy) and accumulate in float32.
+
+T is ``unit_lower_inverse``: the diagonal blocks of LEAF x LEAF of every
+matrix at once by substitution, then pairs of blocks merged by products of
+float32 operands at ``Precision.HIGHEST`` (at default precision the TPU would
+round them to bf16), as many levels as the chunk's size asks for. Every
+product is of true inverses, whose entries stay bounded; a Neumann doubling
+over the whole chunk is not (every key of a chunk the same, beta 0.99:
+max|T| is 1, the powers of A reach 1e18, the result is off by 8.6e10). Its
+backward rule needs only T, dA = -tril(T^T dT T^T, -1), and T is the one
+value a row's ``jax.checkpoint`` keeps (``gdn_T``): the backward pass runs
+``gdn_chunk_fwd`` again, as the benchmark's cell demands (9 Mosaic calls a
+step) and because every chunk's starting state would be 1.6 GB a layer, but
+inverts nothing again.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -44,6 +59,82 @@ CHUNK = 64
 def _mm(a, b, dtype, acc):
     return jnp.matmul(a.astype(dtype), b.astype(dtype),
                       preferred_element_type=acc)
+
+
+def _mm_f32(a, b):
+    """A product of float32 (float64) operands kept at that precision: at
+    default precision the TPU would round them to one bf16 pass."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+LEAF = 32
+
+
+@jax.jit    # traced once for all the layers of a step
+def _inverse_by_blocks(A):
+    lead, C = A.shape[:-2], A.shape[-1]
+    b = math.gcd(LEAF, C)
+    nb = C // b
+    # the diagonal blocks of b x b of every matrix at once, by substitution:
+    # row r of (I + D)^-1 is e_r - D[r, :r] (rows before r). Laid out
+    # [row, column, matrix]: the matrices side by side in the lanes (a minor
+    # dimension of 32 would be padded to 128) and the row in the leading
+    # dimension, where a loop's index costs nothing. A `fori_loop`, not a
+    # Python loop: every pass over the step's jaxpr walks the b - 1 updates,
+    # which written out cost the cell 2.3 s of set-up.
+    blocks = A.reshape(lead + (nb, b, nb, b))
+    D = jnp.stack([blocks[..., m, :, m, :] for m in range(nb)], axis=-3)
+    D = jnp.moveaxis(D.reshape((-1, b, b)), 0, -1)
+
+    def row(r, T):
+        d = jax.lax.dynamic_index_in_dim(D, r, keepdims=False)
+        new = jax.lax.dynamic_slice_in_dim(T, r, 1) - jnp.sum(
+            d[:, None, :] * T, axis=0, keepdims=True)
+        return jax.lax.dynamic_update_slice_in_dim(T, new, r, axis=0)
+
+    T = jax.lax.fori_loop(1, b, row, jnp.broadcast_to(
+        jnp.eye(b, dtype=A.dtype)[:, :, None], D.shape))
+    T = jnp.moveaxis(T, -1, 0).reshape(lead + (nb, b, b))
+    # back on the diagonal of [C, C], zeros elsewhere
+    T = T[..., :, :, None, :] * jnp.eye(nb, dtype=A.dtype)[:, None, :, None]
+    T = T.reshape(A.shape)
+    i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    size = b
+    while size < C:
+        # T holds the inverses of the diagonal blocks of `size`; L is A's
+        # block below the diagonal inside every pair of them, and
+        # [[T11, 0], [-T22 A21 T11, T22]] = T - T L T for all pairs at once
+        L = jnp.where((i // (2 * size) == j // (2 * size))
+                      & (i // size != j // size), A, 0)
+        T = T - _mm_f32(T, _mm_f32(L, T))
+        size *= 2
+    return T
+
+
+@jax.custom_vjp
+def unit_lower_inverse(A):
+    """T = (I + A)^-1 for A [..., C, C] strictly lower triangular, float32
+    (float64 under a gradient check), any C."""
+    return _inverse_by_blocks(A)
+
+
+def _unit_lower_inverse_fwd(A):
+    # Named on the one value that result and residual both come from: under
+    # `save_only_these_names("gdn_T")` (layers/attention.py
+    # `rows_one_at_a_time`) the backward pass then finds T and computes
+    # nothing of the inverse again. Kept with the matrix flattened: the TPU
+    # pads a float32 [..., 64, 64] to 128 lanes, twice the bytes.
+    T = _inverse_by_blocks(A)
+    flat = checkpoint_name(T.reshape(A.shape[:-2] + (-1,)), "gdn_T")
+    return flat.reshape(A.shape), flat
+
+
+def _unit_lower_inverse_bwd(flat, dT):
+    Tt = jnp.swapaxes(flat.reshape(dT.shape), -1, -2)
+    return (-jnp.tril(_mm_f32(Tt, _mm_f32(dT, Tt)), -1),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
 def chunk_prepare(q, k, v, g, beta, chunk):
@@ -71,10 +162,8 @@ def chunk_prepare(q, k, v, g, beta, chunk):
     kb = k.astype(acc) * beta[..., None]
     kk = _mm(kb, jnp.swapaxes(k, -1, -2), dtype, acc)
     A = jnp.where(i > j, kk * decay, 0.0)
-    eye = jnp.eye(C, dtype=acc)
-    Tm = jax.scipy.linalg.solve_triangular(
-        A + eye, jnp.broadcast_to(eye, A.shape), lower=True,
-        unit_diagonal=True)
+    with jax.named_scope("gdn_tinv"):
+        Tm = unit_lower_inverse(A)
     U = _mm(Tm, v.astype(acc) * beta[..., None], dtype, acc)
     W = _mm(Tm, kb * jnp.exp(gamma)[..., None], dtype, acc)
     Aqk = _mm(q, jnp.swapaxes(k, -1, -2), dtype, acc) * decay

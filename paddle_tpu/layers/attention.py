@@ -174,8 +174,13 @@ def rows_one_at_a_time(mixer, x, params):
     """``mixer(row [T, d], params)`` over the rows of x [B, T, d], one after
     the other, each row's forward computed again in its backward pass: what
     is alive at once is one row's activations, not the batch's (a row of
-    4096 tokens fills the MXU on its own)."""
-    return jax.lax.map(jax.checkpoint(lambda row: mixer(row, params)), x)
+    4096 tokens fills the MXU on its own). The one thing kept from a row's
+    forward is what the mixer names ``gdn_T`` (kernels/gdn.py: the delta
+    rule's chunk inverses); ``gated_attention`` names nothing, so all of its
+    forward is computed again."""
+    keep = jax.checkpoint_policies.save_only_these_names("gdn_T")
+    return jax.lax.map(
+        jax.checkpoint(lambda row: mixer(row, params), policy=keep), x)
 
 
 @register_layer("gated_attention", params=_gattn_params)

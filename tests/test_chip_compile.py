@@ -157,6 +157,61 @@ def test_gdn_state_pass_train_compiles_and_keeps_its_names(one_chip, dtype):
     assert any("gdn_chunk_bwd" in x for x in names), names
 
 
+@pytest.mark.parametrize("kind", ["gated_delta_net", "gated_attention"])
+def test_qwen3_next_mixer_train_compiles_at_the_cells_size(one_chip, kind,
+                                                           monkeypatch):
+    """value_and_grad of one mixer layer as the Qwen3-Next cell runs it (4
+    rows x 4096 x 2048, bf16, one row at a time). The DeltaNet layer: the
+    chunk inverse is products (no `InvertDiagBlocksLowerTriangular` custom
+    call, no triangular-solve), the backward pass runs `gdn_chunk_fwd` again
+    (the cell demands 9 Mosaic calls a step) but finds T kept, flattened so
+    that the four rows' stack is 134 MB and not padded to twice that. The
+    attention layer shares `rows_one_at_a_time` and names nothing to keep:
+    its program, and so its temporaries, are what they were before T was
+    kept (parent commit, same compiler: 535,163,904 bytes)."""
+    from paddle_tpu import data_type, layer
+    from paddle_tpu.core.arg import Arg
+    from paddle_tpu.core.topology import Topology
+    from paddle_tpu.kernels import _pallas_util
+
+    # held to the CPU, `take_pallas` would hand the layer the scan
+    monkeypatch.setattr(_pallas_util, "take_pallas",
+                        lambda who, kernel, eligible=True, why_not="": eligible)
+    B, T, d = 4, 4096, 2048
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
+    if kind == "gated_delta_net":
+        out = layer.gated_delta_net(
+            input=x, num_k_heads=16, num_v_heads=32, head_k_dim=128,
+            head_v_dim=128, conv_kernel=4, chunk=64, name="l")
+    else:
+        out = layer.gated_attention(
+            input=x, num_heads=16, num_kv_heads=2, head_dim=256,
+            rotary_dim=64, rope_theta=1e7, name="l")
+    topo = Topology(out)
+    params = {k: _sds(s.shape, jnp.bfloat16, one_chip)
+              for k, s in topo.param_specs().items()}
+
+    def loss(params, x):
+        y = topo.forward(params, {"x": Arg(x, jnp.ones((B, T)))},
+                         training=True)["l"].value
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled, _ = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params,
+                           _sds((B, T, d), jnp.bfloat16, one_chip))
+    text, names = compiled.as_text(), _mosaic_instructions(compiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"{kind}: {temp:,} bytes of temporaries")
+    assert "InvertDiagBlocks" not in text and "triangular-solve" not in text
+    if kind == "gated_delta_net":
+        assert sum("gdn_chunk_fwd" in n for n in names) == 2, names
+        assert sum("gdn_chunk_bwd" in n for n in names) == 1, names
+        assert "f32[4,32,64,4096]" in text          # the rows' kept T
+        # the parent: 1,547,109,376; the kept T is 134 MB of the difference
+        assert temp < 1_900_000_000, temp
+    else:
+        assert names == [] and temp == 535_163_904, (names, temp)
+
+
 # the LSTM classifier (B64/H512/T100) and the split backward past the
 # in-kernel-dW VMEM gate (H1280)
 @pytest.mark.parametrize("B,H,T,dtype,precision", [

@@ -80,7 +80,7 @@ def _seeded(table, seed):
 
 # ---- each layer against the reference's function of the same name ---------
 
-def _layer_case(name, a):
+def _layer_case(name, a, chunk=8):
     """(layer built on a dense sequence input "x", the reference function as
     f(params by suffix, x [T, d]))."""
     x = layer.data(name="x", type=data_type.dense_vector_sequence(
@@ -101,7 +101,7 @@ def _layer_case(name, a):
             num_v_heads=a["linear_num_value_heads"],
             head_k_dim=a["linear_key_head_dim"],
             head_v_dim=a["linear_value_head_dim"],
-            conv_kernel=a["linear_conv_kernel_dim"], chunk=8, name="l"), \
+            conv_kernel=a["linear_conv_kernel_dim"], chunk=chunk, name="l"), \
             lambda ref, p, row: ref.gated_delta_net(p, row, a, _ident)
     return layer.moe_ffn(
         input=x, num_experts=a["num_experts"], top_k=a["num_experts_per_tok"],
@@ -112,15 +112,13 @@ def _layer_case(name, a):
         lambda ref, p, row: ref.moe_ffn(p, row, a, _ident)
 
 
-@pytest.mark.parametrize("name", ["rms_norm", "gated_attention",
-                                  "gated_delta_net", "moe_ffn"])
-def test_layer_matches_the_reference(ref, name):
-    out, ref_fn = _layer_case(name, ARGS)
+def _check_layer_against_the_reference(ref, name, x, chunk=8):
+    """Output and every gradient of the layer on x [B, T, d]."""
+    out, ref_fn = _layer_case(name, ARGS, chunk)
     topo = Topology(out)
-    B, T, d = 2, 21, ARGS["hidden_size"]
+    B, T, d = x.shape
     params = {k: _normal(i, *s.shape, scale=0.3) + (1.0 if k.endswith(".norm") else 0.0)
               for i, (k, s) in enumerate(sorted(topo.param_specs().items()))}
-    x = _normal(77, B, T, d)
     proj = _normal(78, B, T, d)
 
     def prog(params, x):
@@ -140,6 +138,13 @@ def test_layer_matches_the_reference(ref, name):
     _close(g[1], g_ref[1])
     for k in params:
         _close(g[0][k], g_ref[0][k])
+
+
+@pytest.mark.parametrize("name", ["rms_norm", "gated_attention",
+                                  "gated_delta_net", "moe_ffn"])
+def test_layer_matches_the_reference(ref, name):
+    _check_layer_against_the_reference(
+        ref, name, _normal(77, 2, 21, ARGS["hidden_size"]))
 
 
 # ---- the chunked delta rule against the token-by-token form ----------------
@@ -171,6 +176,126 @@ def test_chunked_rule_matches_token_by_token(ref, chunk, T):
     _close(v, v_ref)
     for a, b in zip(g, g_ref):
         _close(a, b)
+
+
+# ---- the chunk inverse T = (I + A)^-1 ---------------------------------------
+
+def _chunk_A(kind, C, dtype, seed=0):
+    """A [2, 3, C, C] as `chunk_prepare` makes it. "layer": unit keys, beta
+    from a sigmoid, decays from g. "one_key": every key of a chunk the same,
+    beta 0.99, g 0 (a run of one repeated token, or padding): max|T| is 1,
+    but the powers A^k reach ~1e18 before they cancel."""
+    lead = (2, 3)
+    if kind == "layer":
+        k = _normal(seed, *lead, C, 8)
+        beta = jax.nn.sigmoid(_normal(seed + 1, *lead, C))
+        g = -jax.nn.softplus(_normal(seed + 2, *lead, C))
+    else:
+        k = jnp.broadcast_to(_normal(seed, *lead, 1, 8), lead + (C, 8))
+        beta, g = jnp.full(lead + (C,), 0.99), jnp.zeros(lead + (C,))
+    k, beta, g = (x.astype(dtype) for x in (k, beta, g))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    gamma = jnp.cumsum(g, axis=-1)
+    decay = jnp.exp(gamma[..., :, None] - gamma[..., None, :])
+    kk = jnp.matmul(k * beta[..., None], jnp.swapaxes(k, -1, -2))
+    return jnp.tril(kk * decay, -1)
+
+
+def _by_the_solve(A):
+    eye = jnp.eye(A.shape[-1], dtype=A.dtype)
+    return jax.scipy.linalg.solve_triangular(
+        A + eye, jnp.broadcast_to(eye, A.shape), lower=True,
+        unit_diagonal=True)
+
+
+def _whole_chunk_neumann(A):
+    """(I - A)(I + A^2)(I + A^4)...: what the block form must not become."""
+    T, P, k = jnp.eye(A.shape[-1], dtype=A.dtype) - A, A, 2
+    while k < A.shape[-1]:
+        P = jnp.matmul(P, P)
+        T, k = T + jnp.matmul(T, P), 2 * k
+    return T
+
+
+@pytest.mark.parametrize("kind", ["layer", "one_key"])
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6), ("float64", 1e-12)])
+@pytest.mark.parametrize("C", [16, 64, 128])
+def test_unit_lower_inverse_matches_the_solve(C, dtype, tol, kind):
+    """Value and gradient against `solve_triangular` and autodiff through
+    it. float32 at 2e-6 of max|T| needs the merges' products at HIGHEST; the
+    one-key case is what a whole-chunk Neumann doubling fails by ten orders
+    of magnitude while max|T| stays 1."""
+    with jax.enable_x64(dtype == "float64"):
+        A = _chunk_A(kind, C, jnp.dtype(dtype))
+        proj = _normal(7, *A.shape).astype(A.dtype)
+        T, want = gdn.unit_lower_inverse(A), _by_the_solve(A)
+        dA = jax.grad(lambda A: jnp.sum(gdn.unit_lower_inverse(A) * proj))(A)
+        dA_want = jax.grad(lambda A: jnp.sum(_by_the_solve(A) * proj))(A)
+        assert T.dtype == A.dtype and dA.dtype == A.dtype
+        _close(T, want, tol)
+        # autodiff through the solve leaves a cotangent above the diagonal,
+        # where A is zero by construction; the rule returns none there
+        _close(dA, jnp.tril(dA_want, -1), 10 * tol)
+        if kind == "one_key" and C >= 64 and dtype == "float32":
+            off = jnp.max(jnp.abs(_whole_chunk_neumann(A) - want))
+            assert float(jnp.max(jnp.abs(want))) < 1.01 and float(off) > 1e3
+
+
+def test_layer_on_a_row_of_one_repeated_id_matches_the_reference(ref):
+    """Every token the same: after the convolution's first three positions
+    every key of a chunk is the same vector, the case above inside the
+    layer, at the cell's chunk of 64."""
+    d = ARGS["hidden_size"]
+    _check_layer_against_the_reference(
+        ref, "gated_delta_net",
+        jnp.broadcast_to(_normal(77, 1, 1, d), (1, 150, d)), chunk=64)
+
+
+def test_backward_pass_finds_T_and_inverts_nothing_again(monkeypatch, capsys):
+    """The row's `jax.checkpoint` keeps the chunk inverses and nothing else
+    of the mixer: T is among the saved residuals, and the backward pass
+    holds the rule's own two products at HIGHEST and none of the forward
+    merge's two. With the policy taken away T is gone from the residuals
+    and the forward's products are back, so this can fail."""
+    from paddle_tpu.layers import attention
+
+    out, _ = _layer_case("gated_delta_net", ARGS, chunk=64)
+    topo = Topology(out)
+    T, d = 128, ARGS["hidden_size"]
+    params = {k: _normal(i, *s.shape, scale=0.3)
+              for i, (k, s) in enumerate(sorted(topo.param_specs().items()))}
+    x = _normal(77, 1, T, d)
+    kept = f"f32[{ARGS['linear_num_value_heads']},{T // 64},{64 * 64}]"
+
+    def f(params, x):
+        return jnp.sum(topo.forward(params, {"x": Arg(x, jnp.ones((1, T)))},
+                                    training=True)["l"].value ** 2)
+
+    def saved_and_products():
+        capsys.readouterr()
+        jax.ad_checkpoint.print_saved_residuals(f, params, x)
+        saved = capsys.readouterr().out
+        # outside this file's "highest" default, only the inverse's own
+        # products say HIGHEST
+        with jax.default_matmul_precision("default"):
+            _, pull = jax.vjp(f, params, x)
+            backward = str(jax.make_jaxpr(pull)(jnp.float32(1.0)))
+        return saved, backward.count("Precision.HIGHEST, Precision.HIGHEST")
+
+    # the one row as a plain call, so that the checkpoint's own residuals
+    # are what is listed, not a scan's
+    monkeypatch.setattr(attention.jax.lax, "map",
+                        lambda f, xs: jnp.stack([f(x) for x in xs]))
+    saved, products = saved_and_products()
+    assert kept in saved and "chunk_prepare" in saved, saved
+    assert products == 2
+
+    monkeypatch.setattr(attention.jax.checkpoint_policies,
+                        "save_only_these_names",
+                        lambda *names: jax.checkpoint_policies.nothing_saveable)
+    saved, products = saved_and_products()
+    assert kept not in saved, saved
+    assert products == 4
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
