@@ -48,8 +48,8 @@ WORK = os.path.join(REPO, ".chip_smoke")          # scratch, git-ignored
 DAEMON = os.path.join(REPO, "paddle_tpu", "native", "paddle_tpu_serving")
 CHILD_TIMEOUT_S = 1000
 
-# The NMT the repo publishes (models/text.py nmt_attention_cost defaults,
-# bench.py's north star): nothing cut but the number of batches.
+# The NMT the repo publishes (models/text.py nmt_attention_cost defaults):
+# nothing cut but the number of batches.
 TRAIN = dict(vocab=30000, width=512, batch=256, n_batches=8, max_len=31,
              lr=5e-4, seed=21,
              # the same program's cost per token on the CPU (jax 0.9.0, this
@@ -367,7 +367,7 @@ def kernel_parity(kind, shape, dtype_name, interpret, seed):
 
 def sync_probe(n, iters):
     """Does block_until_ready wait for the device on this backend?
-    (bench.py syncs by fetching the cost because it once did not.) One
+    (On a retired transport it did not, and timing code fetched a value.) One
     jitted chain of matmuls, long enough to tell dispatch from execution."""
     import jax
     import jax.numpy as jnp

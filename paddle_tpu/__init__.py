@@ -57,8 +57,8 @@ def init(**kwargs):
 
 def compile_cache():
     """Place JAX's persistent compilation cache for a process that will
-    compile for the device (the CLI, chip_smoke.py's children, bench.py,
-    the tools that time on the chip). Returns the directory in use, or
+    compile for the device (the CLI, chip_smoke.py's children, the tools
+    that time on the chip). Returns the directory in use, or
     None when there is none.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, so this sets

@@ -25,8 +25,6 @@ from __future__ import annotations
 import math
 from typing import Tuple
 
-import functools
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -318,64 +316,6 @@ def _deconv3d(cfg, params, ins, ctx):
 
 # --- pooling --------------------------------------------------------------
 
-# max-pool backward implementation switch: "sas" = XLA select-and-
-# scatter (default; 61% of peak HBM BW on the ResNet stem — r4, not
-# re-measured);
-# "eq" = equality-based backward — grad_x[p] = sum over covering
-# windows of (x[p] == y[w]) * g[w], expressed as K*K dilated-pad
-# shifted views so XLA can fuse the whole thing into the adjacent
-# elementwise chain (ReLU bwd). Tie semantics: ALL maxima receive the
-# window cotangent — which DIVERGES from select-and-scatter (one winner)
-# on tied inputs, and post-ReLU feature maps tie at 0.0 constantly, so
-# this is NOT a drop-in for training; it lost the r5 A/B anyway
-# (139.9 vs 96.3 ms/step; r5, not re-measured — XLA does not fuse the
-# k*k shifted passes) and stays an opt-in documented experiment.
-MAXPOOL_BWD = "sas"
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _maxpool_eq(v, dims, strides, pads):
-    return lax.reduce_window(v, -jnp.inf, lax.max, dims, strides, pads)
-
-
-def _maxpool_eq_fwd(v, dims, strides, pads):
-    y = lax.reduce_window(v, -jnp.inf, lax.max, dims, strides, pads)
-    return y, (v, y)
-
-
-def _maxpool_eq_bwd(dims, strides, pads, res, g):
-    v, y = res
-    _, ky, kx, _ = dims
-    _, sy, sx, _ = strides
-    (_, _), (py_lo, _), (px_lo, _), (_, _) = pads
-    B, H, W, C = v.shape
-    OH, OW = y.shape[1], y.shape[2]
-    grad = jnp.zeros_like(v, jnp.float32)
-    gf = g.astype(jnp.float32)
-
-    def upsample(a, fill, i, j):
-        """Place a[w] at the input pixel window w's (i, j) cell covers:
-        interior (stride-1) dilation + edge offset; negative edge pads
-        trim out-of-extent cells."""
-        low_h = i - py_lo
-        low_w = j - px_lo
-        high_h = H - low_h - ((OH - 1) * sy + 1)
-        high_w = W - low_w - ((OW - 1) * sx + 1)
-        return lax.pad(a, jnp.array(fill, a.dtype),
-                       [(0, 0, 0), (low_h, high_h, sy - 1),
-                        (low_w, high_w, sx - 1), (0, 0, 0)])
-
-    for i in range(ky):
-        for j in range(kx):
-            y_up = upsample(y, -jnp.inf, i, j)
-            g_up = upsample(gf, 0.0, i, j)
-            grad = grad + jnp.where(v == y_up, g_up, 0.0)
-    return (grad.astype(v.dtype),)
-
-
-_maxpool_eq.defvjp(_maxpool_eq_fwd, _maxpool_eq_bwd)
-
-
 def _pool_infer(cfg, in_infos):
     c = cfg.attr("num_channels")
     h = cfg.attr("img_size_y") or cfg.attr("img_size")
@@ -426,19 +366,9 @@ def _pool(cfg, params, ins, ctx):
     dims = (1, ky, k, 1)
     strides = (1, sy, s, 1)
     if "max" in ptype:
-        # NOTE: a Pallas backward for the stem geometry exists
-        # (kernels/pool.py, correctness-proven incl. reference all-ties
-        # semantics) but is NOT wired in: on this chip Mosaic rejects
-        # bf16 compares in split layouts, and the forced f32 whole-image
-        # working set (78MB VMEM stack) made it 14x slower than XLA's
-        # select-and-scatter (negative result; r4, not re-measured). An
-        # equality-based fusable backward (MAXPOOL_BWD="eq") is the r5
-        # experiment on the same op — see _maxpool_eq_bwd.
-        if MAXPOOL_BWD == "eq":
-            out = _maxpool_eq(v, dims, strides, pads)
-        else:
-            out = lax.reduce_window(v, -jnp.inf, lax.max, dims, strides,
-                                    pads)
+        # backward is XLA's select-and-scatter: one winner per window,
+        # also on ties (post-ReLU zeros)
+        out = lax.reduce_window(v, -jnp.inf, lax.max, dims, strides, pads)
     else:
         ssum = lax.reduce_window(v, 0.0, lax.add, dims, strides, pads)
         if cfg.attr("exclude_mode", True) and (p or py or extra_h or extra_w):

@@ -3,14 +3,13 @@
 Analogs of paddle/gserver/layers/{CRFLayer,CRFDecodingLayer,
 LinearChainCRF,CTCLayer,WarpCTCLayer}.cpp. The reference implements the
 forward-backward recursions as hand-written CPU loops (LinearChainCRF.cpp)
-and links warp-ctc CUDA for GPU; here both dynamic programs have TWO
-TPU implementations, switched by backend (CRF_IMPL / CTC_IMPL): a
+and links warp-ctc CUDA for GPU; here both dynamic programs are a
 ``lax.scan`` recursion in log space (fully differentiable — autodiff
-yields the posterior-marginal gradients the reference derives by hand;
-the CPU/reference path), and Pallas forward-backward kernels
-(kernels/crf.py, kernels/ctc.py) with the time loop fused in-kernel and
-EXPLICIT marginal backward passes — the long-sequence path on TPU.
-Both are masked for padding.
+yields the posterior-marginal gradients the reference derives by hand).
+The CRF partition function also has a Pallas forward-backward kernel
+(kernels/crf.py) with the time loop fused in-kernel and an EXPLICIT
+marginal backward pass — the long-sequence path on TPU, chosen by
+``_crf_use_pallas``. Both are masked for padding.
 
 CRF parameter layout (LinearChainCRF.cpp parity): w is (L+2) x L —
 row 0 = start weights a, row 1 = end weights b, rows 2.. = transition
@@ -38,25 +37,21 @@ def _crf_pieces(w):
     return w[0], w[1], w[2:]          # start, end, trans [L, L]
 
 
-# CRF implementation switch (mirrors CTC_IMPL below): "auto" runs the
-# Pallas forward-backward kernel (kernels/crf.py) for the partition
-# function on the TPU backend for LONG sequences, the lax.scan
+# The Pallas forward-backward kernel (kernels/crf.py) computes the
+# partition function on the TPU backend for LONG sequences, the lax.scan
 # recursion elsewhere. Crossover on a v5e (B=32, L=64, fwd+bwd): T=128
 # scan wins 1.2x, T=512 pallas 1.2x, T=2048 pallas 3.7x — threshold at
 # 256 (tools/ctc_bench.py; r5, not re-measured).
-CRF_IMPL = "auto"
 _CRF_PALLAS_MIN_T = 256
 
 
-def _crf_use_pallas(T=None):
+def _crf_use_pallas(T):
     from paddle_tpu.kernels._pallas_util import take_pallas
 
-    if CRF_IMPL != "auto":
-        return CRF_IMPL == "pallas"
     if jax.config.jax_disable_jit:
         return take_pallas("crf", "crf_logz", False,
                            "jax_disable_jit: interpreter/reference mode")
-    if T is not None and T < _CRF_PALLAS_MIN_T:
+    if T < _CRF_PALLAS_MIN_T:
         return take_pallas("crf", "crf_logz", False,
                            f"T={T} < {_CRF_PALLAS_MIN_T}, scan wins there")
     return take_pallas("crf", "crf_logz")
@@ -120,7 +115,7 @@ def crf_logz_pallas(emit, mask, w, interpret=False):
     return logz[:B0]
 
 
-def crf_nll(emit, labels, mask, w, interpret=False):
+def crf_nll(emit, labels, mask, w):
     """Negative log-likelihood of label paths under a linear-chain CRF.
 
     emit: [B, T, L] unary scores; labels: [B, T] int; mask: [B, T].
@@ -128,9 +123,8 @@ def crf_nll(emit, labels, mask, w, interpret=False):
     if _crf_use_pallas(emit.shape[1]):
         from paddle_tpu.kernels._pallas_util import call_kernel
 
-        logZ = call_kernel(
-            lambda emit, mask, w: crf_logz_pallas(emit, mask, w, interpret),
-            (emit, mask, w), batch_argnums=(0, 1))
+        logZ = call_kernel(crf_logz_pallas, (emit, mask, w),
+                           batch_argnums=(0, 1))
     else:
         logZ = crf_logz_scan(emit, mask, w)
     return logZ - _crf_gold_score(emit, labels, mask, w)
@@ -290,25 +284,6 @@ def _ctc_infer(cfg, in_infos):
     return ArgInfo(size=1)
 
 
-# CTC implementation switch: "auto" keeps the lax.scan recursion
-# everywhere — a MEASURED negative result (tools/ctc_bench.py; r5,
-# not re-measured): the Pallas CTC kernel (kernels/ctc.py) passes
-# on-chip parity (fwd 6.9e-5, tpu_parity) but runs 0.35-0.58x the scan
-# path on v5e at every T in {128, 512, 2048} — the [B, S] banded
-# recursion has no MXU work, and its per-step lane shifts cost more
-# than XLA's fused scan body. Kept selectable ("pallas") and fully
-# tested; the CRF
-# kernel (dense L x L transitions = MXU matmuls per step) is where
-# the in-kernel time loop wins (CRF_IMPL above).
-CTC_IMPL = "auto"
-
-
-def _ctc_use_pallas():
-    if CTC_IMPL != "auto":
-        return CTC_IMPL == "pallas"
-    return False
-
-
 @register_layer("ctc", infer=_ctc_infer)
 def _ctc_layer(cfg, params, ins, ctx):
     """CTCLayer: input 0 = frame logits/probs seq [B,T,C]; input 1 = label
@@ -325,11 +300,7 @@ def _ctc_layer(cfg, params, ins, ctx):
     ids = lab.value.astype(jnp.int32)
     if ids.ndim == 3:
         ids = ids[..., 0]
-    if _ctc_use_pallas():
-        from paddle_tpu.kernels.ctc import ctc_nll_pallas
-        nll = ctc_nll_pallas(x.value, ids, x.mask, lab.mask, blank)
-    else:
-        nll = ctc_nll(x.value, ids, x.mask, lab.mask, blank)
+    nll = ctc_nll(x.value, ids, x.mask, lab.mask, blank)
     if cfg.attr("norm_by_times", False):
         nll = nll / jnp.maximum(x.mask.sum(-1), 1.0)
     coeff = cfg.attr("coeff", 1.0)

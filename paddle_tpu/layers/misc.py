@@ -146,10 +146,9 @@ def _selfc_params(cfg, in_infos):
 #   make_train_step's tangent-slot protocol): dW is a (rows, values)
 #   SparseRowGrad applied per-row by the optimizer — no [C, D] buffer
 #   anywhere — and the end-to-end train-step crossover drops well below
-#   1M (BENCH_EXTRA_r06.md: r6 harness shows gather+sparse-dW beating
-#   dense-mask at every measured C from 65k up, 3.1-4x on the 3D shape;
-#   r6 was a CPU round, so 256k is kept as the conservative committed
-#   default pending the v5e re-measure).
+#   1M (r6, a CPU round: gather+sparse-dW beat dense-mask at every
+#   measured C from 65k up; not measured on the chip, ROADMAP S2, so
+#   256k is kept as the conservative committed default).
 # The layer picks the regime at trace time (the sparse protocol
 # announces itself via ctx.sparse_collect/sparse_tangents); a per-layer
 # ``gather_min_c`` cfg overrides both — the selective-decode wiring

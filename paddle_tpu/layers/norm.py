@@ -117,7 +117,7 @@ def _batch_norm(cfg, params, ins, ctx):
     # elementwise chain to f32 — under bf16 mixed precision XLA then
     # materialises f32 activations in the backward remat chain (profiled
     # 1.15 GB moved per 56x56 stage fusion vs ~0.3 GB of bf16 operands,
-    # PERF_r03.md). Per-channel math stays f32/f64; only the big
+    # r3, retired setup). Per-channel math stays f32/f64; only the big
     # elementwise apply runs in x.dtype (the standard mixed-precision BN).
     inv = jax.lax.rsqrt(var_b + eps) * g
     scale = inv.astype(x.dtype)

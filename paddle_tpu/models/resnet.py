@@ -55,7 +55,7 @@ def resnet_imagenet(input_image, num_channels=3, img_size=224, depth=50,
     # relu(maxpool(bn(conv))) == maxpool(relu(bn(conv))) for the monotone
     # relu, but the pooled-first order shrinks the relu backward mask from
     # 112^2 to 56^2 — ~1 ms/step of HBM traffic on the bench chip
-    # (PERF_r03.md); numerics identical to the reference order.
+    # (r3, retired setup); numerics identical to the reference order.
     c1 = conv_bn(input_image, 64, 7, 2, 3, False, "res_conv1")      # /2
     p0 = layer.img_pool(input=c1, pool_size=3, stride=2, padding=1,
                         pool_type=pooling.Max(), ceil_mode=False,

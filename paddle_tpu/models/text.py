@@ -92,8 +92,8 @@ def ctr_wide_deep(wide_dim=10000, deep_vocab=10000, emb_dim=16, max_ids=32,
     ``host_resident=True`` marks both tables host-resident
     (docs/embedding_cache.md): they never exist in device memory — the
     trainer stages a per-batch row cache instead — which is what lets
-    ``deep_vocab`` go to 100M+ rows (bench.py --model ctr, the SURVEY
-    §2.3 production-recommender scenario)."""
+    ``deep_vocab`` go to 100M+ rows (the SURVEY §2.3
+    production-recommender scenario)."""
     wide_in = layer.data(name="wide_ids",
                          type=data_type.sparse_binary_vector(wide_dim,
                                                              max_ids=max_ids))
@@ -122,7 +122,7 @@ def ctr_wide_deep(wide_dim=10000, deep_vocab=10000, emb_dim=16, max_ids=32,
 def nmt_attention_cost(src_dict_dim=30000, trg_dict_dim=30000,
                        word_vector_dim=512, encoder_size=512,
                        decoder_size=512, name="m"):
-    """The NMT benchmark training topology (the bench.py north star):
+    """The NMT benchmark training topology (the `nmt-train-b512` cell):
     bidirectional-GRU encoder + Bahdanau-attention GRU decoder
     (networks.gru_encoder_decoder) with teacher forcing and per-token
     cross entropy. Feeds: src / trg / trg_next integer sequences.
@@ -150,8 +150,8 @@ def nmt_attention_cost(src_dict_dim=30000, trg_dict_dim=30000,
 def nmt_packed_cost(src_dict_dim=30000, trg_dict_dim=30000,
                     word_vector_dim=512, encoder_size=512,
                     decoder_size=512, num_heads=8, name="mp"):
-    """Packing-ready NMT training topology (`bench.py --model nmt_packed`,
-    docs/packing.md): the attention seq2seq rebuilt from the SEGMENT-AWARE
+    """Packing-ready NMT training topology (docs/packing.md): the
+    attention seq2seq rebuilt from the SEGMENT-AWARE
     full-sequence layers, so the same graph trains on padded one-sample
     rows AND on packed multi-sequence rows with seg_ids —
 
@@ -204,8 +204,7 @@ def nmt_decode_topology(src_dict_dim=30000, trg_dict_dim=30000,
                         decoder_size=512, beam_size=4, max_length=16,
                         cand_k=1024, mode="compact", early_exit=True,
                         name="m"):
-    """The NMT generation topology behind `bench.py --model nmt_decode`
-    and tools/decode_sweep.py: the training preset's encoder/decoder in
+    """The NMT generation topology: the training preset's encoder/decoder in
     beam-search generation mode, with the decode path selected by
     ``mode`` (docs/decode.md):
 

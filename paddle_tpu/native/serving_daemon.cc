@@ -802,8 +802,8 @@ struct DecodeBackend {
   virtual std::string prepare(DecodeReq* /*r*/) { return ""; }
 };
 
-// Deterministic toy decode model (see file header). Token rule (tests
-// and bench.py reproduce it bit for bit in Python):
+// Deterministic toy decode model (see file header). Token rule (the
+// tests reproduce it bit for bit in Python):
 //   digest = fold(src):  d = (d * 1000003 + id) mod 2^64,  d0 = 0
 //   gen_len(r) = digest % max_new + 1
 //   token(t)   = ((digest ^ ((t+1) * 0x9E3779B97F4A7C15)) >> 17)
@@ -1889,8 +1889,7 @@ struct Daemon {
                                   // the infer twin of --toy_tick_us:
                                   // one device, one dispatch queue, a
                                   // fixed price per execute regardless
-                                  // of gathered rows (bench.py
-                                  // --model serving --batch)
+                                  // of gathered rows
   std::mutex exec_dev_mu;
 
   // One served model: its live bundle pointer (swapped atomically by
@@ -3959,9 +3958,9 @@ struct Daemon {
   // --infer_exec_us: a fixed SERIALIZED cost per infer execute — the
   // toy model of a single accelerator's dispatch queue, the infer twin
   // of --toy_tick_us on the decode side. The per-request path pays it
-  // once per request; a gathered window pays it once per BATCH — so
-  // bench.py --model serving --batch isolates the batcher's
-  // amortization the way the scheduler A/B isolates admission.
+  // once per request; a gathered window pays it once per BATCH, which
+  // isolates the batcher's amortization the way --toy_tick_us isolates
+  // admission.
   void charge_exec() {
     if (infer_exec_us <= 0) return;
     std::lock_guard<std::mutex> l(exec_dev_mu);

@@ -17,8 +17,7 @@ Instrumentation is host-side only: enabling any of it changes no jaxpr
 """
 
 from paddle_tpu.observability import exporter, metrics, trace  # noqa: F401
-from paddle_tpu.observability.metrics import (COUNT_BUCKETS,  # noqa: F401
-                                              DEFAULT_BUCKETS,
+from paddle_tpu.observability.metrics import (DEFAULT_BUCKETS,  # noqa: F401
                                               MetricsRegistry, bench_extras,
                                               counter, default_registry,
                                               gauge, histogram, log_buckets)

@@ -16,10 +16,10 @@ subsystem (trace.py is the spans half, exporter.py the egress):
   scrape never sees counter A after an increment but histogram B before
   its matching observe),
 - ``delta()``: change since the previous ``delta()`` call — what a
-  periodic scraper or a bench run wants (per-window counts, not
+  periodic scraper or a test wants (per-window counts, not
   process-lifetime totals),
 - Prometheus text exposition (``to_prometheus``) and a JSON dump
-  (``to_json``) for the file exporter / bench artifacts.
+  (``to_json``) for the file exporter.
 
 Everything here is host-side pure Python: instrumented call sites time
 around jitted functions, never inside them, so enabling metrics cannot
@@ -442,14 +442,9 @@ def histogram(name: str, help_str: str = "", labels: Sequence[str] = (),
     return default_registry.histogram(name, help_str, labels, buckets)
 
 
-#: fixed integer-ish buckets for tick/count histograms (decode ticks,
-#: queue depths): 1..4096 at powers of two
-COUNT_BUCKETS = tuple(float(2 ** i) for i in range(13))
-
-
 def bench_extras(delta: Optional[dict] = None,
                  registry: Optional[MetricsRegistry] = None) -> dict:
-    """Compact nonzero-only summary for bench JSON artifacts: counter
+    """Compact nonzero-only summary of a snapshot or a delta: counter
     totals, gauge values, histogram (count, sum). Keys flatten to
     'name{k=v}'."""
     reg = registry or default_registry

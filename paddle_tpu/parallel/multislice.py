@@ -337,7 +337,7 @@ class MultiSliceTrainer(SGD):
     baseline — same hierarchical reduction, N times the state bytes);
     ``hierarchical=False`` collapses the two reduction stages into one
     flat all-reduce spanning both axes (what plain DataParallelTrainer's
-    GSPMD program does), for the bench columns.
+    GSPMD program does), as the comparison.
 
     Trajectory: a ZeRO run is allclose to the replicated DP run over
     the same batch stream — losses, final params, and (canonical)

@@ -29,7 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 def schedule_ticks(num_micro: int, num_stages: int) -> int:
     """Ticks one schedule runs: M microbatches drain through S stages in
     M + S - 1 ticks; each device is busy in M of them, so the bubble
-    fraction is (S - 1) / (M + S - 1) (the GPipe model, PERF_r05)."""
+    fraction is (S - 1) / (M + S - 1) (the GPipe model)."""
     return num_micro + num_stages - 1
 
 

@@ -23,7 +23,7 @@ reference feeding every ParallelNeuralNetwork thread the full Argument
 vector.
 
 Because D_max and P_max are maxima over stages, BOTH buffers are sized by
-the single fattest stage: PERF_r05 measured ~33% padding waste from the
+the single fattest stage: r5 measured ~33% padding waste from the
 naive inherit-from-inputs assignment on the NMT enc|dec split.
 :func:`balanced_stage_assignment` (``PipelinedTopology(balance=True)``)
 replaces it with a width-balanced partition: per-layer costs (boundary
@@ -54,8 +54,8 @@ from paddle_tpu.utils.error import enforce
 #: static padding waste of the two stage-uniform buffers (set when the
 #: plan's packers/param matrix are built): kind="param" is the [S, P_max]
 #: matrix fraction that is padding, kind="boundary" the boundary buffer's.
-#: The balancer exists to push these down; tools/pp_accounting.py and
-#: bench --model pipeline --pipeline_trainer pp surface them.
+#: The balancer exists to push these down; tools/pp_accounting.py
+#: surfaces them.
 _M_PP_PAD = obs_metrics.gauge(
     "paddle_pp_stage_padding_fraction",
     "Fraction of the stage-uniform pipeline buffer that is padding "
@@ -210,7 +210,7 @@ _F_TIER = 0.03
 def balanced_stage_assignment(topology: Topology, num_stages: int,
                               stage_map: Optional[Dict[str, int]] = None,
                               seq_len_hint: int = 16):
-    """Width-balanced layer->stage partition (the PERF_r05 fix).
+    """Width-balanced layer->stage partition (the fat-stage padding's fix).
 
     Chooses ``num_stages - 1`` cut points over the ALAP-sorted layer
     chain to minimize the maxima that size the pipeline's uniform

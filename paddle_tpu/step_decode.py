@@ -10,23 +10,15 @@ finished is re-admitted with a NEW request's encoder state at the next
 tick (mid-decode), in drain mode admissions only enter an all-idle
 batch (classic static batching, the A/B baseline).
 
-Two consumers:
-
-- the export-parity suite (tests/test_export_parity.py): tick-by-tick
-  slot decode is bit-identical on ids/ticks to the whole-``while_loop``
-  module and to live Python decode, and scheduling policy never
-  changes results (a mid-decode-admitted request matches its solo
-  decode exactly);
-- ``bench.py --model serving``: the real-decode continuous-vs-drain
-  A/B on hosts without a loadable PJRT plugin — the jax.export
-  artifacts execute through the CPU interp path, so the columns
-  measure the real model's scheduler win (requests/sec, p95, TTFT)
-  end to end.
+Its consumer is the export-parity suite (tests/test_export_parity.py):
+tick-by-tick slot decode is bit-identical on ids/ticks to the
+whole-``while_loop`` module and to live Python decode, and scheduling
+policy never changes results (a mid-decode-admitted request matches its
+solo decode exactly).
 """
 
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -46,10 +38,6 @@ class StepDecodeRequest:
         #: when the export carries it, else scheduler-side truncation
         self.max_new = max_new
         self.slot: Optional[int] = None
-        self.submit_time = 0.0
-        self.admit_time = 0.0
-        self.first_token_time: Optional[float] = None
-        self.done_time = 0.0
         self.admit_tick = -1           # global scheduler tick at admission
         self.mid_batch = False         # admitted while other slots live
         self.tokens: List[int] = []    # streamed best-hypothesis tokens
@@ -117,7 +105,6 @@ class StepDecodeDriver:
                max_new: Optional[int] = None) -> StepDecodeRequest:
         r = StepDecodeRequest(feeds, max_new=max_new)
         r._eos_id = self.eos_id
-        r.submit_time = time.perf_counter()
         self.queue.append(r)
         return r
 
@@ -148,7 +135,6 @@ class StepDecodeDriver:
         self.slot_req[slot] = r
         r.slot = slot
         r.admit_tick = self.tick_count
-        r.admit_time = time.perf_counter()
         r.mid_batch = n_live_entry > 0
         self.admissions["mid_batch" if r.mid_batch else "fresh"] += 1
 
@@ -177,19 +163,15 @@ class StepDecodeDriver:
         for n in self.state_names:
             self.state[n] = named[n]
         self.tick_count += 1
-        now = time.perf_counter()
         for s in range(self.S):
             r = self.slot_req[s]
             if r is None:
                 continue
             r.ticks += 1
             r.tokens.append(int(named["emitted"][s]))
-            if r.first_token_time is None:
-                r.first_token_time = now
             if named["done"][s]:
                 r.ids = np.array(self.state["state:ids"][s])
                 r.scores = np.array(self.state["state:scores"][s])
-                r.done_time = now
                 self.finished.append(r)
                 self.slot_req[s] = None
 

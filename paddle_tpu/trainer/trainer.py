@@ -175,8 +175,7 @@ def make_train_step(loss, optimizer, static, lr_mults=None, evaluators=None,
                     host_tables=()):
     """Build THE jitted train step (TrainerInternal::trainOneBatch as one
     XLA program): forward+backward, optimizer update, batch-norm EMA
-    fold-in, metrics. Shared by the SGD trainer and bench.py so the
-    benchmark measures exactly the program training runs.
+    fold-in, metrics.
 
     ``accum_steps > 1`` reproduces the reference's local gradient
     accumulation (``num_batches_per_send_parameter``,
@@ -308,40 +307,8 @@ def make_train_step(loss, optimizer, static, lr_mults=None, evaluators=None,
                     cost, metrics)
 
     if not jit_compile:
-        return step     # raw body, e.g. for a device-side lax.scan loop
+        return step     # raw body
     return jax.jit(step, donate_argnums=(0, 1) if donate else ())
-
-
-def make_train_loop(loss, optimizer, static, steps_per_call,
-                    lr_mults=None, donate=True):
-    """BENCH-ONLY device-side loop: ``steps_per_call`` train steps as ONE
-    jitted program (lax.scan over the step body), re-using the SAME feeds
-    for every scanned step. Real training must use make_train_step — this
-    loop would silently train repeatedly on one batch, and ms/step numbers
-    derived from it exclude input-streaming cost. Its only callers are
-    bench.py's small-model modes, which use it to keep per-dispatch host
-    overhead out of a ms-scale step time; the reference's
-    TrainerInternal dispatches per batch because a CPU host drives GPUs."""
-    import os
-    if os.environ.get("PADDLE_TPU_ALLOW_SCAN_LOOP", "0").lower() in (
-            "0", "", "false"):
-        import warnings
-        warnings.warn("make_train_loop is a bench-only single-batch loop; "
-                      "use make_train_step for real training", stacklevel=2)
-    body = make_train_step(loss, optimizer, static, lr_mults,
-                           evaluators=None, donate=False, jit_compile=False)
-
-    def loop(params, opt_state, rng, feeds):
-        def tick(carry, i):
-            p, s = carry
-            p, s, c, _ = body(p, s, jax.random.fold_in(rng, i), feeds)
-            return (p, s), c
-
-        (params, opt_state), costs = jax.lax.scan(
-            tick, (params, opt_state), jnp.arange(steps_per_call))
-        return params, opt_state, costs[-1]
-
-    return jax.jit(loop, donate_argnums=(0, 1) if donate else ())
 
 
 def init_accum_state(opt_state, params):

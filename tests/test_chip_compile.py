@@ -258,23 +258,6 @@ def test_crf_logz_train_compiles(one_chip, B, T, L):
     assert n >= 2
 
 
-# the shapes tools/ctc_bench.py timed the (unwired, CTC_IMPL="pallas")
-# CTC kernel at
-@pytest.mark.parametrize("B,T,C,U", [(32, 512, 128, 20)])
-def test_ctc_train_compiles(one_chip, B, T, C, U):
-    from paddle_tpu.kernels.ctc import ctc_nll_pallas
-
-    def train(logits, labels, in_mask, label_mask):
-        return jax.value_and_grad(lambda l: jnp.sum(
-            ctc_nll_pallas(l, labels, in_mask, label_mask)))(logits)
-
-    _c, n = _compile(train, _sds((B, T, C), jnp.float32, one_chip),
-                     _sds((B, U), jnp.int32, one_chip),
-                     _sds((B, T), jnp.float32, one_chip),
-                     _sds((B, U), jnp.float32, one_chip))
-    assert n >= 2
-
-
 def test_fused_gru_under_data_parallel_compiles(topo):
     """GSPMD cannot partition a Mosaic kernel; DataParallelTrainer's step
     wraps each in a shard_map over the batch (kernels/_pallas_util). The

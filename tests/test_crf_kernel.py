@@ -62,18 +62,33 @@ def test_dtrans_with_disfavored_transitions():
                                rtol=1e-4, atol=1e-5)
 
 
-def test_crf_nll_switch_and_parity():
+def test_crf_nll_kernel_and_scan_parity():
+    """The layer's nll with the kernel's log partition (kernels.crf.crf_logz,
+    time-major, the way crf_logz_pallas feeds it) equals the scan path's."""
+    from paddle_tpu.kernels.crf import crf_logz
+
     emit, labels, mask, w = _case(seed=1)
-    old = cc.CRF_IMPL
-    try:
-        cc.CRF_IMPL = "scan"
-        want = cc.crf_nll(emit, labels, mask, w)
-        cc.CRF_IMPL = "pallas"
-        got = cc.crf_nll(emit, labels, mask, w, interpret=True)
-    finally:
-        cc.CRF_IMPL = old
+    want = cc.crf_nll(emit, labels, mask, w)        # scan: the CPU backend
+    start, end, trans = cc._crf_pieces(w)
+    logz = crf_logz(jnp.swapaxes(emit, 0, 1), jnp.swapaxes(mask, 0, 1),
+                    start, end, trans, True)
+    got = logz - cc._crf_gold_score(emit, labels, mask, w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("backend,no_jit,T", [
+    ("cpu", False, 2048), ("tpu", True, 2048), ("tpu", False, 255)],
+    ids=["backend", "disable_jit", "short"])
+def test_crf_use_pallas_is_the_only_selector(monkeypatch, backend, no_jit, T):
+    """The scan runs wherever the kernel cannot or should not: off the TPU
+    backend, under jax_disable_jit, and below the measured crossover
+    (`_CRF_PALLAS_MIN_T`). Nothing a user sets overrides that."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert cc._crf_use_pallas(2048) is True         # the control
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with jax.disable_jit(no_jit):
+        assert cc._crf_use_pallas(T) is False
 
 
 def test_fd_check_f64():

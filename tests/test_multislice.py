@@ -399,18 +399,3 @@ def test_zero_accounting_tool():
         assert r["within_bound"], (name, r)
         if name != "sgd":        # plain SGD keeps no per-param slots
             assert r["drop"] >= 3.0, (name, r)
-
-
-def test_bench_multislice_quick_smoke():
-    import bench
-
-    res = bench.bench_multislice(quick=True)
-    assert res["metric"] == "multislice_train_ms_per_batch"
-    cols = res["extra"]["columns"]
-    assert set(cols) == {"replicated_flat", "replicated_hierarchical",
-                         "zero_flat", "zero_hierarchical"}
-    for col in cols.values():
-        assert col["ms_per_batch"] > 0
-        assert col["per_chip_opt_state_mb"] > 0
-    assert (cols["zero_hierarchical"]["per_chip_opt_state_mb"]
-            < cols["replicated_hierarchical"]["per_chip_opt_state_mb"])

@@ -546,9 +546,44 @@ def test_every_pallas_call_has_a_literal_unique_name():
                     and isinstance(kw["name"].value, str), \
                     f"{fn}:{node.lineno}: pallas_call without a literal name="
                 names.append(kw["name"].value)
-    assert len(names) >= 13
+    assert len(names) >= 12
     assert len(set(names)) == len(names), sorted(names)
     assert {"fused_gru_fwd", "fused_gru_bwd"} <= set(names)
+
+
+def test_every_metric_family_the_doc_names_is_registered():
+    """docs/observability.md is the catalog: every `paddle_*` family it names
+    is registered by the module that holds its name (imported here), or, for
+    `paddle_serving_*`, written by the C++ daemon. A family whose only
+    feeder left the tree has to leave the document with it."""
+    import importlib
+    import re
+
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    with open(os.path.join(root, "docs", "observability.md")) as f:
+        named = set(re.findall(r"`(paddle_[a-z0-9_]*[a-z0-9])\b", f.read()))
+    named -= {"paddle_tpu", "paddle_tpu_serving"}
+    assert len(named) > 90
+    with open(os.path.join(root, "paddle_tpu", "native",
+                           "serving_daemon.cc")) as f:
+        daemon = f.read()
+    sources = {}
+    pkg = os.path.join(root, "paddle_tpu")
+    for d, _dirs, files in os.walk(pkg):
+        for fn in files:
+            if fn.endswith(".py"):
+                mod = os.path.relpath(os.path.join(d, fn), root)[:-3]
+                with open(os.path.join(d, fn)) as f:
+                    sources[mod.replace(os.sep, ".")] = f.read()
+    served = {n for n in named if n.startswith("paddle_serving_")}
+    for mod, src in sources.items():
+        if any('"%s"' % name in src for name in named - served):
+            importlib.import_module(mod[:-9] if mod.endswith(".__init__")
+                                    else mod)
+    registered = obs_metrics.default_registry.snapshot()
+    missing = sorted(n for n in named - served if n not in registered) \
+        + sorted(n for n in served if '"%s' % n not in daemon)
+    assert not missing, missing
 
 
 def test_moe_routing_counters_are_declared():

@@ -10,7 +10,7 @@ Also pins the ISSUE 6 satellites: the segment_sum rewrite of
 _segment_pool against the one-hot reference, bucket_rounding, the fused
 LSTM/GRU mask/reset edge cases (interpret-mode vs scan-path), the
 sort_within_buffer reader window with checkpointable resume, and the
-bench.py nmt_packed --quick smoke.
+packing-ready NMT (models/text.nmt_packed_cost) under one three-slot plan.
 """
 
 import numpy as np
@@ -282,7 +282,8 @@ def test_segment_pool_matches_onehot_exactly(how):
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 
 
-def test_segment_pool_random_floats_allclose():
+@pytest.mark.parametrize("how", ["sum", "average", "squarerootn", "max"])
+def test_segment_pool_random_floats_allclose(how):
     from paddle_tpu.layers.sequence import _segment_pool, \
         _segment_pool_onehot
 
@@ -291,13 +292,11 @@ def test_segment_pool_random_floats_allclose():
     v = jnp.asarray(rs.randn(B, T, 3), jnp.float32)
     seg = jnp.asarray(rs.randint(0, S, (B, T)), jnp.int32)
     mask = jnp.asarray((rs.rand(B, T) > 0.2).astype(np.float32))
-    for how in ("sum", "average", "squarerootn", "max"):
-        want = _segment_pool_onehot(v, mask, seg, S, how)
-        got = _segment_pool(v, mask, seg, S, how)
-        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
-                                   rtol=1e-6, atol=1e-6)
-        np.testing.assert_array_equal(np.asarray(got[1]),
-                                      np.asarray(want[1]))
+    want = _segment_pool_onehot(v, mask, seg, S, how)
+    got = _segment_pool(v, mask, seg, S, how)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 
 
 # --- fused kernel mask/reset edge cases (interpret vs scan bit-compare) ---
@@ -906,20 +905,45 @@ def test_sort_within_buffer_checkpointable_resume():
     assert first + rest == full
 
 
-# --- bench smoke (tier-1 `--quick`) ----------------------------------------
+# --- the packing-ready NMT (models/text.nmt_packed_cost) -------------------
 
-def test_quick_nmt_packed_bench_smoke():
-    import bench
+def test_nmt_packed_cost_same_loss_from_fewer_less_padded_rows():
+    """Three slots under ONE plan (src / trg / trg_next, trg length
+    correlated with src): packing leaves fewer rows and a lower pad
+    fraction in every slot, fills rows past half, and cross-attention
+    through the segment mask sees only its own source sentence — the
+    loss equals the padded feed's."""
+    from paddle_tpu.models.text import nmt_packed_cost
 
-    res = bench.bench_nmt_packed(quick=True)
-    assert res["metric"] == "nmt_packed_train_tokens_per_sec_per_chip"
-    assert res["value"] > 0
-    extra = res["extra"]
-    for col in ("padded", "packed"):
-        for field in ("tokens_per_sec", "ms_per_batch", "rows", "padded_T",
-                      "pad_fraction"):
-            assert field in extra[col], (col, field)
-    # packing must actually delete padding: fewer rows, lower pad fraction
-    assert extra["packed"]["rows"] < extra["padded"]["rows"]
-    assert extra["pad_fraction_packed"] < extra["pad_fraction_padded"]
-    assert extra["packing_efficiency_pct"] > 50.0
+    vocab = 64
+    with layer_name_scope():
+        cost = nmt_packed_cost(src_dict_dim=vocab, trg_dict_dim=vocab,
+                               word_vector_dim=16, encoder_size=16,
+                               decoder_size=16, num_heads=2, name="mp")
+    topo = paddle.Topology(cost)
+    rs = np.random.RandomState(0)
+    samples = []
+    for _ in range(16):
+        ts = int(rs.randint(4, 13))
+        tt = max(3, ts + int(rs.randint(-2, 3)))
+        samples.append((rs.randint(0, vocab, ts).tolist(),
+                        rs.randint(0, vocab, tt).tolist(),
+                        rs.randint(0, vocab, tt).tolist()))
+    feeding = {"src": 0, "trg": 1, "trg_next": 2}
+    padded = DataFeeder(topo.data_type(), feeding)(samples)
+    packed = DataFeeder(topo.data_type(), feeding, pack_sequences=True,
+                        pack_max_len=24, pack_row_rounding=1)(samples)
+
+    def pad_fraction(arg):
+        m = np.asarray(arg.mask)
+        return 1.0 - float(m.sum()) / m.size
+
+    for slot in feeding:
+        assert packed[slot].value.shape[0] < padded[slot].value.shape[0]
+        assert pad_fraction(packed[slot]) < pad_fraction(padded[slot])
+        assert pad_fraction(packed[slot]) < 0.5
+    params = topo.init_params(jax.random.PRNGKey(0))
+    loss = topo.loss_fn("cost")
+    want = float(loss(params, padded, training=False)[0])
+    got = float(loss(params, packed, training=False)[0])
+    assert np.isfinite(want) and got == pytest.approx(want, rel=1e-5)

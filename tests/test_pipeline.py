@@ -8,8 +8,7 @@ Pins: final params / evaluator values / event sequence across depths
 pipelining; preemption honored within depth-1 batches with exact
 resume; a fault-injected reader raising inside the overlap window;
 the jaxpr bit-identity acceptance; the new dispatch/drain phase split,
-in-flight gauge, pad-fraction histogram and on-device param-stats dump;
-and the bench.py data-bound workload smoke (`--quick` tier-1 analog).
+in-flight gauge, pad-fraction histogram and on-device param-stats dump.
 """
 
 import logging
@@ -460,28 +459,3 @@ def test_dp_pipelined_bit_identical():
     a, b = run(0), run(3)
     for k in a:
         np.testing.assert_array_equal(a[k], b[k])
-
-
-# --- bench smoke (tier-1 `--quick` analog for the data-bound workload) -----
-
-def test_quick_pipeline_bench_smoke():
-    """bench.py --model pipeline, tier-1 sized: both columns measure, the
-    JSON carries the sync-vs-pipelined split and per-mode phase costs,
-    and the pipelined loop is never substantially SLOWER than sync (it
-    only removes host sync points; overlap gains need async dispatch,
-    which the 1-CPU test client lacks — docs/pipeline.md)."""
-    import bench
-
-    res = bench.bench_pipeline(batch=16, batches=6, pipeline_depth=2,
-                               feed_ms=2.0, dim=32, hidden=32, classes=4)
-    assert res["metric"] == "pipeline_databound_train_ms_per_batch"
-    assert res["value"] > 0
-    extra = res["extra"]
-    assert "overlapped_compute_ms_per_batch" in extra
-    for mode in ("sync", "pipelined"):
-        for field in ("ms_per_batch", "data_wait_ms", "compute_ms",
-                      "data_wait_share"):
-            assert field in extra[mode], (mode, field)
-        assert extra[mode]["data_wait_ms"] >= 1.0   # the injected feed cost
-    # not substantially slower, with generous CI slack
-    assert res["value"] <= extra["sync"]["ms_per_batch"] * 1.5 + 2.0

@@ -9,7 +9,7 @@ is BIT-identical to the synchronous depth-0 PP run; balanced stage
 assignment is trajectory-equivalent to naive on the same stream (allclose
 losses, identical evaluator totals); r7 snapshot/resume replays the
 exact trajectory under the pipeline step; the paddle_pp_* gauges are
-live; and the bench pp columns measure (tier-1 --quick analog)."""
+live."""
 
 import numpy as np
 import pytest
@@ -201,27 +201,3 @@ def test_pp_batch_must_divide_microbatches():
     with pytest.raises(Error):
         t.train(paddle.batch(_sample_reader, BATCH), num_passes=1,
                 pipeline_depth=0)
-
-
-# --- bench smoke (tier-1 --quick analog for the pp columns) ----------------
-
-def test_quick_pp_bench_smoke():
-    """bench.py --model pipeline --pipeline_trainer pp --quick: all four
-    naive/balanced x sync/overlapped columns measure, each carries its
-    static padding fractions, and the balanced param padding is strictly
-    below the naive one (the deliberately unbalanced bench model)."""
-    import bench
-
-    res = bench.bench_pipeline(trainer="pp", quick=True)
-    assert res["metric"] == "pipeline_pp_train_ms_per_batch"
-    assert res["value"] > 0
-    extra = res["extra"]
-    for col in ("naive_sync", "naive_overlapped", "balanced_sync",
-                "balanced_overlapped"):
-        for field in ("ms_per_batch", "data_wait_ms", "compute_ms",
-                      "stage_padding_fraction"):
-            assert field in extra[col], (col, field)
-    assert set(extra["overlapped_compute_ms_per_batch"]) == \
-        {"naive", "balanced"}
-    assert (extra["balanced_sync"]["stage_padding_fraction"]["param"]
-            < extra["naive_sync"]["stage_padding_fraction"]["param"])

@@ -87,15 +87,15 @@ def _full_coverage_cand(B=1):
     return Arg(jnp.asarray(np.tile(np.arange(V), (B, 1)), jnp.int32))
 
 
-def test_selective_params_are_checkpoint_compatible():
+@pytest.mark.parametrize("compact", [False, True])
+def test_selective_params_are_checkpoint_compatible(compact):
     """Dense, selective (r6) and compact-K (r8) graphs declare identical
     parameter names and shapes — checkpoints port between all three."""
     topo_d, _ = _gen_topo(select=False)
     specs_d = {n: s.shape for n, s in topo_d.param_specs().items()}
-    for compact in (False, True):
-        topo_s, _ = _gen_topo(select=True, compact=compact)
-        specs_s = {n: s.shape for n, s in topo_s.param_specs().items()}
-        assert specs_s == specs_d, f"compact={compact}"
+    topo_s, _ = _gen_topo(select=True, compact=compact)
+    specs_s = {n: s.shape for n, s in topo_s.param_specs().items()}
+    assert specs_s == specs_d
 
 
 @pytest.mark.parametrize("gather_min", [None, 0])
@@ -350,3 +350,86 @@ def test_training_mode_selective_projection_3d():
         off = [c for c in range(V) if c not in on]
         assert (out[b][:, off] < 1e-12).all()          # softmax of -1e30
         np.testing.assert_allclose(out[b].sum(-1), 1.0, rtol=1e-5)
+
+
+# --- the three decode paths at the NMT generation topology ---------------
+
+def _length_schedule(max_length, eos_id=1, beam=1):
+    """A candidate_adjust callback giving each sample an output length in
+    6 .. 3/4 * max_length, as a trained model's sentences have: past its
+    length every hypothesis of a sample is pushed onto eos, so the
+    early-exit loop ends like a production decode (random-init parameters
+    essentially never emit eos). Works in vocabulary space ([BK, V]) and in
+    candidate space ([BK, K], through state['cand_ids'])."""
+    lo = min(6, max_length - 1)
+    hi = max(lo + 1, (3 * max_length) // 4)
+
+    def candidate_adjust(t, logp, state):
+        bk = logp.shape[0]
+        length = lo + ((jnp.arange(bk) // beam) % (hi - lo + 1))
+        ids = state.get("cand_ids")
+        col = ids if ids is not None else jnp.arange(logp.shape[-1])[None, :]
+        return jnp.where((t >= length)[:, None],
+                         jnp.where(col == eos_id, 0.0, -1e4), logp)
+
+    return candidate_adjust
+
+
+@pytest.mark.parametrize("mode", ["dense", "selective", "compact"])
+def test_decode_modes_finish_under_max_length(mode):
+    """Each decode path of models/text.nmt_decode_topology emits finite
+    scores and, under the length schedule, stops before max_length: the
+    early-exit loop does not pay for ticks no hypothesis needs."""
+    from paddle_tpu.layer import BeamSearchControlCallbacks
+    from paddle_tpu.models.text import nmt_decode_topology
+
+    vocab, beam, K, B, T, max_length, eos_id = 500, 2, 32, 2, 4, 12, 1
+    gen = nmt_decode_topology(src_dict_dim=vocab, trg_dict_dim=vocab,
+                              word_vector_dim=16, encoder_size=16,
+                              decoder_size=16, beam_size=beam,
+                              max_length=max_length, cand_k=K, mode=mode,
+                              name="m")
+    gen.cfg["ctrl_callbacks"] = BeamSearchControlCallbacks(
+        candidate_adjust=_length_schedule(max_length, eos_id, beam))
+    topo = Topology(gen)
+    params = topo.init_params(jax.random.PRNGKey(0))
+    r = np.random.RandomState(0)
+    feeds = {"src": Arg(jnp.asarray(r.randint(0, vocab, (B, T)), jnp.int32),
+                        jnp.ones((B, T), jnp.float32))}
+    if mode != "dense":
+        # unique candidate rows that hold eos (docs/decode.md's contract)
+        cand = np.stack([r.choice(vocab, K, replace=False)
+                         for _ in range(B)]).astype(np.int32)
+        cand[~(cand == eos_id).any(axis=1), 0] = eos_id
+        feeds["cand"] = Arg(jnp.asarray(cand))
+    outs, ctx = topo.forward(params, feeds, return_ctx=True)
+    assert np.isfinite(np.asarray(ctx.extras[f"{gen.name}:scores"])).all()
+    assert float(outs[gen.name].mask.sum()) > 0     # tokens were emitted
+    assert 0 < int(ctx.extras[f"{gen.name}:ticks"]) < max_length
+
+
+def test_decode_flop_accounting():
+    """flops.py prices beam_search layers per executed tick and prices
+    the selective projection in candidate space: compact decode FLOPs
+    are V-independent and far below dense, and scale with decode_ticks."""
+    from paddle_tpu.flops import topology_fwd_flops
+    from paddle_tpu.models.text import nmt_decode_topology
+
+    def flops(mode, ticks=None, V=2000):
+        gen = nmt_decode_topology(src_dict_dim=V, trg_dict_dim=V,
+                                  word_vector_dim=16, encoder_size=16,
+                                  decoder_size=16, beam_size=2,
+                                  max_length=8, cand_k=32, mode=mode)
+        return topology_fwd_flops(Topology(gen), batch=4, seq_len=6,
+                                  decode_ticks=ticks)
+
+    dense, compact = flops("dense"), flops("compact")
+    assert compact < dense / 3          # K=32 << V=2000 projection rows
+    # candidate-space pricing is V-independent
+    assert flops("compact", V=4000) == pytest.approx(compact, rel=1e-6)
+    # fewer executed ticks -> proportionally less beam work
+    full, half = flops("compact", ticks=8), flops("compact", ticks=4)
+    assert half < full
+    # the selective (r6) projection also gathers K rows: same matmul
+    # count as compact (what differs at runtime is non-matmul O(V) work)
+    assert flops("selective") == pytest.approx(compact, rel=1e-6)

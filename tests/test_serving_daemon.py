@@ -553,30 +553,6 @@ def test_load_shed_503_retry_after_only_above_high_water(serving_build):
                        "paddle_serving_shed_total") == shed
 
 
-def test_serving_bench_quick(serving_build):
-    """bench.py --model serving --quick: toy drain-vs-continuous
-    columns AND the r19 real-decode step-module columns come back with
-    speedups, TTFT and the mid-batch admission fraction computed."""
-    import bench
-
-    out = bench.bench_serving(quick=True)
-    assert out["metric"] == "serving_requests_per_sec"
-    assert out["extra"]["drain"]["requests_per_sec"] > 0
-    assert out["extra"]["continuous"]["requests_per_sec"] > 0
-    assert out["extra"]["continuous"]["mean_slot_occupancy"] > 0
-    real = out["extra"]["real_decode"]
-    assert "error" not in real, real
-    assert real["continuous"]["requests_per_sec"] > 0
-    assert real["drain"]["requests_per_sec"] > 0
-    # the acceptance bars: a real-model scheduler win with genuinely
-    # mid-batch admissions, and first tokens landing before completion
-    assert real["continuous"]["mid_batch_admissions"] >= 1
-    assert real["drain"]["mid_batch_admissions"] == 0
-    assert real["continuous"]["p50_ttft_ms"] < \
-        real["continuous"]["p50_latency_ms"]
-    assert real["continuous"]["p50_stream_lead_ms"] > 0
-
-
 # --- quantized bundles (ISSUE 16, docs/serving.md "Quantized bundles") ----
 
 def _quantized_bundles(tmp_path, batch_ladder=None):
@@ -791,33 +767,6 @@ def test_daemon_fail_closed_unknown_param_dtype(serving_build, tmp_path):
             "den": dv.tolist()}})
         assert after["outputs"]["o1"]["data"] == \
             before["outputs"]["o1"]["data"]
-
-
-def test_serving_quantized_bench_quick(serving_build):
-    """bench.py --model serving --quantize --quick: the f32/bf16/int8
-    A/B columns come back with the byte cut and the golden-tolerance
-    column per precision."""
-    import bench
-
-    out = bench.bench_serving(quick=True, quantize=True)
-    assert out["metric"] == "serving_quantized_requests_per_sec"
-    ex = out["extra"]
-    for mode in ("f32", "bf16", "int8"):
-        col = ex[mode]
-        assert col["requests_per_sec"] > 0
-        assert col["param_bytes"]["total"] > 0
-    assert ex["f32"]["max_abs_err_vs_f32"] < 1e-5
-    assert ex["bf16"]["max_abs_err_vs_f32"] < 5e-3
-    assert ex["int8"]["max_abs_err_vs_f32"] < 2e-2
-    # quick mode's tiny params leave the bundle dominated by the
-    # serialized module, so the bundle cut is muted here (the full
-    # bench shows ~2x/~3.6x); the param-byte cut is the strict bar
-    assert ex["bundle_bytes_cut"]["bf16"] > 1.1
-    assert ex["bundle_bytes_cut"]["int8"] > 1.1
-    assert ex["bf16"]["param_bytes"]["total"] < \
-        ex["f32"]["param_bytes"]["total"] * 0.62
-    assert ex["int8"]["param_bytes"]["total"] < \
-        ex["f32"]["param_bytes"]["total"] * 0.45
 
 
 def test_metrics_dump_url_against_daemon(serving_build, tmp_path):

@@ -145,7 +145,7 @@ class TestBalancedAssignment:
         graph the balancer's partition cuts P_max well below the naive
         nmt_stage_map assignment — the padded [S, P_max] matrix stops
         being sized by the naive fattest stage and its padding ratio
-        drops from PERF_r05's ~33% — WITHOUT regressing the per-tick
+        drops from the naive split's ~33% — WITHOUT regressing the per-tick
         critical path (max stage flops, which measured step time
         tracks) and without meaningfully widening the boundary."""
         T = 16
@@ -156,7 +156,7 @@ class TestBalancedAssignment:
         assert bal["p_max"] < 0.9 * naive["p_max"]
         assert max(bal["stage_flops"]) <= max(naive["stage_flops"]) * 1.001
         assert bal["d_max"] <= naive["d_max"] * 1.05
-        assert naive["param_pad_frac"] > 0.3      # the PERF_r05 baseline
+        assert naive["param_pad_frac"] > 0.3      # the naive split
         assert bal["param_pad_frac"] < 0.25
 
     def test_assignment_is_monotone(self):

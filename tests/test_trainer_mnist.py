@@ -151,44 +151,6 @@ def test_ctr_wide_deep_trains_on_sparse_inputs():
     assert errs[-1] < 0.35, errs
 
 
-def test_make_train_loop_matches_per_step(monkeypatch):
-    """Device-side lax.scan loop == N sequential step calls (same feeds,
-    same rng derivation)."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.trainer.trainer import make_train_loop, make_train_step
-
-    img = layer.data(name="x", type=data_type.dense_vector(6))
-    lab = layer.data(name="y", type=data_type.integer_value(3))
-    out = layer.fc(input=img, size=3, act=activation.Softmax(), name="o")
-    cost = layer.classification_cost(input=out, label=lab, name="c")
-    topo = paddle.Topology(cost)
-    params = topo.init_params(jax.random.PRNGKey(0))
-    opt = optimizer.Momentum(learning_rate=0.1, momentum=0.9)
-    loss = topo.loss_fn(cost)
-    static = topo.static_map()
-    rng = jax.random.PRNGKey(3)
-    r = np.random.RandomState(0)
-    feeds = {"x": jnp.asarray(r.rand(8, 6), jnp.float32),
-             "y": jnp.asarray(r.randint(0, 3, (8, 1)), jnp.int32)}
-
-    monkeypatch.setenv("PADDLE_TPU_ALLOW_SCAN_LOOP", "1")
-    loop = make_train_loop(loss, opt, static, steps_per_call=4,
-                           donate=False)
-    p_loop, _, c_loop = loop(dict(params), opt.init(params), rng, feeds)
-
-    step = make_train_step(loss, opt, static, donate=False)
-    p, s = dict(params), opt.init(params)
-    for i in range(4):
-        p, s, c, _ = step(p, s, jax.random.fold_in(rng, i), feeds)
-    assert float(c) == pytest.approx(float(c_loop), rel=1e-5)
-    for k in p:
-        np.testing.assert_allclose(np.asarray(p[k]),
-                                   np.asarray(p_loop[k]), rtol=1e-5,
-                                   atol=1e-6)
-
-
 def test_test_period_runs_mid_pass_evaluation():
     """--test_period N: TestResult events fire every N batches mid-pass
     (reference periodic Tester mode), not only at pass end."""

@@ -1,5 +1,5 @@
-"""CRF/CTC Pallas vs lax.scan on silicon: parity + the T-sweep timing
-table (VERDICT r4 item 4 acceptance).
+"""CRF Pallas vs lax.scan on silicon: parity + the T-sweep timing table
+that `layers/crf_ctc._CRF_PALLAS_MIN_T` is derived from.
 
 Run on the TPU (default platform):  python tools/ctc_bench.py
 Produced the r5 figures quoted in layers/crf_ctc.py (not re-measured).
@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import paddle_tpu.layers.crf_ctc as cc
-from paddle_tpu.kernels.ctc import ctc_nll_pallas
 
 
 def _sync(x):
@@ -32,34 +31,8 @@ def _time(f, *args, iters=30):
     return (time.perf_counter() - t0) / iters * 1e3
 
 
-def bench_ctc(B=32, C=128, U=20):
-    print("# CTC fwd+bwd ms (B=%d C=%d U=%d)" % (B, C, U), flush=True)
-    print("| T | scan ms | pallas ms | speedup | grad maxdiff |")
-    print("|---|---------|-----------|---------|--------------|")
-    for T in (128, 512, 2048):
-        r = np.random.RandomState(0)
-        logits = jnp.asarray(r.randn(B, T, C), jnp.float32)
-        labels = jnp.asarray(r.randint(1, C, (B, U)), jnp.int32)
-        lens = r.randint(2 * U + 1, T + 1, B)
-        im = jnp.asarray((np.arange(T)[None] < lens[:, None])
-                         .astype(np.float32))
-        lm = jnp.ones((B, U), jnp.float32)
-
-        f_scan = jax.jit(jax.grad(
-            lambda l: cc.ctc_nll(l, labels, im, lm).sum()))
-        f_pal = jax.jit(jax.grad(
-            lambda l: ctc_nll_pallas(l, labels, im, lm).sum()))
-        g1 = f_scan(logits)
-        g2 = f_pal(logits)
-        diff = float(jnp.abs(g1 - g2).max())
-        ms_scan = _time(f_scan, logits)
-        ms_pal = _time(f_pal, logits)
-        print(f"| {T} | {ms_scan:.2f} | {ms_pal:.2f} | "
-              f"{ms_scan / ms_pal:.2f}x | {diff:.2e} |", flush=True)
-
-
 def bench_crf(B=32, L=64):
-    print(f"\n# CRF logZ fwd+bwd ms (B={B} L={L})", flush=True)
+    print(f"# CRF logZ fwd+bwd ms (B={B} L={L})", flush=True)
     print("| T | scan ms | pallas ms | speedup | grad maxdiff |")
     print("|---|---------|-----------|---------|--------------|")
     for T in (128, 512, 2048):
@@ -89,5 +62,4 @@ if __name__ == "__main__":
     import paddle_tpu
 
     paddle_tpu.compile_cache()
-    bench_ctc()
     bench_crf()

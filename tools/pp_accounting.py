@@ -144,7 +144,7 @@ def _feeds(B, T, V):
 
 def main(S=4, B=64, T=16, D=96, V=600, iters=3):
     # defaults sized so compute dominates per-tick dispatch noise on the
-    # CPU container: at the PERF_r05 sizes (B=32 D=48) the bubble-model
+    # CPU container: at the r5 sizes (B=32 D=48) the bubble-model
     # fit degrades to ~10-15% because tiny per-tick work is nonlinear in
     # B_mb on CPU; at B=64 D=96 the fit lands within the ~4-5% check
     devices = jax.devices()[:S]

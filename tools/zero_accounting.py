@@ -17,7 +17,7 @@ tests/test_multislice.py::test_zero_accounting_tool):
     zero_per_chip <= replicated_per_chip / N + O(1) overhead
 
 where the overhead is the replicated scalars plus <= N-1 pad elements
-per slot. The table lands in BENCH_EXTRA_r14.md.
+per slot.
 
 Usage:  python tools/zero_accounting.py [--hidden 512] [--layers 3]
         [--quick] [--json]
