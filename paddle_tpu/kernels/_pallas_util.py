@@ -27,8 +27,16 @@ NEG = -1e30
 # (r4, not re-measured); kernels gate their working sets well under this
 VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 
-# (who, kernel, taken, why) decisions already logged by take_pallas
+# (who, line) already logged by log_once
 _LOGGED_DECISIONS: set = set()
+
+
+def log_once(who: str, line: str) -> None:
+    """``who: line`` in the log, once per layer and line however often the
+    layer is traced (a step traces a layer several times)."""
+    if (who, line) not in _LOGGED_DECISIONS:
+        _LOGGED_DECISIONS.add((who, line))
+        logger.info("%s: %s", who, line)
 
 
 def take_pallas(who: str, kernel: str, eligible: bool = True,
@@ -45,12 +53,8 @@ def take_pallas(who: str, kernel: str, eligible: bool = True,
         taken, why = False, f"backend is {jax.default_backend()!r}"
     else:
         taken, why = True, "backend is 'tpu' and the kernel's gate passes"
-    key = (who, kernel, taken, why)
-    if key not in _LOGGED_DECISIONS:
-        _LOGGED_DECISIONS.add(key)
-        logger.info("%s: %s (%s)", who,
-                    f"Pallas {kernel}" if taken else f"lax.scan, not {kernel}",
-                    why)
+    log_once(who, (f"Pallas {kernel}" if taken else f"lax.scan, not {kernel}")
+             + f" ({why})")
     return taken
 
 

@@ -19,8 +19,29 @@ running sum of g inside a chunk and decay[i, j] = exp(gamma_i - gamma_j):
 The state pass is the part that is a recurrence: ``state_pass_scan`` is its
 ``lax.scan`` form (the path everywhere but the TPU, and what the CPU tests
 pin the kernels to); ``state_pass_kernel`` runs it as the Mosaic kernels
-``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` under a ``jax.custom_vjp`` with S
-resident in VMEM over a (batch x head) row's chunks.
+``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` under a ``jax.custom_vjp``.
+
+Their grid is (row groups, chunk groups): a grid step holds R (batch x head)
+rows x Kc chunks of each, blocks [R, Kc, C, .], with S (backward: dS)
+[R, dk, dv] float32 resident in VMEM over a row group's chunks. A
+``fori_loop`` walks the Kc chunks (backward: groups and chunks in reverse);
+in its body the R rows' chains stand side by side, independent, so that the
+scheduler can interleave them on the MXUs (one row's chunk alone is three
+products that wait on one another). R and Kc come from the shapes alone
+(``state_pass_block``: divisors of BH and NC under ``_vmem_estimate_bytes``
+against a quarter of ``VMEM_LIMIT_BYTES``, rows first, 1 x 1 where nothing
+larger fits); the arithmetic of a chunk is the same in every block, bit for
+bit. Bytes a chunk and launch, bf16 at C 64 and widths 128, beside the
+benchmark's need of 90,116 forward + 180,232 backward:
+
+    gdn_chunk_fwd, primal    reads W U Q~ K~ Aqk d 74,240  writes O 16,384
+    gdn_chunk_fwd, the rule  the same, and writes S0 (float32) 65,536
+    gdn_chunk_bwd            reads those, S0 and dO 156,160  writes 74,240
+
+The primal call (no gradient asked: the first pass under a row's
+``jax.checkpoint``, by ``optimize_remat``) writes no S0; W, Q~, K~ and Aqk
+are read once as ``chunk_prepare`` stored them, and the products that need
+them turned contract over their first dimension inside the kernel (``_tn``).
 
 g, gamma, the decay products, T and S are float32 (wider under an f64
 gradient check); the products take their operands in the dtype q, k, v come
@@ -51,7 +72,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.kernels._pallas_util import VMEM_LIMIT_BYTES
+from paddle_tpu.kernels._pallas_util import VMEM_LIMIT_BYTES, round_up
 
 CHUNK = 64
 
@@ -208,60 +229,129 @@ def _nt(a, b):
                                preferred_element_type=jnp.float32)
 
 
+def _tn(a, b):
+    """a [k, m]^T x b [k, n] -> [m, n], float32: the operand is read as it
+    is stored and turned inside the kernel, not by XLA in HBM before it."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _dot(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(w_ref, u_ref, qt_ref, ktT_ref, aqk_ref, d_ref,
-                o_ref, s0_ref, s_scr):
+def _fwd_kernel(w_ref, u_ref, qt_ref, kt_ref, aqk_ref, d_ref, o_ref, *rest):
+    # rest: (s0_ref, s_scr) in the custom rule's forward, (s_scr,) in the
+    # primal call, whose chunks' starting states nothing would read
+    s0_ref, s_scr = rest if len(rest) == 2 else (None, rest[0])
+    R, Kc = u_ref.shape[:2]
+
     @pl.when(pl.program_id(1) == 0)
     def _():
-        s_scr[:] = jnp.zeros_like(s_scr)
+        s_scr[...] = jnp.zeros_like(s_scr)
 
     dt = u_ref.dtype
-    S = s_scr[:]
-    s0_ref[0, 0] = S
-    Sb = S.astype(dt)
-    vn = u_ref[0, 0].astype(jnp.float32) - _dot(w_ref[0, 0], Sb)
-    vnb = vn.astype(dt)
-    o = _dot(qt_ref[0, 0], Sb) + _dot(aqk_ref[0, 0], vnb)
-    o_ref[0, 0] = o.astype(o_ref.dtype)
-    s_scr[:] = d_ref[0, 0] * S + _dot(ktT_ref[0, 0], vnb)
+
+    def chunk(c, carry):
+        # the R rows' chains are independent: side by side in one basic
+        # block, for the scheduler to interleave on the MXUs
+        for r in range(R):
+            S = s_scr[r]
+            if s0_ref is not None:
+                s0_ref[r, c] = S
+            Sb = S.astype(dt)
+            vn = u_ref[r, c].astype(jnp.float32) - _dot(w_ref[r, c], Sb)
+            vnb = vn.astype(dt)
+            o = _dot(qt_ref[r, c], Sb) + _dot(aqk_ref[r, c], vnb)
+            o_ref[r, c] = o.astype(o_ref.dtype)
+            s_scr[r] = d_ref[r, c] * S + _tn(kt_ref[r, c], vnb)
+        return carry
+
+    jax.lax.fori_loop(0, Kc, chunk, 0)
 
 
-def _bwd_kernel(w_ref, wT_ref, u_ref, qtT_ref, kt_ref, aqkT_ref, d_ref,
-                s0_ref, do_ref,
+def _bwd_kernel(w_ref, u_ref, qt_ref, kt_ref, aqk_ref, d_ref, s0_ref, do_ref,
                 dw_ref, du_ref, dqt_ref, dkt_ref, daqk_ref, dd_ref, ds_scr):
-    @pl.when(pl.program_id(1) == 0)          # the LAST chunk: grid runs back
+    R, Kc = u_ref.shape[:2]
+
+    @pl.when(pl.program_id(1) == 0)          # the LAST chunks: grid runs back
     def _():
-        ds_scr[:] = jnp.zeros_like(ds_scr)
+        ds_scr[...] = jnp.zeros_like(ds_scr)
 
     dt = u_ref.dtype
-    S = s0_ref[0, 0]
-    Sb = S.astype(dt)
-    dS = ds_scr[:]                           # gradient of the state AFTER
-    dSb = dS.astype(dt)
-    do = do_ref[0, 0]
-    vnb = (u_ref[0, 0].astype(jnp.float32) - _dot(w_ref[0, 0], Sb)).astype(dt)
-    dvn = _dot(aqkT_ref[0, 0], do) + _dot(kt_ref[0, 0], dSb)
-    dvnb = dvn.astype(dt)
-    du_ref[0, 0] = dvnb
-    daqk_ref[0, 0] = _nt(do, vnb).astype(daqk_ref.dtype)
-    dqt_ref[0, 0] = _nt(do, Sb).astype(dqt_ref.dtype)
-    dkt_ref[0, 0] = _nt(vnb, dSb).astype(dkt_ref.dtype)
-    dw_ref[0, 0] = (-_nt(dvnb, Sb)).astype(dw_ref.dtype)
-    dd_ref[0, 0] = jnp.sum(dS * S, axis=0, keepdims=True)
-    ds_scr[:] = d_ref[0, 0] * dS + _dot(qtT_ref[0, 0], do) \
-        - _dot(wT_ref[0, 0], dvnb)
+
+    def chunk(i, carry):
+        c = Kc - 1 - i                       # and so does the walk inside
+        for r in range(R):
+            w = w_ref[r, c]
+            S = s0_ref[r, c]
+            Sb = S.astype(dt)
+            dS = ds_scr[r]                   # gradient of the state AFTER
+            dSb = dS.astype(dt)
+            do = do_ref[r, c]
+            vnb = (u_ref[r, c].astype(jnp.float32) - _dot(w, Sb)).astype(dt)
+            dvn = _tn(aqk_ref[r, c], do) + _dot(kt_ref[r, c], dSb)
+            dvnb = dvn.astype(dt)
+            du_ref[r, c] = dvnb
+            daqk_ref[r, c] = _nt(do, vnb).astype(daqk_ref.dtype)
+            dqt_ref[r, c] = _nt(do, Sb).astype(dqt_ref.dtype)
+            dkt_ref[r, c] = _nt(vnb, dSb).astype(dkt_ref.dtype)
+            dw_ref[r, c] = (-_nt(dvnb, Sb)).astype(dw_ref.dtype)
+            dd_ref[r, c] = jnp.sum(dS * S, axis=0, keepdims=True)
+            ds_scr[r] = d_ref[r, c] * dS + _tn(qt_ref[r, c], do) \
+                - _tn(w, dvnb)
+        return carry
+
+    jax.lax.fori_loop(0, Kc, chunk, 0)
 
 
-def _block(shape, rev_of=None):
-    """One (row, chunk) block of an array [BH, NC, ...]."""
+def _vmem_estimate_bytes(R, Kc, C, dk, dv, itemsize):
+    """What a grid step of the backward call (the larger of the two) holds in
+    VMEM: every block twice, as the pipeline keeps the next one coming, the
+    minor dimension padded to the 128 lanes and a [1, dv] row to 8 sublanes;
+    dS resident; and the float32 values of the R chains that are alive at
+    once (S, dS, their products: six of [dk, dv] a row, reckoned wide)."""
+    ldk, ldv, lC = (round_up(n, 128) for n in (dk, dv, C))
+    row = 8 * ldv * 4                                   # d, dd
+    ins = (3 * C * ldk + 2 * C * ldv + C * lC) * itemsize \
+        + dk * ldv * 4 + row                            # W Q~ K~, U dO, Aqk, S0
+    outs = (3 * C * ldk + C * ldv + C * lC) * itemsize + row
+    return 2 * R * Kc * (ins + outs) + 7 * R * dk * ldv * 4
+
+
+# rows side by side in a grid step: enough independent chains for the four
+# MXUs, few enough that the unrolled body stays a basic block Mosaic
+# schedules in seconds
+MAX_ROWS = 8
+
+
+def state_pass_block(BH, NC, C, dk, dv, itemsize):
+    """(R, Kc): how many rows and how many chunks of each one grid step of
+    ``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` holds. From the shapes alone: R the
+    largest divisor of BH up to MAX_ROWS, then Kc the largest divisor of NC,
+    whose blocks ``_vmem_estimate_bytes`` puts under a quarter of
+    VMEM_LIMIT_BYTES (the rest is Mosaic's own: spills, the products'
+    staging); rows give way before chunks do, down to (1, 1)."""
+    budget = VMEM_LIMIT_BYTES // 4
+    fits = lambda R, Kc: _vmem_estimate_bytes(R, Kc, C, dk, dv, itemsize) \
+        <= budget
+    divisors = lambda n, most: [m for m in range(min(n, most), 0, -1)
+                                if n % m == 0]
+    for R in divisors(BH, MAX_ROWS):
+        for Kc in divisors(NC, NC):
+            if fits(R, Kc):
+                return R, Kc
+    return 1, 1
+
+
+def _block(shape, block, rev_of=None):
+    """A block of R rows x Kc chunks of an array [BH, NC, ...]; with
+    ``rev_of`` (the number of chunk groups) the groups are walked back."""
     if rev_of is None:
         index = lambda b, c: (b, c, 0, 0)
     else:
         index = lambda b, c: (b, rev_of - 1 - c, 0, 0)
-    return pl.BlockSpec((1, 1) + tuple(shape[2:]), index,
+    return pl.BlockSpec(tuple(block) + tuple(shape[2:]), index,
                         memory_space=pltpu.VMEM)
 
 
@@ -277,49 +367,54 @@ def _dvec(d, dv):
     return jnp.broadcast_to(d.astype(jnp.float32), d.shape[:3] + (dv,))
 
 
-def _fwd_call(W, U, Qt, Kt, Aqk, d, interpret):
+def _fwd_call(W, U, Qt, Kt, Aqk, d, interpret, keep_s0):
+    """O, and with ``keep_s0`` every chunk's starting state (float32)."""
     BH, NC, C, dk = W.shape
     dv = U.shape[-1]
-    ins = (W, U, Qt, jnp.swapaxes(Kt, -1, -2), Aqk, _dvec(d, dv))
-    outs = (jax.ShapeDtypeStruct((BH, NC, C, dv), U.dtype),
-            jax.ShapeDtypeStruct((BH, NC, dk, dv), jnp.float32))
+    R, Kc = block = state_pass_block(BH, NC, C, dk, dv, U.dtype.itemsize)
+    ins = (W, U, Qt, Kt, Aqk, _dvec(d, dv))
+    outs = (jax.ShapeDtypeStruct((BH, NC, C, dv), U.dtype),)
+    if keep_s0:
+        outs += (jax.ShapeDtypeStruct((BH, NC, dk, dv), jnp.float32),)
     return pl.pallas_call(
-        _fwd_kernel, name="gdn_chunk_fwd", grid=(BH, NC),
-        in_specs=[_block(x.shape) for x in ins],
-        out_specs=[_block(x.shape) for x in outs], out_shape=list(outs),
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        _fwd_kernel, name="gdn_chunk_fwd", grid=(BH // R, NC // Kc),
+        in_specs=[_block(x.shape, block) for x in ins],
+        out_specs=[_block(x.shape, block) for x in outs], out_shape=list(outs),
+        scratch_shapes=[pltpu.VMEM((R, dk, dv), jnp.float32)],
         interpret=interpret, **_params(interpret))(*ins)
 
 
 def _bwd_call(W, U, Qt, Kt, Aqk, d, S0, dO, interpret):
     BH, NC, C, dk = W.shape
     dv, dt = U.shape[-1], U.dtype
-    T_ = lambda x: jnp.swapaxes(x, -1, -2)
-    ins = (W, T_(W), U, T_(Qt), Kt, T_(Aqk), _dvec(d, dv), S0, dO.astype(dt))
+    R, Kc = block = state_pass_block(BH, NC, C, dk, dv, dt.itemsize)
+    ins = (W, U, Qt, Kt, Aqk, _dvec(d, dv), S0, dO.astype(dt))
     outs = (jax.ShapeDtypeStruct(W.shape, dt), jax.ShapeDtypeStruct(U.shape, dt),
             jax.ShapeDtypeStruct(Qt.shape, dt), jax.ShapeDtypeStruct(Kt.shape, dt),
             jax.ShapeDtypeStruct(Aqk.shape, dt),
             jax.ShapeDtypeStruct((BH, NC, 1, dv), jnp.float32))
+    groups = NC // Kc
     return pl.pallas_call(
-        _bwd_kernel, name="gdn_chunk_bwd", grid=(BH, NC),
-        in_specs=[_block(x.shape, rev_of=NC) for x in ins],
-        out_specs=[_block(x.shape, rev_of=NC) for x in outs],
+        _bwd_kernel, name="gdn_chunk_bwd", grid=(BH // R, groups),
+        in_specs=[_block(x.shape, block, rev_of=groups) for x in ins],
+        out_specs=[_block(x.shape, block, rev_of=groups) for x in outs],
         out_shape=list(outs),
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((R, dk, dv), jnp.float32)],
         interpret=interpret, **_params(interpret))(*ins)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def state_pass_kernel(W, U, Qt, Kt, Aqk, d, interpret=False):
     """``state_pass_scan`` as the Mosaic kernels ``gdn_chunk_fwd`` /
-    ``gdn_chunk_bwd``: grid (rows, chunks), S (and in the backward pass its
-    gradient) resident in VMEM over a row's chunks. The forward call also
-    hands out every chunk's starting state (float32) for the backward one."""
-    return _fwd_call(W, U, Qt, Kt, Aqk, d, interpret)[0]
+    ``gdn_chunk_bwd``: grid (row groups, chunk groups), S (and in the
+    backward pass its gradient) resident in VMEM over a row group's chunks.
+    This, the primal call, writes O alone; the custom rule's forward also
+    hands out every chunk's starting state for the backward call."""
+    return _fwd_call(W, U, Qt, Kt, Aqk, d, interpret, keep_s0=False)[0]
 
 
 def _state_pass_fwd(W, U, Qt, Kt, Aqk, d, interpret):
-    O, S0 = _fwd_call(W, U, Qt, Kt, Aqk, d, interpret)
+    O, S0 = _fwd_call(W, U, Qt, Kt, Aqk, d, interpret, keep_s0=True)
     return O, (W, U, Qt, Kt, Aqk, d, S0)
 
 
@@ -331,7 +426,9 @@ def _state_pass_bwd(interpret, res, dO):
         jnp.sum(dd, axis=-1, keepdims=True).astype(d.dtype)
 
 
-state_pass_kernel.defvjp(_state_pass_fwd, _state_pass_bwd)
+# optimize_remat: under a row's `jax.checkpoint` the first pass keeps no
+# residual of this rule, and JAX then runs the primal call in its place
+state_pass_kernel.defvjp(_state_pass_fwd, _state_pass_bwd, optimize_remat=True)
 
 
 def kernel_supported(dk, dv, chunk, dtype):
@@ -343,14 +440,24 @@ def pick_state_pass(who, dk, dv, chunk, dtype):
     """The state pass a layer takes: the Mosaic kernels on the TPU where
     their gate passes, the scan elsewhere (and under a data-parallel GSPMD
     step, whose batch the row-by-row mixer does not hand to ``call_kernel``
-    shard by shard)."""
+    shard by shard). The decision's line in the log is followed by the block
+    the kernels engaged, once the shapes are there: a 1 x 1 fallback shows."""
     from paddle_tpu.kernels._pallas_util import (batch_shards, call_kernel,
-                                                 take_pallas)
+                                                 log_once, take_pallas)
 
     ok = kernel_supported(dk, dv, chunk, dtype)
     why = f"dk {dk}, dv {dv}, chunk {chunk}, {jnp.dtype(dtype).name} is " \
         "outside the kernel's gate" if not ok else \
         "the batch is sharded over a mesh"
-    if take_pallas(who, "gdn_chunk_fwd/bwd", ok and batch_shards() == 1, why):
-        return lambda *xs: call_kernel(state_pass_kernel, xs, range(6))
-    return state_pass_scan
+    if not take_pallas(who, "gdn_chunk_fwd/bwd", ok and batch_shards() == 1,
+                       why):
+        return state_pass_scan
+
+    def kernels(*xs):
+        (BH, NC, C, _), U = xs[0].shape, xs[1]
+        R, Kc = state_pass_block(BH, NC, C, dk, dv, U.dtype.itemsize)
+        log_once(who, f"gdn_chunk_fwd/bwd take rows {R} x chunks {Kc} of "
+                 f"{BH} x {NC} a grid step; the primal call writes no S0")
+        return call_kernel(state_pass_kernel, xs, range(6))
+
+    return kernels

@@ -142,6 +142,8 @@ def test_gdn_state_pass_train_compiles_and_keeps_its_names(one_chip, dtype):
 
     BH, NC, C, dk, dv = 32, 64, 64, 128, 128
     assert gdn.kernel_supported(dk, dv, C, dtype)
+    R, Kc = gdn.state_pass_block(BH, NC, C, dk, dv, jnp.dtype(dtype).itemsize)
+    assert R * Kc > 1, (R, Kc)      # several rows and chunks a grid step
     args = [_sds((BH, NC, C, n), dtype, one_chip) for n in (dk, dv, dk, dk, C)]
     args.append(_sds((BH, NC, 1, 1), jnp.float32, one_chip))
 
@@ -157,18 +159,23 @@ def test_gdn_state_pass_train_compiles_and_keeps_its_names(one_chip, dtype):
     assert any("gdn_chunk_bwd" in x for x in names), names
 
 
-@pytest.mark.parametrize("kind", ["gated_delta_net", "gated_attention"])
+@pytest.mark.parametrize("kind,dtype", [("gated_delta_net", jnp.bfloat16),
+                                        ("gated_delta_net", jnp.float32),
+                                        ("gated_attention", jnp.bfloat16)])
 def test_qwen3_next_mixer_train_compiles_at_the_cells_size(one_chip, kind,
-                                                           monkeypatch):
+                                                           dtype, monkeypatch):
     """value_and_grad of one mixer layer as the Qwen3-Next cell runs it (4
     rows x 4096 x 2048, bf16, one row at a time). The DeltaNet layer: the
     chunk inverse is products (no `InvertDiagBlocksLowerTriangular` custom
     call, no triangular-solve), the backward pass runs `gdn_chunk_fwd` again
     (the cell demands 9 Mosaic calls a step) but finds T kept, flattened so
     that the four rows' stack is 134 MB and not padded to twice that. The
-    attention layer shares `rows_one_at_a_time` and names nothing to keep:
-    its program, and so its temporaries, are what they were before T was
-    kept (parent commit, same compiler: 535,163,904 bytes)."""
+    kernels take the block `state_pass_block` chooses (bf16 and float32
+    compile with it); the first pass's `gdn_chunk_fwd` has O as its one
+    result, the pass made again for the gradient O and the float32 states.
+    The attention layer shares `rows_one_at_a_time` and names nothing to
+    keep: its program, and so its temporaries, are what they were before T
+    was kept (parent commit, same compiler: 535,163,904 bytes)."""
     from paddle_tpu import data_type, layer
     from paddle_tpu.core.arg import Arg
     from paddle_tpu.core.topology import Topology
@@ -188,7 +195,7 @@ def test_qwen3_next_mixer_train_compiles_at_the_cells_size(one_chip, kind,
             input=x, num_heads=16, num_kv_heads=2, head_dim=256,
             rotary_dim=64, rope_theta=1e7, name="l")
     topo = Topology(out)
-    params = {k: _sds(s.shape, jnp.bfloat16, one_chip)
+    params = {k: _sds(s.shape, dtype, one_chip)
               for k, s in topo.param_specs().items()}
 
     def loss(params, x):
@@ -197,17 +204,26 @@ def test_qwen3_next_mixer_train_compiles_at_the_cells_size(one_chip, kind,
         return jnp.sum(y.astype(jnp.float32) ** 2)
 
     compiled, _ = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params,
-                           _sds((B, T, d), jnp.bfloat16, one_chip))
+                           _sds((B, T, d), dtype, one_chip))
     text, names = compiled.as_text(), _mosaic_instructions(compiled)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    print(f"{kind}: {temp:,} bytes of temporaries")
+    print(f"{kind} {jnp.dtype(dtype).name}: {temp:,} bytes of temporaries")
     assert "InvertDiagBlocks" not in text and "triangular-solve" not in text
     if kind == "gated_delta_net":
         assert sum("gdn_chunk_fwd" in n for n in names) == 2, names
         assert sum("gdn_chunk_bwd" in n for n in names) == 1, names
         assert "f32[4,32,64,4096]" in text          # the rows' kept T
-        # the parent: 1,547,109,376; the kept T is 134 MB of the difference
-        assert temp < 1_900_000_000, temp
+        # what each gdn_chunk_fwd hands out: one of the two keeps no S0
+        S0 = "f32[32,64,128,128]"
+        results = sorted(S0 in line.split(" custom-call(")[0]
+                         for line in text.splitlines()
+                         if "tpu_custom_call" in line and "gdn_chunk_fwd" in line)
+        assert results == [False, True], results
+        if dtype == jnp.bfloat16:
+            # the parent (one row's one chunk a grid step, S0 from both
+            # forward calls, four transposes by XLA): 1,800,759,296; blocks
+            # of 8 rows x 4 chunks, no S0 from the first: 1,632,672,768
+            assert temp < 1_900_000_000, temp
     else:
         assert names == [] and temp == 535_163_904, (names, temp)
 

@@ -298,16 +298,44 @@ def test_backward_pass_finds_T_and_inverts_nothing_again(monkeypatch, capsys):
     assert products == 4
 
 
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
-                                       (jnp.bfloat16, 2e-2)])
-def test_state_pass_kernels_match_the_scan(dtype, tol):
+def _limit_engaging(block, dtype, C=16, dk=128, dv=128):
+    """The VMEM limit under which `state_pass_block` engages `block` (it
+    budgets a quarter of the limit); None: the limit as it is."""
+    if block is None:
+        return gdn.VMEM_LIMIT_BYTES
+    if block == (1, 1):
+        return 0                    # nothing fits: the 1 x 1 fallback
+    return 4 * gdn._vmem_estimate_bytes(*block, C, dk, dv,
+                                        jnp.dtype(dtype).itemsize)
+
+
+# (dtype, tolerance, B x H rows, tokens, the block a grid step holds). Chunks
+# of 16 tokens: 40 tokens are 3 chunks, 96 are 6. With the limit as it is a
+# grid step holds all there is; a tight one leaves rows 2 x chunks 3 of 4 x 6
+# (the budget has no room for 4 rows, nor for 2 x 6: the chunks a step holds
+# divide NC), and none at all leaves 1 x 1.
+@pytest.mark.parametrize("dtype,tol,H,T,block", [
+    (jnp.float32, 1e-5, 2, 40, None),
+    (jnp.bfloat16, 2e-2, 2, 40, None),
+    (jnp.float32, 1e-5, 4, 96, None),
+    (jnp.float32, 1e-5, 4, 96, (2, 3)),
+    (jnp.float32, 1e-5, 4, 96, (4, 1)),
+    (jnp.float32, 1e-5, 4, 96, (1, 1)),
+    (jnp.float32, 1e-5, 2, 40, (1, 3)),
+    (jnp.bfloat16, 2e-2, 4, 96, (2, 3)),
+], ids=lambda x: getattr(x, "__name__", str(x)).replace(" ", ""))
+def test_state_pass_kernels_match_the_scan(dtype, tol, H, T, block,
+                                           monkeypatch):
     """gdn_chunk_fwd / gdn_chunk_bwd in interpret mode against the lax.scan
     form, values and every input's gradient (float32: the same arithmetic;
     bfloat16: the hand-written backward pass rounds at other points than the
-    scan's transpose)."""
-    ins = _rule_inputs(1, 40, 2, 128, 128)
+    scan's transpose), in blocks of several rows and chunks a grid step, and
+    equal BIT FOR BIT to the kernels that take one row's one chunk a step:
+    the blocking moves no arithmetic."""
+    ins = _rule_inputs(1, T, H, 128, 128)
     ins = tuple(x.astype(dtype) for x in ins[:3]) + ins[3:]
     xs = gdn.chunk_prepare(*ins, 16)
+    BH, NC = xs[0].shape[:2]
     proj = _normal(9, *xs[1].shape)
 
     def through(state_pass):
@@ -315,12 +343,92 @@ def test_state_pass_kernels_match_the_scan(dtype, tol):
             state_pass(*xs).astype(jnp.float32) * proj),
             argnums=tuple(range(6)))(*xs)
 
-    v, g = through(lambda *xs: gdn.state_pass_kernel(*xs, True))
+    def kernels(block):
+        monkeypatch.setattr(gdn, "VMEM_LIMIT_BYTES",
+                            _limit_engaging(block, dtype))
+        engaged = gdn.state_pass_block(BH, NC, 16, 128, 128,
+                                       jnp.dtype(dtype).itemsize)
+        assert engaged == (block or (BH, NC)), engaged
+        return through(lambda *xs: gdn.state_pass_kernel(*xs, True)), \
+            gdn.state_pass_kernel(*xs, True)
+
+    (v, g), primal = kernels(block)
     v_ref, g_ref = through(gdn.state_pass_scan)
     _close(v, v_ref, tol)
     for a, b in zip(g, g_ref):
         assert a.dtype == b.dtype and a.shape == b.shape
         _close(a.astype(jnp.float32), b.astype(jnp.float32), tol)
+    (v1, g1), primal1 = kernels((1, 1))
+    for a, b in zip((v, primal) + g, (v1, primal1) + g1):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation in a jaxpr, however deep."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+def test_the_primal_call_writes_no_s0():
+    """Asked for no gradient, `state_pass_kernel` is one gdn_chunk_fwd with
+    one result, O. The custom rule's forward has two (O and every chunk's
+    starting state, float32), which the backward call reads. Under a row's
+    `jax.checkpoint` the first pass keeps nothing of the rule, so it is the
+    primal call there too (`optimize_remat`), and the pass made again for
+    the gradient is the one with S0."""
+    ins = _rule_inputs(1, 32, 2, 128, 128)
+    xs = gdn.chunk_prepare(*ins, 16)
+    S0 = ((2, 2, 128, 128), jnp.float32)
+
+    def results(fn):
+        calls = _pallas_calls(jax.make_jaxpr(fn)(*xs).jaxpr)
+        return [(eqn.params["name"],
+                 [(v.aval.shape, v.aval.dtype) for v in eqn.outvars])
+                for eqn in calls]
+
+    O = (xs[1].shape, xs[1].dtype)
+    kernel = lambda *xs: gdn.state_pass_kernel(*xs, True)
+    loss = lambda *xs: jnp.sum(kernel(*xs))
+    assert results(kernel) == [("gdn_chunk_fwd", [O])]
+    fwd, bwd = results(jax.grad(loss, argnums=tuple(range(6))))
+    assert fwd == ("gdn_chunk_fwd", [O, S0])
+    assert bwd[0] == "gdn_chunk_bwd" and len(bwd[1]) == 6
+    kept = jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names("gdn_T"))
+    first, again, back = results(jax.grad(kept, argnums=tuple(range(6))))
+    assert first == ("gdn_chunk_fwd", [O]), first
+    assert again == fwd and back == bwd
+
+
+def test_state_pass_block_at_the_cells_shape():
+    """The block comes from the shapes and the VMEM estimate alone. At the
+    Qwen3-Next cell's shape (32 rows of 64 chunks of 64 tokens, widths 128)
+    a grid step holds several rows and chunks, they divide the array, and
+    what they need stays under a quarter of the limit the calls are compiled
+    with; a shape with nothing to divide, or no room, runs 1 x 1."""
+    for itemsize in (2, 4):
+        R, Kc = gdn.state_pass_block(32, 64, 64, 128, 128, itemsize)
+        assert R * Kc > 1 and 32 % R == 0 and 64 % Kc == 0, (R, Kc)
+        need = gdn._vmem_estimate_bytes(R, Kc, 64, 128, 128, itemsize)
+        assert need <= gdn.VMEM_LIMIT_BYTES // 4, need
+        # the estimate is no less than the blocks themselves, twice
+        per = ((3 * 128 + 2 * 128 + 64) * 64 * itemsize + 128 * 128 * 4) \
+            + (3 * 128 + 128 + 64) * 64 * itemsize
+        assert need >= 2 * R * Kc * per
+    assert gdn.state_pass_block(32, 64, 64, 128, 128, 2) == (8, 4)
+    assert gdn.state_pass_block(32, 64, 64, 128, 128, 4) == (8, 2)
+    assert gdn.state_pass_block(7, 13, 64, 128, 128, 2) == (7, 1)
+    assert gdn.state_pass_block(1, 1, 64, 128, 128, 2) == (1, 1)
+    assert gdn.state_pass_block(3, 5, 16, 128, 128, 4) == (3, 5)
+    # rows give way before chunks do
+    assert gdn.state_pass_block(32, 64, 64, 1024, 1024, 4)[0] < 8
 
 
 def test_kernel_gate():
@@ -330,6 +438,35 @@ def test_kernel_gate():
     # held to the CPU here, every layer takes the scan
     assert gdn.pick_state_pass("t", 128, 128, 64, jnp.bfloat16) \
         is gdn.state_pass_scan
+
+
+def test_the_engaged_block_is_in_the_log(monkeypatch, caplog):
+    """Where the layer takes the kernels, its decision line is followed, once
+    a layer, by the block a grid step holds, from the function that computes
+    it: a fallback to 1 x 1 shows in every run's log."""
+    import logging
+
+    from paddle_tpu.kernels import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "take_pallas",
+                        lambda who, kernel, eligible=True, why_not="": eligible)
+    calls = []
+    monkeypatch.setattr(_pallas_util, "call_kernel",
+                        lambda fn, xs, batch: calls.append(fn) or xs[1])
+    monkeypatch.setattr(_pallas_util, "_LOGGED_DECISIONS", set())
+    xs = gdn.chunk_prepare(*_rule_inputs(1, 40, 2, 128, 128), 16)
+    with caplog.at_level(logging.INFO, logger="paddle_tpu"):
+        state_pass = gdn.pick_state_pass("l7", 128, 128, 16, jnp.float32)
+        state_pass(*xs), state_pass(*xs)
+        monkeypatch.setattr(gdn, "VMEM_LIMIT_BYTES", 0)
+        state_pass(*xs)
+    lines = [r.getMessage() for r in caplog.records if "l7" in r.getMessage()]
+    assert calls == [gdn.state_pass_kernel] * 3
+    assert lines == [
+        "l7: gdn_chunk_fwd/bwd take rows 2 x chunks 3 of 2 x 3 a grid step; "
+        "the primal call writes no S0",
+        "l7: gdn_chunk_fwd/bwd take rows 1 x chunks 1 of 2 x 3 a grid step; "
+        "the primal call writes no S0"], lines
 
 
 # ---- the whole tiny model ---------------------------------------------------
