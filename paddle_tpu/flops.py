@@ -105,6 +105,18 @@ def _gated_attention_flops(l, shapes, T) -> float:
     return proj + 2 * 2.0 * H * D * T * (T + 1) / 2
 
 
+def _gqa_attention_flops(l, shapes, T) -> float:
+    """Per row of the 2L positions the layer's mask rule is over (whatever
+    ``T`` the caller counts the other layers at): the four projections, and
+    the scores and weighted sum of the pairs the block-diffusion rule keeps,
+    L^2 + sum of the squared block sizes of the 4 L^2."""
+    H, D = l.attr("num_heads"), l.attr("head_dim")
+    _, L, b = l.attr("mask")
+    kept = L * L + (L // b) * b * b + (L % b) ** 2
+    proj = 2.0 * 2 * L * _numel(shapes, "wq", "wk", "wv", "wo")
+    return proj + 2 * 2.0 * H * D * kept
+
+
 def _gated_delta_net_flops(l, shapes, T) -> float:
     """Per row: the projections, the depthwise convolution, and the delta
     rule as its recurrence defines it (three dk x dv matrix-vector products
@@ -118,12 +130,12 @@ def _gated_delta_net_flops(l, shapes, T) -> float:
 
 def _moe_ffn_flops(l, shapes, T) -> float:
     """Per row: the router over all experts, the shared expert and its
-    gate, and the routed experts a token reaches HERE: of its top_k choices
+    gate where the layer has one, and the routed experts a token reaches HERE: of its top_k choices
     the share experts_held / num_experts in expectation, three d x I
     products each. Not all the weights held."""
     E, held, k = l.attr("num_experts"), l.attr("experts_held"), l.attr("top_k")
-    dense = _numel(shapes, "router", "shared_gate", "shared_wg", "shared_wu",
-                   "shared_wd")
+    dense = _numel(shapes, *(s for s in shapes
+                             if s == "router" or s.startswith("shared_")))
     one_expert = _numel(shapes, "wg", "wu", "wd") / held
     return 2.0 * T * (dense + k * held / E * one_expert)
 
@@ -133,6 +145,7 @@ def _moe_ffn_flops(l, shapes, T) -> float:
 _DECODER_FLOPS = {
     "rms_norm": lambda l, shapes, T: 0.0,        # elementwise
     "gated_attention": _gated_attention_flops,
+    "gqa_attention": _gqa_attention_flops,
     "gated_delta_net": _gated_delta_net_flops,
     "moe_ffn": _moe_ffn_flops,
 }

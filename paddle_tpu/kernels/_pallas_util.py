@@ -40,20 +40,21 @@ def log_once(who: str, line: str) -> None:
 
 
 def take_pallas(who: str, kernel: str, eligible: bool = True,
-                why_not: str = "") -> bool:
+                why_not: str = "", otherwise: str = "lax.scan") -> bool:
     """THE predicate for "this layer runs its Pallas kernel". The caller
     says whether the kernel covers its configuration (``eligible``;
     ``why_not`` names what rules it out); this adds the platform — the
     Mosaic kernels compile for the ``tpu`` backend only — and logs, once
     per layer and decision at trace time, which implementation was
-    taken and why, so a run that fell back to ``lax.scan`` says so."""
+    taken and why, so a run that fell back to ``otherwise`` (the layer's
+    other implementation, ``lax.scan`` for the recurrences) says so."""
     if not eligible:
         taken, why = False, why_not
     elif jax.default_backend() != "tpu":
         taken, why = False, f"backend is {jax.default_backend()!r}"
     else:
         taken, why = True, "backend is 'tpu' and the kernel's gate passes"
-    log_once(who, (f"Pallas {kernel}" if taken else f"lax.scan, not {kernel}")
+    log_once(who, (f"Pallas {kernel}" if taken else f"{otherwise}, not {kernel}")
              + f" ({why})")
     return taken
 

@@ -274,13 +274,15 @@ def classification_cost(input, label, name=None, weight=None, evaluator=None,
                         layer_attr=None):
     """softmax output + cross-entropy, fused (the reference wires a softmax
     fc output into multi-class-cross-entropy; we use the fused stable form
-    when the input activation is softmax)."""
+    when the input activation is softmax). ``weight``: a layer giving each
+    step's cost a weight before the row's sum."""
+    ins = [input, label] + ([weight] if weight is not None else [])
     if input.act is not None and input.act.name == "softmax":
         # refuse double-softmax: fuse by using the raw logits path is not
         # possible post-hoc, so use prob-form xent (reference behavior).
-        return Layer("multi-class-cross-entropy", [input, label], name=name,
+        return Layer("multi-class-cross-entropy", ins, name=name,
                      extra=layer_attr)
-    return Layer("softmax_with_cross_entropy", [input, label], name=name,
+    return Layer("softmax_with_cross_entropy", ins, name=name,
                  extra=layer_attr)
 
 
@@ -829,9 +831,12 @@ __all__ += ["multi_head_attention"]
 
 # --- decoder-only blocks (docs/qwen3_next.md) -----------------------------
 
-def rms_norm(input, eps=1e-6, name=None, param_attr=None, layer_attr=None):
-    """x * rsqrt(mean(x^2) + eps) * (1 + w) over the features, w from 0."""
+def rms_norm(input, eps=1e-6, zero_centered=True, name=None, param_attr=None,
+             layer_attr=None):
+    """x * rsqrt(mean(x^2) + eps) * (1 + w) over the features, w from 0;
+    with ``zero_centered=False`` ... * w, w from 1."""
     return Layer("rms_norm", [input], name=name, eps=eps,
+                 zero_centered=zero_centered,
                  param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
 
 
@@ -860,12 +865,44 @@ def gated_delta_net(input, num_k_heads, num_v_heads, head_k_dim, head_v_dim,
                  param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
 
 
-def moe_ffn(input, num_experts, top_k, expert_size, shared_size,
+def gqa_attention(input, num_heads, num_kv_heads, head_dim, mask,
+                  rope_theta=10000.0, eps=1e-6, scope=None, name=None,
+                  param_attr=None, layer_attr=None):
+    """Grouped-query self-attention with per-head q/k RMS norm (weight w,
+    from 1), rotary positions on the whole head and no gate, under a
+    structured mask the layer knows by rule: ``mask=("block_diffusion", L,
+    b)`` for rows of 2L positions [noised ; clean] in blocks of b tokens.
+    Only the tiles the rule keeps are computed."""
+    return Layer("gqa_attention", [input], name=name, num_heads=num_heads,
+                 num_kv_heads=num_kv_heads, head_dim=head_dim,
+                 rope_theta=rope_theta, eps=eps, mask=tuple(mask), scope=scope,
+                 param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
+
+
+def block_diffusion_noise(ids, v, t, block, mask_id, scope=None, name=None):
+    """(the 2L ids [xt ; x0], the loss weight of each of the L noised
+    positions) of a block-diffusion training row: token i of ``ids`` is
+    replaced by ``mask_id`` where v_i < t of its block; its weight is then
+    1 / t, and 0 anywhere else. The clean half carries no loss."""
+    noised = Layer("block_diffusion_noise", [ids, v, t], name=name,
+                   block=block, mask_id=mask_id, scope=scope)
+    weights = Layer("block_diffusion_weights", [ids, v, t], block=block,
+                    name=None if name is None else name + "_weights")
+    return noised, weights
+
+
+def noised_half(input, name=None):
+    """The first L of a sequence of 2L positions [noised ; clean]."""
+    return Layer("noised_half", [input], name=name)
+
+
+def moe_ffn(input, num_experts, top_k, expert_size, shared_size=None,
             experts_held=None, first_expert=0, tile=256, scope=None, name=None,
             param_attr=None, layer_attr=None):
-    """Top-k mixture of gated-MLP experts with a gated shared expert. The
-    router is over all ``num_experts``; the layer holds (and computes) the
-    experts [first_expert, first_expert + experts_held) only."""
+    """Top-k mixture of gated-MLP experts, with a gated shared expert of
+    ``shared_size`` where one is given. The router is over all
+    ``num_experts``; the layer holds (and computes) the experts
+    [first_expert, first_expert + experts_held) only."""
     return Layer("moe_ffn", [input], name=name, num_experts=num_experts,
                  top_k=top_k, expert_size=expert_size, shared_size=shared_size,
                  experts_held=experts_held or num_experts,
@@ -873,7 +910,8 @@ def moe_ffn(input, num_experts, top_k, expert_size, shared_size,
                  param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
 
 
-__all__ += ["rms_norm", "gated_attention", "gated_delta_net", "moe_ffn"]
+__all__ += ["rms_norm", "gated_attention", "gated_delta_net", "moe_ffn",
+            "gqa_attention", "block_diffusion_noise", "noised_half"]
 
 
 # --- detection (SSD) ------------------------------------------------------

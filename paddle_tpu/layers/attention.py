@@ -170,15 +170,17 @@ def causal_gqa(q, k, v, block):
     return jnp.concatenate(outs, axis=1)
 
 
-def rows_one_at_a_time(mixer, x, params):
+def rows_one_at_a_time(mixer, x, params, kept=("gdn_T",)):
     """``mixer(row [T, d], params)`` over the rows of x [B, T, d], one after
     the other, each row's forward computed again in its backward pass: what
     is alive at once is one row's activations, not the batch's (a row of
     4096 tokens fills the MXU on its own). The one thing kept from a row's
-    forward is what the mixer names ``gdn_T`` (kernels/gdn.py: the delta
-    rule's chunk inverses); ``gated_attention`` names nothing, so all of its
-    forward is computed again."""
-    keep = jax.checkpoint_policies.save_only_these_names("gdn_T")
+    forward is what the mixer names as ``kept`` says: ``gdn_T``
+    (kernels/gdn.py: the delta rule's chunk inverses), ``flash_attn_o`` /
+    ``flash_attn_lse`` (kernels/flash_attn.py: what the forward launch
+    made); ``gated_attention`` names nothing, so all of its forward is
+    computed again."""
+    keep = jax.checkpoint_policies.save_only_these_names(*kept)
     return jax.lax.map(
         jax.checkpoint(lambda row: mixer(row, params), policy=keep), x)
 
@@ -214,4 +216,81 @@ def _gated_attention_forward(cfg, params, ins, ctx):
 
     with jax.named_scope(cfg.attr("scope") or cfg.name):
         out = rows_one_at_a_time(mixer, x, params)
+    return ins[0].with_value(out)
+
+
+# --- grouped-query attention under a structured mask -------------------------
+
+def _gqa_params(cfg, in_infos):
+    d = in_infos[0].size
+    H, Hkv, D = cfg.attr("num_heads"), cfg.attr("num_kv_heads"), cfg.attr("head_dim")
+    a = cfg.param_attr(0)
+    return {
+        "wq": ParamSpec((d, H * D), a, fan_in=d),
+        "wk": ParamSpec((d, Hkv * D), a, fan_in=d),
+        "wv": ParamSpec((d, Hkv * D), a, fan_in=d),
+        "wo": ParamSpec((H * D, d), a, fan_in=H * D),
+        "q_norm": ParamSpec((D,), const_init(a, 1.0), fan_in=D),
+        "k_norm": ParamSpec((D,), const_init(a, 1.0), fan_in=D),
+    }
+
+
+def rotary_at(x, pos, theta):
+    """Rotate-half rotary on the whole last axis of x [B, T, heads, D], at
+    the given position of each of the T steps; angles in float32."""
+    D = x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    ang = jnp.asarray(pos, jnp.float32)[:, None] * inv[None]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None]
+    turned = jnp.concatenate([-x[..., D // 2:], x[..., :D // 2]], -1)
+    return x * cos.astype(x.dtype) + turned * sin.astype(x.dtype)
+
+
+def _head_norm(x, w, eps, scale=1.0):
+    """x * rsqrt(mean(x^2) + eps) * w * scale over the head, in float32,
+    rounded once to x's dtype."""
+    f32 = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(f32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * (w.astype(f32) * scale)).astype(x.dtype)
+
+
+@register_layer("gqa_attention", params=_gqa_params)
+def _gqa_attention_forward(cfg, params, ins, ctx):
+    """Grouped-query self-attention as SDAR's blocks have it (docs/sdar.md):
+    per-head RMS norm of q and k with a learned weight w, rotate-half rotary
+    on the whole head at the position the mask's rule gives each step,
+    softmax(q k^T / sqrt(D)) under the rule's mask, no gate, no bias. The
+    rule (``mask``) is known at trace time and the scores are computed tile
+    by tile where it keeps something (kernels/flash_attn.py). A row's
+    padding is computed as tokens: the rule is over whole rows."""
+    from paddle_tpu.kernels import flash_attn
+
+    enforce(not getattr(ctx, "packed", False),
+            f"gqa_attention {cfg.name}: packed rows need a segment rule "
+            "beside the mask's, which this layer does not have")
+    x = ins[0].value
+    B, T, _ = x.shape
+    H, Hkv, D = cfg.attr("num_heads"), cfg.attr("num_kv_heads"), cfg.attr("head_dim")
+    eps, theta, rule = cfg.attr("eps", 1e-6), cfg.attr("rope_theta"), cfg.attr("mask")
+    pos = flash_attn.positions(rule, T)
+
+    def mixer(x, p):
+        """One row [T, d]."""
+        q = jnp.matmul(x, p["wq"]).reshape(1, T, H, D)
+        k = jnp.matmul(x, p["wk"]).reshape(1, T, Hkv, D)
+        v = jnp.matmul(x, p["wv"])[None]
+        # 1 / sqrt(D) rides on the query's norm weight: one rounding
+        q = rotary_at(_head_norm(q, p["q_norm"], eps, D ** -0.5), pos, theta)
+        k = rotary_at(_head_norm(k, p["k_norm"], eps), pos, theta)
+        o = flash_attn.attention(cfg.name, q.reshape(1, T, H * D),
+                                 k.reshape(1, T, Hkv * D), v, rule, Hkv)
+        return jnp.matmul(o[0], p["wo"])
+
+    # a row's backward pass computes its projections again from the layer's
+    # input; what the kernels' forward launch made of the row it keeps
+    with jax.named_scope(cfg.attr("scope") or cfg.name):
+        out = rows_one_at_a_time(mixer, x, params,
+                                 kept=("flash_attn_o", "flash_attn_lse"))
     return ins[0].with_value(out)

@@ -84,6 +84,15 @@ def register_cost(name):
     return deco
 
 
+def _weighted(cost, ins):
+    """Per-step costs times a third input's per-step weights (float32
+    [B, T] or [B, T, 1]), where the layer was given one."""
+    if len(ins) < 3:
+        return cost
+    w = _f32up(ins[2].value)
+    return cost * (w[..., 0] if w.ndim == cost.ndim + 1 else w)
+
+
 def _reduce_seq(cost, mask):
     """[B, T] per-step costs -> [B] via masked sum."""
     if mask is not None:
@@ -104,7 +113,8 @@ def _stable_nll(logits, ids):
 
 @register_cost("multi-class-cross-entropy")
 def _xent_forward(cfg, params, ins, ctx):
-    """Input 0: probability distribution (post-softmax); input 1: int labels.
+    """Input 0: probability distribution (post-softmax); input 1: int labels;
+    an optional input 2: a weight for each step's cost.
     When the producing layer stashed pre-softmax logits (core/layer.py
     Layer.forward), compute the numerically-stable fused log-softmax form
     directly from them — XLA then dead-code-eliminates the softmax if the
@@ -118,14 +128,15 @@ def _xent_forward(cfg, params, ins, ctx):
     logits = ctx.extras.get(f"{cfg.inputs[0].name}#logits") \
         if cfg.inputs else None
     if logits is not None and logits.value.shape == probs.value.shape:
-        cost = _reduce_seq(_stable_nll(logits.value, ids), probs.mask)
+        cost = _reduce_seq(_weighted(_stable_nll(logits.value, ids), ins),
+                           probs.mask)
         return Arg(cost[:, None])
     # gather FIRST, then upcast/clip/log on the [B(,T)] gathered vector —
     # upcasting the whole [B,T,V] prob tensor materialises a V-sized f32
     # array (at V=30k that is a 921MB HBM pass per step; r4 profile)
     p_lab = jnp.take_along_axis(probs.value, ids[..., None], axis=-1)[..., 0]
     nll = -jnp.log(jnp.clip(_f32up(p_lab), 1e-10, 1.0))
-    cost = _reduce_seq(nll, probs.mask)
+    cost = _reduce_seq(_weighted(nll, ins), probs.mask)
     return Arg(cost[:, None])
 
 
@@ -138,7 +149,8 @@ def _fused_xent_forward(cfg, params, ins, ctx):
     ids = label.value.astype(jnp.int32)
     if ids.ndim == logits.value.ndim:
         ids = ids[..., 0]
-    cost = _reduce_seq(_stable_nll(logits.value, ids), logits.mask)
+    cost = _reduce_seq(_weighted(_stable_nll(logits.value, ids), ins),
+                       logits.mask)
     return Arg(cost[:, None])
 
 

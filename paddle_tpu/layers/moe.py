@@ -6,7 +6,8 @@ layer (docs/qwen3_next.md).
     LAYER HOLDS, [first_expert, first_expert + experts_held), of
     p_e (silu(x Wg_e) * (x Wu_e)) Wd_e;
     shared = sigmoid(x w_sg) * (silu(x Wg_s) * (x Wu_s)) Wd_s;
-    out = routed + shared
+    out = routed + shared; a layer built without a shared size has no
+    shared expert: no ``shared_*`` leaf, no shared term
 
 What the absent experts would add is left out: the other shares of the layer
 hold them, and on one chip nothing stands in for the exchange that would
@@ -78,16 +79,20 @@ def _moe_params(cfg, in_infos):
     E, held = cfg.attr("num_experts"), cfg.attr("experts_held")
     I, Is = cfg.attr("expert_size"), cfg.attr("shared_size")
     a = cfg.param_attr(0)
-    return {
+    specs = {
         "router": ParamSpec((d, E), a, fan_in=d),
         "wg": ParamSpec((held, d, I), a, fan_in=d),
         "wu": ParamSpec((held, d, I), a, fan_in=d),
         "wd": ParamSpec((held, I, d), a, fan_in=I),
-        "shared_gate": ParamSpec((d, 1), a, fan_in=d),
-        "shared_wg": ParamSpec((d, Is), a, fan_in=d),
-        "shared_wu": ParamSpec((d, Is), a, fan_in=d),
-        "shared_wd": ParamSpec((Is, d), a, fan_in=Is),
     }
+    if Is is not None:
+        specs.update({
+            "shared_gate": ParamSpec((d, 1), a, fan_in=d),
+            "shared_wg": ParamSpec((d, Is), a, fan_in=d),
+            "shared_wu": ParamSpec((d, Is), a, fan_in=d),
+            "shared_wd": ParamSpec((Is, d), a, fan_in=Is),
+        })
+    return specs
 
 
 def _acc(dtype):
@@ -225,6 +230,8 @@ def _moe_ffn_forward(cfg, params, ins, ctx):
             idx, top, valid.reshape(-1), first, held, tile)
         routed = grouped_ffn(x, p["wg"], p["wu"], p["wd"], row_w, row_tok,
                              tile_expert, n_tiles, tile)
+        if "shared_gate" not in p:
+            return routed.reshape(x_in.shape), stats
         gate = jax.nn.sigmoid(jnp.matmul(x, p["shared_gate"]).astype(acc))
         h = jax.nn.silu(jnp.matmul(x, p["shared_wg"])) \
             * jnp.matmul(x, p["shared_wu"])

@@ -229,13 +229,18 @@ def rms_normalize(x, eps):
 
 def _rms_params(cfg, in_infos):
     d = in_infos[0].size
-    return {"w0": ParamSpec((d,), const_init(cfg.param_attr(0), 0.0), fan_in=d)}
+    start = 0.0 if cfg.attr("zero_centered", True) else 1.0
+    return {"w0": ParamSpec((d,), const_init(cfg.param_attr(0), start),
+                            fan_in=d)}
 
 
 @register_layer("rms_norm", params=_rms_params)
 def _rms_norm_forward(cfg, params, ins, ctx):
-    """Zero-centred RMS norm over the feature axis:
-    x * rsqrt(mean(x^2) + eps) * (1 + w), w starting at 0."""
+    """RMS norm over the feature axis: zero-centred,
+    x * rsqrt(mean(x^2) + eps) * (1 + w) with w starting at 0, or with
+    ``zero_centered=False`` the plain form, ... * w with w starting at 1."""
     x = ins[0].value
-    y = rms_normalize(x, cfg.attr("eps", 1e-6)) * (1 + params["w0"]).astype(x.dtype)
-    return ins[0].with_value(y)
+    y = rms_normalize(x, cfg.attr("eps", 1e-6))
+    w = params["w0"]
+    scale = 1 + w if cfg.attr("zero_centered", True) else w
+    return ins[0].with_value(y * scale.astype(x.dtype))

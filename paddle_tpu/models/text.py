@@ -316,3 +316,65 @@ def qwen3_next_lm_cost(vocab_size=151936, hidden_size=2048,
     probs = layer.fc(input=x, size=vocab_size, act=act.Softmax(),
                      bias_attr=False, name=f"{name}_head")
     return layer.classification_cost(input=probs, label=nxt, name="cost")
+
+
+def sdar_lm_cost(vocab_size=151936, hidden_size=2048, num_hidden_layers=48,
+                 num_attention_heads=32, num_key_value_heads=4, head_dim=128,
+                 rope_theta=1000000, moe_intermediate_size=768,
+                 num_experts=128, num_experts_per_tok=8, experts_held=None,
+                 first_expert=0, rms_norm_eps=1e-6, seq_len=8192,
+                 block_length=4, mask_token_id=151669, name="s"):
+    """Block-diffusion training cost of an SDAR decoder (docs/sdar.md):
+    blocks of ``h = x + attn(norm(x)); y = h + moe(norm(h))``, grouped-query
+    attention with per-head q/k norms and a top-k MoE with no shared expert,
+    a final norm and an untied head. A row of ``seq_len`` clean ids is noised
+    block by block and the model runs once over the 2 x seq_len positions
+    [noised ; clean] under the block-diffusion mask; the head and the loss
+    (1 / t on every masked token, no shift) are on the noised half. Every
+    MoE routes over all ``num_experts`` and holds ``experts_held`` of them
+    from ``first_expert`` on (all by default). Feeds: ``ids`` (an integer
+    sequence of seq_len tokens), ``mask_u`` (seq_len values in [-0.5, 0.5):
+    token i is masked where mask_u + 0.5 < t of its block) and ``noise_t``
+    (one value a block in [-0.5, 0.5): t = 1/256 + noise_t + 0.5)."""
+    n_blocks = -(-seq_len // block_length)
+    ids = layer.data(name="ids",
+                     type=data_type.integer_value_sequence(vocab_size))
+    u = layer.data(name="mask_u", type=data_type.dense_vector(seq_len))
+    t = layer.data(name="noise_t", type=data_type.dense_vector(n_blocks))
+    v = layer.slope_intercept(input=u, intercept=0.5, name=f"{name}_v")
+    t = layer.slope_intercept(input=t, intercept=0.5 + 1.0 / 256,
+                              name=f"{name}_t")
+    both, weights = layer.block_diffusion_noise(
+        ids, v, t, block=block_length, mask_id=mask_token_id,
+        scope="sdar/noise", name=f"{name}_noise")
+    x = layer.embedding(input=both, size=hidden_size, name=f"{name}_emb")
+    for l in range(num_hidden_layers):
+        b = f"{name}_l{l}"
+        normed = layer.rms_norm(input=x, eps=rms_norm_eps, zero_centered=False,
+                                name=f"{b}_in_norm")
+        mixed = layer.gqa_attention(
+            input=normed, num_heads=num_attention_heads,
+            num_kv_heads=num_key_value_heads, head_dim=head_dim,
+            rope_theta=rope_theta, eps=rms_norm_eps,
+            mask=("block_diffusion", seq_len, block_length),
+            scope=f"sdar/l{l}/attn", name=f"{b}_attn")
+        h = layer.addto(input=[x, mixed], act=act.Linear(), bias_attr=False,
+                        name=f"{b}_h")
+        if l == num_hidden_layers - 1:
+            # nothing reads the clean half past the last attention
+            h = layer.noised_half(h, name=f"{b}_noised")
+        normed = layer.rms_norm(input=h, eps=rms_norm_eps, zero_centered=False,
+                                name=f"{b}_post_norm")
+        ffn = layer.moe_ffn(
+            input=normed, num_experts=num_experts, top_k=num_experts_per_tok,
+            expert_size=moe_intermediate_size, experts_held=experts_held,
+            first_expert=first_expert, scope=f"sdar/l{l}/moe",
+            name=f"{b}_moe")
+        x = layer.addto(input=[h, ffn], act=act.Linear(), bias_attr=False,
+                        name=f"{b}_out")
+    x = layer.rms_norm(input=x, eps=rms_norm_eps, zero_centered=False,
+                       name=f"{name}_final_norm")
+    probs = layer.fc(input=x, size=vocab_size, act=act.Softmax(),
+                     bias_attr=False, name=f"{name}_head")
+    return layer.classification_cost(
+        input=probs, label=ids, weight=weights, name="cost")

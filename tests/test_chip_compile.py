@@ -228,6 +228,47 @@ def test_qwen3_next_mixer_train_compiles_at_the_cells_size(one_chip, kind,
         assert names == [] and temp == 535_163_904, (names, temp)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_sdar_attention_train_compiles_at_the_cells_size(one_chip, dtype,
+                                                         monkeypatch):
+    """value_and_grad of one attention layer as the SDAR cell runs it (2
+    rows x 2 x 8192 positions x 2048, 32 : 4 heads of 128, the
+    block-diffusion mask in blocks of 4, one row at a time): a row's
+    backward pass keeps what `flash_attn_fwd` made, so the compiled layer
+    holds one forward and one backward launch, by their names."""
+    from paddle_tpu import data_type, layer
+    from paddle_tpu.core.arg import Arg
+    from paddle_tpu.core.topology import Topology
+    from paddle_tpu.kernels import flash_attn
+
+    # held to the CPU, `take_pallas` would hand the layer the tiles in XLA
+    monkeypatch.setattr(flash_attn, "take_pallas",
+                        lambda who, kernel, eligible=True, why_not="", **kw:
+                        eligible)
+    B, L, d = 2, 8192, 2048
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
+    out = layer.gqa_attention(input=x, num_heads=32, num_kv_heads=4,
+                              head_dim=128, rope_theta=1e6,
+                              mask=("block_diffusion", L, 4), name="l")
+    topo = Topology(out)
+    params = {k: _sds(s.shape, dtype, one_chip)
+              for k, s in topo.param_specs().items()}
+
+    def loss(params, x):
+        y = topo.forward(params, {"x": Arg(x, jnp.ones((B, 2 * L)))},
+                         training=True)["l"].value
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled, n = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params,
+                           _sds((B, 2 * L, d), dtype, one_chip))
+    names = _mosaic_instructions(compiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"gqa_attention {jnp.dtype(dtype).name}: {temp:,} bytes of temporaries")
+    assert n == 2 and len(names) == 2, names
+    assert sum("flash_attn_fwd" in x for x in names) == 1, names
+    assert sum("flash_attn_bwd" in x for x in names) == 1, names
+
+
 # the LSTM classifier (B64/H512/T100) and the split backward past the
 # in-kernel-dW VMEM gate (H1280)
 @pytest.mark.parametrize("B,H,T,dtype,precision", [

@@ -196,6 +196,10 @@ SKIP = {
                       "tests/test_validation_layers.py",
     "pnpair-validation": "constant-zero output by design (see "
                          "auc-validation); tests/test_validation_layers.py",
+    "block_diffusion_noise": "discrete output (ids with the mask id put "
+                             "in); checked in tests/test_sdar.py",
+    "block_diffusion_weights": "piecewise constant in its inputs (1 / t "
+                               "where v < t); checked in tests/test_sdar.py",
 }
 
 
@@ -778,6 +782,21 @@ def _b_gated_attention():
     return (layer.gated_attention(input=x, num_heads=4, num_kv_heads=2,
                                   head_dim=4, rotary_dim=2, query_block=2),
             {"x": _seq(5, 6, ragged=False)})
+
+
+@build("gqa_attention")
+def _b_gqa_attention():
+    # 3 tokens in blocks of 2 (a short last block), noised and clean halves
+    x = _data_seq("x", 6)
+    return (layer.gqa_attention(input=x, num_heads=4, num_kv_heads=2,
+                                head_dim=4, mask=("block_diffusion", 3, 2)),
+            {"x": _seq(6, 6, ragged=False)})
+
+
+@build("noised_half")
+def _b_noised_half():
+    x = _data_seq("x", 6)
+    return layer.noised_half(x), {"x": _seq(6, 6)}
 
 
 @build("gated_delta_net")
