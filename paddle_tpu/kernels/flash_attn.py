@@ -32,12 +32,17 @@ query heads that share a key/value head ([bq, G * D], read as the projection
 stored it: no transpose) and one key/value tile [bk, D]; the G heads' chains
 stand side by side in the body. Softmax is online, in float32; whole tiles
 skip the mask's compares and selects. The forward pass keeps the
-log-sum-exp [B, Hkv, T, G]; the backward pass is ONE launch of two phases
-over the same plan: key tiles outermost (dK, dV accumulate in VMEM over a
-key tile's query tiles and over the G heads), then query tiles outermost
-(dQ), each phase computing the tile's probabilities again from the
-log-sum-exp. Bytes a launch moves at the SDAR cell's shape beside the
-benchmark's need are in docs/sdar.md.
+log-sum-exp [B, Hkv, T, G]; the backward pass is ONE walk of the same steps
+(query tiles outermost): a tile's probabilities are computed once from the
+log-sum-exp and feed all three gradients, five products a tile and head.
+dQ accumulates in VMEM over a query tile's key tiles and leaves when the
+query tile moves; dK and dV of the whole row of one key/value head stay in
+VMEM ([Tp, D] float32 each, updated at the step's key rows) and leave once,
+when the (row, key/value head) is done. That working set follows the row's
+length, so ``kernel_gate`` admits the kernels only where
+``bwd_vmem_bytes`` fits; a longer row takes the tiles in XLA. Bytes a
+launch moves at the SDAR cell's shape beside the benchmark's need are in
+docs/sdar.md.
 """
 
 from __future__ import annotations
@@ -156,19 +161,19 @@ def attention_tiles_xla(q, k, v, rule, bq, bk):
 
 # ---- the Mosaic kernels ------------------------------------------------------
 
-_FIRST, _LAST, _MASKED, _DQ = 1, 2, 4, 8
+_FIRST, _LAST, _MASKED = 1, 2, 4
 
 
-def _steps(plan, by_key):
-    """The plan's kept tiles as three int32 vectors (query tile, key tile,
-    flags), query tiles outermost or key tiles outermost; _FIRST / _LAST
-    mark where an accumulator starts and ends."""
-    qi, ki = np.nonzero(plan.T)[::-1] if by_key else np.nonzero(plan)
-    outer = ki if by_key else qi
+def _steps(plan):
+    """The plan's kept tiles, each once, as the kernels' scalar prefetch:
+    three int32 vectors (query tile, key tile, flags), query tiles
+    outermost; _FIRST / _LAST mark where a query tile's accumulator starts
+    and ends. Forward and backward launch walk the same steps."""
+    qi, ki = np.nonzero(plan)
     flags = np.where(plan[qi, ki] == PARTIAL, _MASKED, 0)
-    edge = np.flatnonzero(np.diff(outer)) + 1
+    edge = np.flatnonzero(np.diff(qi)) + 1
     flags[np.concatenate([[0], edge])] |= _FIRST
-    flags[np.concatenate([edge - 1, [len(outer) - 1]])] |= _LAST
+    flags[np.concatenate([edge - 1, [len(qi) - 1]])] |= _LAST
     return qi.astype(np.int32), ki.astype(np.int32), flags.astype(np.int32)
 
 
@@ -208,7 +213,7 @@ def _either(flag, body):
     pl.when((flag & _MASKED) == 0)(lambda: body(False))
 
 
-def _fwd_kernel(qi_ref, ki_ref, fl_ref, qo_ref, ko_ref, q_ref, k_ref, v_ref, thr_ref, eq_ref,
+def _fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, thr_ref, eq_ref,
                 code_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, G, D):
     flag = fl_ref[pl.program_id(2)]
 
@@ -252,28 +257,27 @@ def _fwd_kernel(qi_ref, ki_ref, fl_ref, qo_ref, ko_ref, q_ref, k_ref, v_ref, thr
         lse_ref[0, 0] = _columns(lse)
 
 
-def _bwd_kernel(qi_ref, ki_ref, fl_ref, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+def _bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 dl_ref, thr_ref, eq_ref, code_ref, dq_ref, dk_ref, dv_ref,
                 dq_scr, dk_scr, dv_scr, *, G, D):
-    flag = fl_ref[pl.program_id(2)]
-    first, last = (flag & _FIRST) != 0, (flag & _LAST) != 0
-    dq_phase = (flag & _DQ) != 0
-    dkv_phase = (flag & _DQ) == 0
+    step = pl.program_id(2)
+    flag, ki = fl_ref[step], ki_ref[step]
 
-    @pl.when(first & dkv_phase)
+    @pl.when(step == 0)
     def _():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(first & dq_phase)
+    @pl.when((flag & _FIRST) != 0)
     def _():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def tile(masked, dq):
+    def tile(masked):
         k, v = k_ref[0], v_ref[0]
         lse, dl = lse_ref[0, 0], dl_ref[0, 0]
         if masked:
             kept = keep(thr_ref[...], eq_ref[...], code_ref[...])
+        dk = dv = None
         for g in range(G):
             cols = slice(g * D, (g + 1) * D)
             q, do = q_ref[0, :, cols], do_ref[0, :, cols]
@@ -281,29 +285,24 @@ def _bwd_kernel(qi_ref, ki_ref, fl_ref, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_
             if masked:
                 p = jnp.where(kept, p, 0.0)
             ds = (p * (_nt(do, v) - _column(dl, g))).astype(k.dtype)
-            if dq:
-                dq_scr[:, cols] += jnp.dot(ds, k,
-                                           preferred_element_type=jnp.float32)
-            else:
-                dv_scr[...] += _tn(p.astype(do.dtype), do)
-                dk_scr[...] += _tn(ds, q)
+            dq_scr[:, cols] += jnp.dot(ds, k,
+                                       preferred_element_type=jnp.float32)
+            dv_g, dk_g = _tn(p.astype(do.dtype), do), _tn(ds, q)
+            dv, dk = (dv_g, dk_g) if g == 0 else (dv + dv_g, dk + dk_g)
+        # the G heads' sum, then one update of the resident pair's key rows
+        dv_scr[ki] += dv
+        dk_scr[ki] += dk
 
-    @pl.when(dkv_phase)
+    _either(flag, tile)
+
+    @pl.when((flag & _LAST) != 0)
     def _():
-        _either(flag, functools.partial(tile, dq=False))
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
-    @pl.when(dq_phase)
-    def _():
-        _either(flag, functools.partial(tile, dq=True))
-
-    @pl.when(last & dkv_phase)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
-
-    @pl.when(last & dq_phase)
-    def _():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _params(interpret):
@@ -314,33 +313,30 @@ def _params(interpret):
         dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
-def _specs(G, D, bq, bk):
+def _specs(G, D, bq, bk, nk):
     """BlockSpecs by what a block follows: the step's query tile or its key
-    tile, read from the prefetched plan. A result follows ``qo`` / ``ko``,
-    which stand still through the phase that does not write it: a block
-    goes back to HBM when its index moves, and only finished ones may."""
+    tile, read from the prefetched steps, or (``row``: dK, dV) nothing but
+    the (row, key/value head): such a block stays in VMEM for the whole
+    walk and goes back to HBM once."""
     vm = pltpu.VMEM
     return {
         "q": pl.BlockSpec((1, bq, G * D),
-                          lambda b, n, s, qi, ki, fl, qo, ko: (b, qi[s], n),
+                          lambda b, n, s, qi, ki, fl: (b, qi[s], n),
                           memory_space=vm),
         "k": pl.BlockSpec((1, bk, D),
-                          lambda b, n, s, qi, ki, fl, qo, ko: (b, ki[s], n),
+                          lambda b, n, s, qi, ki, fl: (b, ki[s], n),
                           memory_space=vm),
-        "q_out": pl.BlockSpec((1, bq, G * D),
-                              lambda b, n, s, qi, ki, fl, qo, ko: (b, qo[s], n),
-                              memory_space=vm),
-        "k_out": pl.BlockSpec((1, bk, D),
-                              lambda b, n, s, qi, ki, fl, qo, ko: (b, ko[s], n),
-                              memory_space=vm),
+        "row": pl.BlockSpec((1, nk, bk, D),
+                            lambda b, n, s, qi, ki, fl: (b, 0, 0, n),
+                            memory_space=vm),
         "stat": pl.BlockSpec((1, 1, bq, G),
-                             lambda b, n, s, qi, ki, fl, qo, ko:
-                             (b, n, qi[s], 0), memory_space=vm),
+                             lambda b, n, s, qi, ki, fl: (b, n, qi[s], 0),
+                             memory_space=vm),
         "qcode": pl.BlockSpec((bq, 1),
-                              lambda b, n, s, qi, ki, fl, qo, ko: (qi[s], 0),
+                              lambda b, n, s, qi, ki, fl: (qi[s], 0),
                               memory_space=vm),
         "kcode": pl.BlockSpec((1, bk),
-                              lambda b, n, s, qi, ki, fl, qo, ko: (0, ki[s]),
+                              lambda b, n, s, qi, ki, fl: (0, ki[s]),
                               memory_space=vm),
     }
 
@@ -361,32 +357,17 @@ def _pad_rows(x, Tp):
     return x if T == Tp else jnp.pad(x, [(0, 0), (0, Tp - T), (0, 0)])
 
 
-def _prefetch(plan):
-    """The plan as the kernels' scalar prefetch: forward and the backward
-    pass's dQ phase walk query tiles outermost; its dK / dV phase, first in
-    the launch, walks key tiles outermost."""
-    qi, ki, fl = _steps(plan, by_key=False)
-    fwd = (qi, ki, fl, qi, ki)
-    kqi, kki, kfl = _steps(plan, by_key=True)
-    n = len(qi)
-    bwd = (np.concatenate([kqi, qi]), np.concatenate([kki, ki]),
-           np.concatenate([kfl, fl | _DQ]),
-           np.concatenate([np.full(n, qi[0], np.int32), qi]),
-           np.concatenate([kki, np.full(n, kki[-1], np.int32)]))
-    return fwd, bwd
-
-
 def _fwd_call(q, k, v, rule, Hkv, bq, bk, interpret):
     """(o [B, T, H * D], lse [B, Hkv, Tp, G] float32)."""
     B, T, _ = q.shape
     D, G = k.shape[-1] // Hkv, q.shape[-1] // k.shape[-1]
     Tp = round_up(T, math.lcm(bq, bk))
-    steps, _ = _prefetch(tile_plan(rule, T, bq, bk))
-    spec = _specs(G, D, bq, bk)
+    steps = _steps(tile_plan(rule, T, bq, bk))
+    spec = _specs(G, D, bq, bk, Tp // bk)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, G=G, D=D), name="flash_attn_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5, grid=(B, Hkv, len(steps[0])),
+            num_scalar_prefetch=3, grid=(B, Hkv, len(steps[0])),
             in_specs=[spec["q"], spec["k"], spec["k"], spec["qcode"],
                       spec["qcode"], spec["kcode"]],
             out_specs=[spec["q"], spec["stat"]],
@@ -405,30 +386,34 @@ def _bwd_call(q, k, v, o, lse, do, rule, Hkv, bq, bk, interpret):
     B, T, _ = q.shape
     D, G = k.shape[-1] // Hkv, q.shape[-1] // k.shape[-1]
     Tp = round_up(T, math.lcm(bq, bk))
-    _, steps = _prefetch(tile_plan(rule, T, bq, bk))
+    nk = Tp // bk
+    steps = _steps(tile_plan(rule, T, bq, bk))
     # sum_d o do of every (position, head), as the log-sum-exp is laid out
     dl = jnp.sum((o.astype(jnp.float32) * do.astype(jnp.float32))
                  .reshape(B, T, Hkv, G, D), axis=-1)
     dl = jnp.pad(jnp.moveaxis(dl, 1, 2), [(0, 0), (0, 0), (0, Tp - T), (0, 0)])
-    spec = _specs(G, D, bq, bk)
+    spec = _specs(G, D, bq, bk, nk)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, G=G, D=D), name="flash_attn_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5, grid=(B, Hkv, len(steps[0])),
+            num_scalar_prefetch=3, grid=(B, Hkv, len(steps[0])),
             in_specs=[spec["q"], spec["k"], spec["k"], spec["q"],
                       spec["stat"], spec["stat"], spec["qcode"],
                       spec["qcode"], spec["kcode"]],
-            out_specs=[spec["q_out"], spec["k_out"], spec["k_out"]],
+            out_specs=[spec["q"], spec["row"], spec["row"]],
             scratch_shapes=[pltpu.VMEM((bq, G * D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)]),
+                            pltpu.VMEM((nk, bk, D), jnp.float32),
+                            pltpu.VMEM((nk, bk, D), jnp.float32)]),
+        # dk, dv as [B, key tiles, bk, Hkv * D]: the same bytes as
+        # [B, Tp, Hkv * D], and a key tile's rows are one leading index
         out_shape=[jax.ShapeDtypeStruct((B, Tp) + q.shape[2:], q.dtype),
-                   jax.ShapeDtypeStruct((B, Tp) + k.shape[2:], k.dtype),
-                   jax.ShapeDtypeStruct((B, Tp) + v.shape[2:], v.dtype)],
+                   jax.ShapeDtypeStruct((B, nk, bk) + k.shape[2:], k.dtype),
+                   jax.ShapeDtypeStruct((B, nk, bk) + v.shape[2:], v.dtype)],
         interpret=interpret, **_params(interpret))(
             *steps, _pad_rows(q, Tp), _pad_rows(k, Tp), _pad_rows(v, Tp),
             _pad_rows(do.astype(q.dtype), Tp), lse, dl, *_codes(rule, T, Tp))
-    return dq[:, :T], dk[:, :T], dv[:, :T]
+    return (dq[:, :T], dk.reshape(B, Tp, -1)[:, :T],
+            dv.reshape(B, Tp, -1)[:, :T])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -459,22 +444,58 @@ def kernel_supported(D, dtype):
     return D % 128 == 0 and dtype in (jnp.bfloat16, jnp.float32)
 
 
+# what the backward launch may hold of VMEM_LIMIT_BYTES by the estimate
+# below; the rest is Mosaic's own (spills, the products' staging)
+_VMEM_BUDGET = VMEM_LIMIT_BYTES * 7 // 8
+
+
+def bwd_vmem_bytes(T, D, G, dtype):
+    """(all, dK/dV) bytes ``flash_attn_bwd`` holds in VMEM over a row of T
+    positions: dK and dV of one key/value head, whole, in float32, and
+    their result blocks; every tile block twice, as the pipeline keeps the
+    next one coming; dQ's accumulator; the body's [bq, bk] float32
+    temporaries (scores, probabilities, dp, ds and their roundings)."""
+    bq, bk = tile_sizes(T)
+    Tp, item = round_up(T, math.lcm(bq, bk)), jnp.dtype(dtype).itemsize
+    pair = 2 * Tp * D * 4
+    tiles = 2 * (3 * bq * G * D + 2 * bk * D) * item + bq * G * D * 4
+    stats = 2 * 2 * bq * round_up(G, 128) * 4
+    return (pair + 2 * 2 * Tp * D * item + tiles + stats + 6 * bq * bk * 4,
+            pair)
+
+
+def kernel_gate(T, D, G, dtype):
+    """(eligible, why not): the kernels cover head sizes that fill the
+    lanes in bf16 / float32, on rows whose dK / dV fit in VMEM."""
+    if not kernel_supported(D, dtype):
+        return False, (f"head size {D}, {jnp.dtype(dtype).name} is outside "
+                       "the kernel's gate")
+    need = bwd_vmem_bytes(T, D, G, dtype)[0]
+    return need <= _VMEM_BUDGET, (
+        f"over a row of {T} positions flash_attn_bwd would hold "
+        f"{need / 1e6:.1f} MB in VMEM, dK and dV whole, against the "
+        f"{_VMEM_BUDGET / 1e6:.1f} MB of the kernel's gate")
+
+
 def attention(who, q, k, v, rule, Hkv):
     """The layer's call: q [B, T, H * D] (scaled), k, v [B, T, Hkv * D] ->
     [B, T, H * D], by the Mosaic kernels on the TPU where their gate passes
     and by the same tiles in XLA elsewhere; the log says once a layer which,
-    and how much of the square the rule keeps."""
+    how much of the square the rule keeps and what the backward launch
+    holds in VMEM."""
     B, T, _ = q.shape
     D, G = k.shape[-1] // Hkv, q.shape[-1] // k.shape[-1]
     bq, bk = tile_sizes(T)
     kept, whole, partial, every = plan_counts(tile_plan(rule, T, bq, bk))
-    pallas = take_pallas(
-        who, "flash_attn_fwd/bwd", kernel_supported(D, q.dtype),
-        f"head size {D}, {jnp.dtype(q.dtype).name} is outside the kernel's gate",
-        otherwise="the tiles in XLA")
+    pallas = take_pallas(who, "flash_attn_fwd/bwd",
+                         *kernel_gate(T, D, G, q.dtype),
+                         otherwise="the tiles in XLA")
     log_once(who, f"mask {rule}: {kept} of {every} tiles of {bq} x {bk} kept "
              f"({whole} whole, {partial} partial)")
     if pallas:
+        need, pair = bwd_vmem_bytes(T, D, G, q.dtype)
+        log_once(who, f"flash_attn_bwd: one walk of {kept} tiles, dK/dV "
+                 f"{pair / 1e6:.1f} MB of {need / 1e6:.1f} MB in VMEM")
         return call_kernel(
             lambda q, k, v: flash_attention(q, k, v, rule, Hkv, bq, bk),
             (q, k, v), range(3))

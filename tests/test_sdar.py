@@ -133,12 +133,18 @@ def test_at_the_cells_shape_a_quarter_of_the_square_is_kept():
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("L,b,tile", [(40, 4, 16), (96, 32, 32), (24, 1, 16)])
-def test_flash_kernels_match_the_tiles_in_xla(L, b, tile, dtype, tol):
+@pytest.mark.parametrize("L,b,tile,B,Hkv,G", [
+    (40, 4, 16, 2, 2, 2), (96, 32, 32, 2, 2, 2), (24, 1, 16, 2, 2, 2),
+    # rows the tile does not divide: padding keys in the resident dK / dV
+    (36, 4, 16, 2, 2, 2), (20, 4, 32, 1, 1, 4),
+    # more rows and key/value heads: the pair starts from zero in each
+    (40, 8, 16, 3, 3, 1)])
+def test_flash_kernels_match_the_tiles_in_xla(L, b, tile, B, Hkv, G, dtype,
+                                              tol):
     """flash_attn_fwd / flash_attn_bwd (interpret mode) against
     `attention_tiles_xla` and against plain attention under the dense mask:
     the output and the gradients of q, k and v."""
-    B, Hkv, G, D, T = 2, 2, 2, 8, 2 * L
+    D, T = 8, 2 * L
     rule = ("block_diffusion", L, b)
     q = (_normal(1, B, T, Hkv * G * D) * D ** -0.5).astype(dtype)
     k, v = (_normal(s, B, T, Hkv * D).astype(dtype) for s in (2, 3))
@@ -169,6 +175,113 @@ def test_flash_kernels_match_the_tiles_in_xla(L, b, tile, dtype, tol):
         for a, w in zip(got[1], want[1]):
             assert a.dtype == w.dtype and a.shape == w.shape
             _close(a.astype(jnp.float32), w.astype(jnp.float32), tol)
+
+
+@pytest.mark.parametrize("L,b,tile", [(8192, 4, 512), (40, 4, 16),
+                                      (36, 4, 16), (96, 32, 32)])
+def test_the_backward_launch_walks_every_kept_tile_once(L, b, tile):
+    """Forward and backward launch share one list of steps: every kept tile
+    of the plan exactly once (288 at the cell's shape), query tiles
+    outermost, each query tile's run opened and closed once."""
+    plan = flash_attn.tile_plan(("block_diffusion", L, b), 2 * L, tile, tile)
+    qi, ki, fl = flash_attn._steps(plan)
+    kept = flash_attn.plan_counts(plan)[0]
+    assert len(qi) == len(ki) == len(fl) == kept
+    if L == 8192:
+        assert kept == 288
+    assert sorted(zip(qi.tolist(), ki.tolist())) == \
+        [tuple(t) for t in np.argwhere(plan != flash_attn.SKIPPED).tolist()]
+    assert np.all(np.diff(qi) >= 0)
+    first = (fl & flash_attn._FIRST) != 0
+    last = (fl & flash_attn._LAST) != 0
+    assert first.sum() == last.sum() == len(set(qi.tolist()))
+    assert np.array_equal((fl & flash_attn._MASKED) != 0,
+                          plan[qi, ki] == flash_attn.PARTIAL)
+
+
+@pytest.mark.parametrize("G", [1, 2, 4])
+def test_a_tile_costs_two_products_forward_and_five_backward(G):
+    """The traced kernel bodies (a copy for whole tiles, one for partial
+    ones): q k^T and p v a head forward; q k^T, do v^T, p^T do, ds^T q and
+    ds k a head backward, the probabilities made once."""
+    probe = _load("tools/flash_attn_probe.py")
+    L, tile, Hkv, D = 32, 16, 2, 8
+    q = jax.ShapeDtypeStruct((1, 2 * L, Hkv * G * D), jnp.float32)
+    k = jax.ShapeDtypeStruct((1, 2 * L, Hkv * D), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, do: jax.vjp(
+        lambda *a: flash_attn.flash_attention(
+            *a, ("block_diffusion", L, 4), Hkv, tile, tile, True),
+        q, k, v)[1](do))(q, k, k, q).jaxpr
+    assert probe.kernel_products(jaxpr, "flash_attn_fwd") == 2 * 2 * G
+    assert probe.kernel_products(jaxpr, "flash_attn_bwd") == 2 * 5 * G
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_the_vmem_gate_follows_the_rows_length(dtype, monkeypatch, caplog):
+    """dK and dV of a key/value head stay in VMEM over the whole row, so the
+    gate is an estimate from the shapes: the cell's row passes in both
+    dtypes, a row too long takes the tiles in XLA and the log says why."""
+    import logging
+
+    from paddle_tpu.kernels import _pallas_util
+
+    T, D, G = 16384, 128, 8
+    need, pair = flash_attn.bwd_vmem_bytes(T, D, G, dtype)
+    assert pair == 2 * T * D * 4 and pair < need <= flash_attn._VMEM_BUDGET
+    assert flash_attn.kernel_gate(T, D, G, dtype)[0]
+    assert flash_attn._VMEM_BUDGET < _pallas_util.VMEM_LIMIT_BYTES
+    # the estimate grows with the row, and somewhere stops fitting
+    needs = [flash_attn.bwd_vmem_bytes(t, D, G, dtype)[0]
+             for t in (4096, 16384, 32768, 65536, 131072)]
+    assert needs == sorted(needs)
+    long_row = 131072
+    ok, why = flash_attn.kernel_gate(long_row, D, G, dtype)
+    assert not ok and f"over a row of {long_row} positions" in why \
+        and "in VMEM" in why
+
+    # the layer reads that gate: a short row whose estimate is made not to
+    # fit takes the tiles in XLA, and says so once
+    monkeypatch.setattr(_pallas_util, "_LOGGED_DECISIONS", set())
+    monkeypatch.setattr(flash_attn, "_VMEM_BUDGET", 1000)
+    q = _normal(1, 1, 80, 2 * 128).astype(dtype)
+    with caplog.at_level(logging.INFO, logger="paddle_tpu"):
+        o = flash_attn.attention("l7", q, q[..., :128], q[..., :128],
+                                 ("block_diffusion", 40, 4), 1)
+    assert o.shape == q.shape and o.dtype == q.dtype
+    lines = [r.getMessage() for r in caplog.records if "l7" in r.getMessage()]
+    assert len(lines) == 2 and lines[0].startswith(
+        "l7: the tiles in XLA, not flash_attn_fwd/bwd (over a row of 80 "
+        "positions flash_attn_bwd would hold ") \
+        and "MB in VMEM, dK and dV whole, against the" in lines[0], lines
+
+
+def test_the_layers_log_says_what_the_backward_launch_holds(monkeypatch,
+                                                            caplog):
+    """Where the kernels are taken the layer's log gains, once, the form of
+    the backward launch and what of the estimate is the resident pair."""
+    import logging
+
+    from paddle_tpu.kernels import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "_LOGGED_DECISIONS", set())
+    monkeypatch.setattr(flash_attn, "take_pallas",
+                        lambda who, kernel, eligible=True, why_not="", **kw:
+                        eligible)
+    monkeypatch.setattr(flash_attn, "flash_attention",
+                        lambda q, k, v, *a: q)      # nothing runs here
+    q = jax.ShapeDtypeStruct((1, 16384, 8 * 4 * 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 16384, 4 * 128), jnp.bfloat16)
+    with caplog.at_level(logging.INFO, logger="paddle_tpu"):
+        for _ in range(2):
+            jax.eval_shape(lambda q, k: flash_attn.attention(
+                "l5", q, k, k, ("block_diffusion", 8192, 4), 4), q, k)
+    lines = [r.getMessage() for r in caplog.records if "l5" in r.getMessage()]
+    assert lines == [
+        "l5: mask ('block_diffusion', 8192, 4): 288 of 1024 tiles of 512 x "
+        "512 kept (240 whole, 48 partial)",
+        "l5: flash_attn_bwd: one walk of 288 tiles, dK/dV 16.8 MB of 49.8 "
+        "MB in VMEM"], lines
 
 
 def test_kernel_gate_and_the_layers_line_in_the_log(monkeypatch, caplog):
