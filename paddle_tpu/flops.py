@@ -117,6 +117,16 @@ def _gqa_attention_flops(l, shapes, T) -> float:
     return proj + 2 * 2.0 * H * D * kept
 
 
+def _mla_attention_flops(l, shapes, T) -> float:
+    """Per row: the query, latent, decompression and output projections, and
+    the causal scores (heads of nope + rope) and weighted sum (value
+    heads), each query against the keys at or before it."""
+    H = l.attr("num_heads")
+    Dqk = l.attr("qk_nope_head_dim") + l.attr("qk_rope_head_dim")
+    proj = 2.0 * T * _numel(shapes, "wq", "wkva", "wkvb", "wo")
+    return proj + 2.0 * H * (Dqk + l.attr("v_head_dim")) * T * (T + 1) / 2
+
+
 def _gated_delta_net_flops(l, shapes, T) -> float:
     """Per row: the projections, the depthwise convolution, and the delta
     rule as its recurrence defines it (three dk x dv matrix-vector products
@@ -146,6 +156,7 @@ _DECODER_FLOPS = {
     "rms_norm": lambda l, shapes, T: 0.0,        # elementwise
     "gated_attention": _gated_attention_flops,
     "gqa_attention": _gqa_attention_flops,
+    "mla_attention": _mla_attention_flops,
     "gated_delta_net": _gated_delta_net_flops,
     "moe_ffn": _moe_ffn_flops,
 }

@@ -4,9 +4,12 @@ time, computed tile by tile and only where the mask keeps something
 
     o_i = sum_j softmax_j(q_i . k_j  where keep(i, j)) v_j
 
-q arrives scaled (the layer folds 1/sqrt(D) into the query's norm weight).
-The mask is a RULE, not an array: ``("block_diffusion", L, b)`` over the 2L
-positions ``[noised ; clean]`` of a block-diffusion training row,
+q arrives scaled (the layer folds 1/sqrt(D) into the query). The head size of
+q and k (Dk) may differ from that of v and o (Dv): latent attention's heads
+are 192 : 128 (docs/kimi_vl.md), padded to 256 : 128 by the layer. The mask is
+a RULE, not an array: ``("causal", L)`` over the L positions of a row,
+keep(i, j) = j <= i, or ``("block_diffusion", L, b)`` over the 2L positions
+``[noised ; clean]`` of a block-diffusion training row,
 
     keep(i, j) = (i <  L, j <  L, blk(j) == blk(i))      noised sees its block
                | (i <  L, j >= L, blk(j) <  blk(i))      and the clean past
@@ -28,8 +31,8 @@ implementations walk that one plan:
 The kernels' grid is (batch, key/value heads, kept tiles): the plan's kept
 tiles reach the index maps by scalar prefetch, so a skipped tile costs
 nothing, not even a grid step. A grid step holds one query tile of the G
-query heads that share a key/value head ([bq, G * D], read as the projection
-stored it: no transpose) and one key/value tile [bk, D]; the G heads' chains
+query heads that share a key/value head ([bq, G * Dk], read as the projection
+stored it: no transpose) and one key and value tile [bk, Dk], [bk, Dv]; the G heads' chains
 stand side by side in the body. Softmax is online, in float32; whole tiles
 skip the mask's compares and selects. The forward pass keeps the
 log-sum-exp [B, Hkv, T, G]; the backward pass is ONE walk of the same steps
@@ -37,12 +40,12 @@ log-sum-exp [B, Hkv, T, G]; the backward pass is ONE walk of the same steps
 log-sum-exp and feed all three gradients, five products a tile and head.
 dQ accumulates in VMEM over a query tile's key tiles and leaves when the
 query tile moves; dK and dV of the whole row of one key/value head stay in
-VMEM ([Tp, D] float32 each, updated at the step's key rows) and leave once,
+VMEM ([Tp, Dk] and [Tp, Dv] float32, updated at the step's key rows) and leave once,
 when the (row, key/value head) is done. That working set follows the row's
 length, so ``kernel_gate`` admits the kernels only where
 ``bwd_vmem_bytes`` fits; a longer row takes the tiles in XLA. Bytes a
-launch moves at the SDAR cell's shape beside the benchmark's need are in
-docs/sdar.md.
+launch moves at the cells' shapes beside the benchmark's need are in
+docs/sdar.md and docs/kimi_vl.md.
 """
 
 from __future__ import annotations
@@ -73,9 +76,15 @@ _NEVER = np.iinfo(np.int32).max
 def mask_codes(rule, T, xp=np):
     """(thr [T], eq [T], code [T]) int32 of a rule over T positions:
     keep(i, j) = code[j] <= thr[i] or code[j] == eq[i]."""
-    kind, L, b = rule
-    enforce(kind == "block_diffusion",
+    kind, L = rule[:2]
+    enforce(kind in ("causal", "block_diffusion"),
             f"attention mask rule {kind!r} is not known")
+    if kind == "causal":
+        enforce(T == L, f"a causal mask over rows of {L} tokens needs {L} "
+                f"positions, the layer got {T}")
+        pos = xp.arange(T, dtype=xp.int32)
+        return pos, xp.full((T,), -1, xp.int32), pos
+    b = rule[2]
     enforce(T == 2 * L, f"a block_diffusion mask over rows of {L} tokens "
             f"needs 2 x {L} positions, the layer got {T}")
     nb = -(-L // b)
@@ -133,8 +142,8 @@ def tile_sizes(T):
 # ---- the tiles in XLA ------------------------------------------------------
 
 def attention_tiles_xla(q, k, v, rule, bq, bk):
-    """q [B, T, Hkv, G, D] (scaled), k, v [B, T, Hkv, D] -> [B, T, Hkv, G, D]:
-    one query tile at a time against the key tiles the plan keeps for it,
+    """q [B, T, Hkv, G, Dk] (scaled), k [B, T, Hkv, Dk], v [B, T, Hkv, Dv]
+    -> [B, T, Hkv, G, Dv]: one query tile at a time against the key tiles the plan keeps for it,
     scores [B, Hkv, G, bq, kept keys] in float32, computed again in the
     backward pass."""
     T = q.shape[1]
@@ -214,7 +223,7 @@ def _either(flag, body):
 
 
 def _fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, thr_ref, eq_ref,
-                code_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, G, D):
+                code_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, G, Dk, Dv):
     flag = fl_ref[pl.program_id(2)]
 
     @pl.when((flag & _FIRST) != 0)
@@ -228,8 +237,8 @@ def _fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, thr_ref, eq_ref,
         if masked:
             kept = keep(thr_ref[...], eq_ref[...], code_ref[...])
         for g in range(G):
-            cols = slice(g * D, (g + 1) * D)
-            s = _nt(q_ref[0, :, cols], k)
+            cols = slice(g * Dv, (g + 1) * Dv)
+            s = _nt(q_ref[0, :, g * Dk:(g + 1) * Dk], k)
             if masked:
                 s = jnp.where(kept, s, NEG)
             m_prev = m_scr[g]
@@ -249,7 +258,7 @@ def _fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, thr_ref, eq_ref,
     def _():
         lse = []
         for g in range(G):
-            cols = slice(g * D, (g + 1) * D)
+            cols = slice(g * Dv, (g + 1) * Dv)
             l = l_scr[g]
             l = jnp.where(l == 0.0, 1.0, l)      # padding queries keep nothing
             o_ref[0, :, cols] = (acc_scr[:, cols] / l).astype(o_ref.dtype)
@@ -259,7 +268,7 @@ def _fwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, thr_ref, eq_ref,
 
 def _bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 dl_ref, thr_ref, eq_ref, code_ref, dq_ref, dk_ref, dv_ref,
-                dq_scr, dk_scr, dv_scr, *, G, D):
+                dq_scr, dk_scr, dv_scr, *, G, Dk, Dv):
     step = pl.program_id(2)
     flag, ki = fl_ref[step], ki_ref[step]
 
@@ -279,8 +288,8 @@ def _bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             kept = keep(thr_ref[...], eq_ref[...], code_ref[...])
         dk = dv = None
         for g in range(G):
-            cols = slice(g * D, (g + 1) * D)
-            q, do = q_ref[0, :, cols], do_ref[0, :, cols]
+            cols = slice(g * Dk, (g + 1) * Dk)
+            q, do = q_ref[0, :, cols], do_ref[0, :, g * Dv:(g + 1) * Dv]
             p = jnp.exp(_nt(q, k) - _column(lse, g))
             if masked:
                 p = jnp.where(kept, p, 0.0)
@@ -313,22 +322,32 @@ def _params(interpret):
         dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
-def _specs(G, D, bq, bk, nk):
-    """BlockSpecs by what a block follows: the step's query tile or its key
-    tile, read from the prefetched steps, or (``row``: dK, dV) nothing but
+def _specs(G, Dk, Dv, bq, bk, nk):
+    """BlockSpecs by what a block follows: the step's query tile (``q``,
+    ``dq`` of width G * Dk; ``o``, ``do`` of G * Dv) or its key tile (``k``,
+    ``v``), read from the prefetched steps, or (``dk``, ``dv``) nothing but
     the (row, key/value head): such a block stays in VMEM for the whole
     walk and goes back to HBM once."""
     vm = pltpu.VMEM
-    return {
-        "q": pl.BlockSpec((1, bq, G * D),
-                          lambda b, n, s, qi, ki, fl: (b, qi[s], n),
-                          memory_space=vm),
-        "k": pl.BlockSpec((1, bk, D),
-                          lambda b, n, s, qi, ki, fl: (b, ki[s], n),
-                          memory_space=vm),
-        "row": pl.BlockSpec((1, nk, bk, D),
+
+    def query(width):
+        return pl.BlockSpec((1, bq, width),
+                            lambda b, n, s, qi, ki, fl: (b, qi[s], n),
+                            memory_space=vm)
+
+    def key(width):
+        return pl.BlockSpec((1, bk, width),
+                            lambda b, n, s, qi, ki, fl: (b, ki[s], n),
+                            memory_space=vm)
+
+    def row(width):
+        return pl.BlockSpec((1, nk, bk, width),
                             lambda b, n, s, qi, ki, fl: (b, 0, 0, n),
-                            memory_space=vm),
+                            memory_space=vm)
+
+    return {
+        "q": query(G * Dk), "o": query(G * Dv), "k": key(Dk), "v": key(Dv),
+        "dk": row(Dk), "dv": row(Dv),
         "stat": pl.BlockSpec((1, 1, bq, G),
                              lambda b, n, s, qi, ki, fl: (b, n, qi[s], 0),
                              memory_space=vm),
@@ -357,24 +376,30 @@ def _pad_rows(x, Tp):
     return x if T == Tp else jnp.pad(x, [(0, 0), (0, Tp - T), (0, 0)])
 
 
+def _head_sizes(q, k, v, Hkv):
+    """(Dk, Dv, G) of q [.., H * Dk], k [.., Hkv * Dk], v [.., Hkv * Dv]."""
+    return k.shape[-1] // Hkv, v.shape[-1] // Hkv, q.shape[-1] // k.shape[-1]
+
+
 def _fwd_call(q, k, v, rule, Hkv, bq, bk, interpret):
-    """(o [B, T, H * D], lse [B, Hkv, Tp, G] float32)."""
+    """(o [B, T, H * Dv], lse [B, Hkv, Tp, G] float32)."""
     B, T, _ = q.shape
-    D, G = k.shape[-1] // Hkv, q.shape[-1] // k.shape[-1]
+    Dk, Dv, G = _head_sizes(q, k, v, Hkv)
     Tp = round_up(T, math.lcm(bq, bk))
     steps = _steps(tile_plan(rule, T, bq, bk))
-    spec = _specs(G, D, bq, bk, Tp // bk)
+    spec = _specs(G, Dk, Dv, bq, bk, Tp // bk)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, G=G, D=D), name="flash_attn_fwd",
+        functools.partial(_fwd_kernel, G=G, Dk=Dk, Dv=Dv),
+        name="flash_attn_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B, Hkv, len(steps[0])),
-            in_specs=[spec["q"], spec["k"], spec["k"], spec["qcode"],
+            in_specs=[spec["q"], spec["k"], spec["v"], spec["qcode"],
                       spec["qcode"], spec["kcode"]],
-            out_specs=[spec["q"], spec["stat"]],
+            out_specs=[spec["o"], spec["stat"]],
             scratch_shapes=[pltpu.VMEM((G, bq, 1), jnp.float32),
                             pltpu.VMEM((G, bq, 1), jnp.float32),
-                            pltpu.VMEM((bq, G * D), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((B, Tp, G * Hkv * D), q.dtype),
+                            pltpu.VMEM((bq, G * Dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, Tp, G * Hkv * Dv), q.dtype),
                    jax.ShapeDtypeStruct((B, Hkv, Tp, G), jnp.float32)],
         interpret=interpret, **_params(interpret))(
             *steps, _pad_rows(q, Tp), _pad_rows(k, Tp), _pad_rows(v, Tp),
@@ -384,26 +409,27 @@ def _fwd_call(q, k, v, rule, Hkv, bq, bk, interpret):
 
 def _bwd_call(q, k, v, o, lse, do, rule, Hkv, bq, bk, interpret):
     B, T, _ = q.shape
-    D, G = k.shape[-1] // Hkv, q.shape[-1] // k.shape[-1]
+    Dk, Dv, G = _head_sizes(q, k, v, Hkv)
     Tp = round_up(T, math.lcm(bq, bk))
     nk = Tp // bk
     steps = _steps(tile_plan(rule, T, bq, bk))
     # sum_d o do of every (position, head), as the log-sum-exp is laid out
     dl = jnp.sum((o.astype(jnp.float32) * do.astype(jnp.float32))
-                 .reshape(B, T, Hkv, G, D), axis=-1)
+                 .reshape(B, T, Hkv, G, Dv), axis=-1)
     dl = jnp.pad(jnp.moveaxis(dl, 1, 2), [(0, 0), (0, 0), (0, Tp - T), (0, 0)])
-    spec = _specs(G, D, bq, bk, nk)
+    spec = _specs(G, Dk, Dv, bq, bk, nk)
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, G=G, D=D), name="flash_attn_bwd",
+        functools.partial(_bwd_kernel, G=G, Dk=Dk, Dv=Dv),
+        name="flash_attn_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B, Hkv, len(steps[0])),
-            in_specs=[spec["q"], spec["k"], spec["k"], spec["q"],
+            in_specs=[spec["q"], spec["k"], spec["v"], spec["o"],
                       spec["stat"], spec["stat"], spec["qcode"],
                       spec["qcode"], spec["kcode"]],
-            out_specs=[spec["q"], spec["row"], spec["row"]],
-            scratch_shapes=[pltpu.VMEM((bq, G * D), jnp.float32),
-                            pltpu.VMEM((nk, bk, D), jnp.float32),
-                            pltpu.VMEM((nk, bk, D), jnp.float32)]),
+            out_specs=[spec["q"], spec["dk"], spec["dv"]],
+            scratch_shapes=[pltpu.VMEM((bq, G * Dk), jnp.float32),
+                            pltpu.VMEM((nk, bk, Dk), jnp.float32),
+                            pltpu.VMEM((nk, bk, Dv), jnp.float32)]),
         # dk, dv as [B, key tiles, bk, Hkv * D]: the same bytes as
         # [B, Tp, Hkv * D], and a key tile's rows are one leading index
         out_shape=[jax.ShapeDtypeStruct((B, Tp) + q.shape[2:], q.dtype),
@@ -418,8 +444,9 @@ def _bwd_call(q, k, v, o, lse, do, rule, Hkv, bq, bk, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, rule, Hkv, bq, bk, interpret=False):
-    """q [B, T, H * D] (scaled; head h = n * G + g reads key/value head n),
-    k, v [B, T, Hkv * D] -> o [B, T, H * D], as ``flash_attn_fwd`` /
+    """q [B, T, H * Dk] (scaled; head h = n * G + g reads key/value head n),
+    k [B, T, Hkv * Dk], v [B, T, Hkv * Dv] -> o [B, T, H * Dv], as
+    ``flash_attn_fwd`` /
     ``flash_attn_bwd`` over the kept tiles of ``rule``."""
     return _fwd_call(q, k, v, rule, Hkv, bq, bk, interpret)[0]
 
@@ -440,8 +467,10 @@ def _flash_bwd(rule, Hkv, bq, bk, interpret, res, do):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def kernel_supported(D, dtype):
-    return D % 128 == 0 and dtype in (jnp.bfloat16, jnp.float32)
+def kernel_supported(D, dtype, Dv=None):
+    """A value head of whole lanes (the q / k head goes in padded to them,
+    `attention`), in bf16 or float32."""
+    return (Dv or D) % 128 == 0 and dtype in (jnp.bfloat16, jnp.float32)
 
 
 # what the backward launch may hold of VMEM_LIMIT_BYTES by the estimate
@@ -449,57 +478,76 @@ def kernel_supported(D, dtype):
 _VMEM_BUDGET = VMEM_LIMIT_BYTES * 7 // 8
 
 
-def bwd_vmem_bytes(T, D, G, dtype):
+def bwd_vmem_bytes(T, D, G, dtype, Dv=None):
     """(all, dK/dV) bytes ``flash_attn_bwd`` holds in VMEM over a row of T
-    positions: dK and dV of one key/value head, whole, in float32, and
-    their result blocks; every tile block twice, as the pipeline keeps the
-    next one coming; dQ's accumulator; the body's [bq, bk] float32
-    temporaries (scores, probabilities, dp, ds and their roundings)."""
+    positions at head sizes D (q, k) and Dv (v, o; D where not given): dK
+    and dV of one key/value head, whole, in float32, and their result
+    blocks; every tile block twice, as the pipeline keeps the next one
+    coming; dQ's accumulator; the body's [bq, bk] float32 temporaries
+    (scores, probabilities, dp, ds and their roundings)."""
+    Dv = Dv or D
     bq, bk = tile_sizes(T)
     Tp, item = round_up(T, math.lcm(bq, bk)), jnp.dtype(dtype).itemsize
-    pair = 2 * Tp * D * 4
-    tiles = 2 * (3 * bq * G * D + 2 * bk * D) * item + bq * G * D * 4
+    pair = Tp * (D + Dv) * 4
+    tiles = 2 * (bq * G * (2 * D + Dv) + bk * (D + Dv)) * item \
+        + bq * G * D * 4
     stats = 2 * 2 * bq * round_up(G, 128) * 4
-    return (pair + 2 * 2 * Tp * D * item + tiles + stats + 6 * bq * bk * 4,
-            pair)
+    return (pair + 2 * Tp * (D + Dv) * item + tiles + stats
+            + 6 * bq * bk * 4, pair)
 
 
-def kernel_gate(T, D, G, dtype):
-    """(eligible, why not): the kernels cover head sizes that fill the
-    lanes in bf16 / float32, on rows whose dK / dV fit in VMEM."""
-    if not kernel_supported(D, dtype):
-        return False, (f"head size {D}, {jnp.dtype(dtype).name} is outside "
-                       "the kernel's gate")
-    need = bwd_vmem_bytes(T, D, G, dtype)[0]
+def kernel_gate(T, D, G, dtype, Dv=None):
+    """(eligible, why not): the kernels cover value heads that fill the
+    lanes in bf16 / float32, on rows whose dK / dV (the q / k head padded to
+    whole lanes) fit in VMEM."""
+    if not kernel_supported(D, dtype, Dv):
+        sizes = D if Dv in (None, D) else f"{D} : {Dv}"
+        return False, (f"head size {sizes}, {jnp.dtype(dtype).name} is "
+                       "outside the kernel's gate")
+    need = bwd_vmem_bytes(T, round_up(D, 128), G, dtype, Dv)[0]
     return need <= _VMEM_BUDGET, (
         f"over a row of {T} positions flash_attn_bwd would hold "
         f"{need / 1e6:.1f} MB in VMEM, dK and dV whole, against the "
         f"{_VMEM_BUDGET / 1e6:.1f} MB of the kernel's gate")
 
 
+def _pad_heads(x, D, Dp):
+    """[B, T, heads * D] -> [B, T, heads * Dp], zeros behind every head."""
+    B, T, _ = x.shape
+    x = jnp.pad(x.reshape(B, T, -1, D), [(0, 0)] * 3 + [(0, Dp - D)])
+    return x.reshape(B, T, -1)
+
+
 def attention(who, q, k, v, rule, Hkv):
-    """The layer's call: q [B, T, H * D] (scaled), k, v [B, T, Hkv * D] ->
-    [B, T, H * D], by the Mosaic kernels on the TPU where their gate passes
-    and by the same tiles in XLA elsewhere; the log says once a layer which,
-    how much of the square the rule keeps and what the backward launch
-    holds in VMEM."""
+    """The layer's call: q [B, T, H * Dk] (scaled), k [B, T, Hkv * Dk],
+    v [B, T, Hkv * Dv] -> [B, T, H * Dv], by the Mosaic kernels on the TPU
+    where their gate passes and by the same tiles in XLA elsewhere; the log
+    says once a layer which, how much of the square the rule keeps and what
+    the backward launch holds in VMEM."""
     B, T, _ = q.shape
-    D, G = k.shape[-1] // Hkv, q.shape[-1] // k.shape[-1]
+    Dk, Dv, G = _head_sizes(q, k, v, Hkv)
+    # the kernels read a head as whole lanes: a q / k head that is not
+    # (latent attention's 192) goes in with zeros behind it, which add
+    # nothing to a score
+    Dp = round_up(Dk, 128)
     bq, bk = tile_sizes(T)
     kept, whole, partial, every = plan_counts(tile_plan(rule, T, bq, bk))
     pallas = take_pallas(who, "flash_attn_fwd/bwd",
-                         *kernel_gate(T, D, G, q.dtype),
+                         *kernel_gate(T, Dk, G, q.dtype, Dv),
                          otherwise="the tiles in XLA")
     log_once(who, f"mask {rule}: {kept} of {every} tiles of {bq} x {bk} kept "
              f"({whole} whole, {partial} partial)")
     if pallas:
-        need, pair = bwd_vmem_bytes(T, D, G, q.dtype)
+        need, pair = bwd_vmem_bytes(T, Dp, G, q.dtype, Dv)
         log_once(who, f"flash_attn_bwd: one walk of {kept} tiles, dK/dV "
                  f"{pair / 1e6:.1f} MB of {need / 1e6:.1f} MB in VMEM")
+        if Dp != Dk:
+            log_once(who, f"q and k heads of {Dk} go in as {Dp} lanes")
+            q, k = _pad_heads(q, Dk, Dp), _pad_heads(k, Dk, Dp)
         return call_kernel(
             lambda q, k, v: flash_attention(q, k, v, rule, Hkv, bq, bk),
             (q, k, v), range(3))
-    o = attention_tiles_xla(q.reshape(B, T, Hkv, G, D),
-                            k.reshape(B, T, Hkv, D), v.reshape(B, T, Hkv, D),
+    o = attention_tiles_xla(q.reshape(B, T, Hkv, G, Dk),
+                            k.reshape(B, T, Hkv, Dk), v.reshape(B, T, Hkv, Dv),
                             rule, bq, bk)
-    return o.reshape(q.shape)
+    return o.reshape(B, T, Hkv * G * Dv)

@@ -879,6 +879,31 @@ def gqa_attention(input, num_heads, num_kv_heads, head_dim, mask,
                  param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
 
 
+def mla_attention(input, num_heads, qk_nope_head_dim, qk_rope_head_dim,
+                  v_head_dim, kv_lora_rank, mask, rope_theta=10000.0, eps=1e-6,
+                  scope=None, name=None, param_attr=None, layer_attr=None):
+    """Multi-head latent attention in its training (decompressed) form: keys
+    and values come from a normed low-rank latent of ``kv_lora_rank``; a
+    head's query and key are a part without positions (``qk_nope_head_dim``)
+    beside a rotary part (``qk_rope_head_dim``) whose key all heads share;
+    the value head is ``v_head_dim``. No query latent, no bias.
+    ``mask=("causal", L)`` for rows of L positions."""
+    return Layer("mla_attention", [input], name=name, num_heads=num_heads,
+                 qk_nope_head_dim=qk_nope_head_dim,
+                 qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+                 kv_lora_rank=kv_lora_rank, rope_theta=rope_theta, eps=eps,
+                 mask=tuple(mask), scope=scope,
+                 param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
+
+
+def gated_mlp(input, size, scope=None, name=None, param_attr=None,
+              layer_attr=None):
+    """(silu(x Wg) * (x Wu)) Wd of width ``size``, no bias: a decoder's
+    dense feed-forward layer."""
+    return Layer("gated_mlp", [input], name=name, width=size, scope=scope,
+                 param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
+
+
 def block_diffusion_noise(ids, v, t, block, mask_id, scope=None, name=None):
     """(the 2L ids [xt ; x0], the loss weight of each of the L noised
     positions) of a block-diffusion training row: token i of ``ids`` is
@@ -897,21 +922,31 @@ def noised_half(input, name=None):
 
 
 def moe_ffn(input, num_experts, top_k, expert_size, shared_size=None,
-            experts_held=None, first_expert=0, tile=256, scope=None, name=None,
-            param_attr=None, layer_attr=None):
-    """Top-k mixture of gated-MLP experts, with a gated shared expert of
-    ``shared_size`` where one is given. The router is over all
-    ``num_experts``; the layer holds (and computes) the experts
-    [first_expert, first_expert + experts_held) only."""
+            experts_held=None, first_expert=0, tile=256, score="softmax",
+            selection_bias=False, bias_rate=1e-3, route_scale=1.0,
+            shared_gate=True, scope=None, name=None, param_attr=None,
+            layer_attr=None):
+    """Top-k mixture of gated-MLP experts, with a shared expert of
+    ``shared_size`` where one is given (behind a sigmoid gate unless
+    ``shared_gate=False``). The router is over all ``num_experts``, by
+    ``score`` ("softmax" or "sigmoid"); the layer holds (and computes) the
+    experts [first_expert, first_expert + experts_held) only. With
+    ``selection_bias`` the top k are chosen by score + bias, the weights
+    staying the scores' own; the bias is state that moves by ``bias_rate``
+    against each expert's load after every step. The renormalised weights
+    are scaled by ``route_scale``."""
     return Layer("moe_ffn", [input], name=name, num_experts=num_experts,
                  top_k=top_k, expert_size=expert_size, shared_size=shared_size,
                  experts_held=experts_held or num_experts,
-                 first_expert=first_expert, tile=tile, scope=scope,
+                 first_expert=first_expert, tile=tile, score=score,
+                 selection_bias=selection_bias, bias_rate=bias_rate,
+                 route_scale=route_scale, shared_gate=shared_gate, scope=scope,
                  param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
 
 
 __all__ += ["rms_norm", "gated_attention", "gated_delta_net", "moe_ffn",
-            "gqa_attention", "block_diffusion_noise", "noised_half"]
+            "gqa_attention", "block_diffusion_noise", "noised_half",
+            "mla_attention", "gated_mlp"]
 
 
 # --- detection (SSD) ------------------------------------------------------

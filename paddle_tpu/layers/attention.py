@@ -294,3 +294,76 @@ def _gqa_attention_forward(cfg, params, ins, ctx):
         out = rows_one_at_a_time(mixer, x, params,
                                  kept=("flash_attn_o", "flash_attn_lse"))
     return ins[0].with_value(out)
+
+
+# --- multi-head latent attention, training form -------------------------------
+
+def _mla_params(cfg, in_infos):
+    d, H, r = in_infos[0].size, cfg.attr("num_heads"), cfg.attr("kv_lora_rank")
+    Dn, Dr, Dv = (cfg.attr("qk_nope_head_dim"), cfg.attr("qk_rope_head_dim"),
+                  cfg.attr("v_head_dim"))
+    a = cfg.param_attr(0)
+    return {
+        "wq": ParamSpec((d, H * (Dn + Dr)), a, fan_in=d),
+        "wkva": ParamSpec((d, r + Dr), a, fan_in=d),
+        "kv_norm": ParamSpec((r,), const_init(a, 1.0), fan_in=r),
+        "wkvb": ParamSpec((r, H * (Dn + Dv)), a, fan_in=r),
+        "wo": ParamSpec((H * Dv, d), a, fan_in=H * Dv),
+    }
+
+
+@register_layer("mla_attention", params=_mla_params)
+def _mla_attention_forward(cfg, params, ins, ctx):
+    """Multi-head latent attention as DeepSeek-V3-style decoders train it
+    (docs/kimi_vl.md), the decompressed form:
+
+        q = x Wq -> [T, H, Dn + Dr] = [q_nope ; q_rope]      (no query latent)
+        [c ; k_r] = x Wkva -> c [T, r], k_r [T, Dr]
+        [k_nope ; v] = rms_norm(c; w_kv) Wkvb -> [T, H, Dn + Dv]
+        rotate-half rotary on q_rope and on k_r, which ALL heads share
+        k_h = [k_nope_h ; k_r];  o_h = softmax(q_h k_h^T / sqrt(Dn + Dr)) v_h
+        out = concat(o_h) Wo
+
+    under the mask's rule, tile by tile where it keeps something
+    (kernels/flash_attn.py, head sizes Dn + Dr : Dv). No bias. The absorbed
+    form over a latent cache is generation's, which this layer does not
+    have."""
+    from paddle_tpu.kernels import flash_attn
+
+    enforce(not getattr(ctx, "packed", False),
+            f"mla_attention {cfg.name}: packed rows need a segment rule "
+            "beside the mask's, which this layer does not have")
+    x = ins[0].value
+    B, T, _ = x.shape
+    H, r = cfg.attr("num_heads"), cfg.attr("kv_lora_rank")
+    Dn, Dr, Dv = (cfg.attr("qk_nope_head_dim"), cfg.attr("qk_rope_head_dim"),
+                  cfg.attr("v_head_dim"))
+    eps, theta, rule = cfg.attr("eps", 1e-6), cfg.attr("rope_theta"), cfg.attr("mask")
+    if rule == ("causal", None):         # rows of any one length
+        rule = ("causal", T)
+    pos = flash_attn.positions(rule, T)
+    f32 = jnp.promote_types(x.dtype, jnp.float32)
+
+    def mixer(x, p):
+        """One row [T, d]."""
+        # 1 / sqrt(Dn + Dr) goes into q before its one rounding
+        q = (jnp.matmul(x, p["wq"], preferred_element_type=f32)
+             * (Dn + Dr) ** -0.5).astype(x.dtype).reshape(1, T, H, Dn + Dr)
+        ckr = jnp.matmul(x, p["wkva"])
+        kv = jnp.matmul(_head_norm(ckr[:, :r], p["kv_norm"], eps),
+                        p["wkvb"]).reshape(1, T, H, Dn + Dv)
+        k_r = rotary_at(ckr[:, r:].reshape(1, T, 1, Dr), pos, theta)
+        q = jnp.concatenate(
+            [q[..., :Dn], rotary_at(q[..., Dn:], pos, theta)], -1)
+        k = jnp.concatenate(
+            [kv[..., :Dn], jnp.broadcast_to(k_r, (1, T, H, Dr))], -1)
+        o = flash_attn.attention(
+            cfg.name, q.reshape(1, T, H * (Dn + Dr)),
+            k.reshape(1, T, H * (Dn + Dr)),
+            kv[..., Dn:].reshape(1, T, H * Dv), rule, H)
+        return jnp.matmul(o[0], p["wo"])
+
+    with jax.named_scope(cfg.attr("scope") or cfg.name):
+        out = rows_one_at_a_time(mixer, x, params,
+                                 kept=("flash_attn_o", "flash_attn_lse"))
+    return ins[0].with_value(out)

@@ -1,5 +1,5 @@
 """Sparse mixture of experts, as one chip's share of an expert-parallel
-layer (docs/qwen3_next.md).
+layer (docs/qwen3_next.md), and the plain gated MLP an expert is.
 
     p = softmax(x Wr) over ALL num_experts (float32); the top k of p,
     renormalised to sum 1; routed = sum over the chosen experts e THAT THIS
@@ -8,6 +8,16 @@ layer (docs/qwen3_next.md).
     shared = sigmoid(x w_sg) * (silu(x Wg_s) * (x Wu_s)) Wd_s;
     out = routed + shared; a layer built without a shared size has no
     shared expert: no ``shared_*`` leaf, no shared term
+
+With ``score="sigmoid"`` p = sigmoid(x Wr); with ``selection_bias`` the top k
+are chosen by p + b while the weights stay the chosen p, renormalised and
+scaled by ``route_scale``; ``shared_gate=False`` leaves the shared expert's
+gate out (docs/kimi_vl.md). b is state, not a parameter: no gradient reaches
+it (it only decides an index), it starts at 0, and after every step
+b_e += bias_rate * sign(mean(c) - c_e) from the step's own counts c_e of
+(real token, chosen expert) pairs over all experts. It travels as batch
+norm's moving statistics do: an ``is_static`` leaf whose new value goes under
+``ctx.extras["batch_stats"]``.
 
 What the absent experts would add is left out: the other shares of the layer
 hold them, and on one chip nothing stands in for the exchange that would
@@ -42,9 +52,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.attr import ParamAttr
 from paddle_tpu.core.layer import (ParamSpec, register_layer,
                                    register_step_stats)
 from paddle_tpu.observability import metrics as obs_metrics
+from paddle_tpu.utils.error import enforce
 
 STATS = ("held", "elsewhere", "load_max_over_mean", "dropped")
 
@@ -62,16 +74,24 @@ _M_DROPPED = obs_metrics.counter(
     "paddle_moe_dropped_total",
     "Held (token, expert) pairs the dispatch buffer had no row for; the "
     "buffer is sized for the worst case, so this stays 0")
+_M_BIAS = obs_metrics.gauge(
+    "paddle_moe_selection_bias_max_abs",
+    "Largest magnitude of a moe_ffn layer's selection bias after the last "
+    "drained step's update (layers with selection_bias only)",
+    labels=("layer",))
 
 
 @register_step_stats("moe_ffn")
 def _publish_stats(lname, vec):
-    """One drained step's STATS vector of one layer into the counters."""
-    held, elsewhere, load, dropped = (float(v) for v in vec)
+    """One drained step's STATS vector of one layer into the counters; a
+    layer with a selection bias appends its largest magnitude."""
+    held, elsewhere, load, dropped = (float(v) for v in vec[:len(STATS)])
     _M_TOKENS.labels(layer=lname, result="held").inc(held)
     _M_TOKENS.labels(layer=lname, result="elsewhere").inc(elsewhere)
     _M_LOAD.labels(layer=lname).set(load)
     _M_DROPPED.inc(dropped)
+    if len(vec) > len(STATS):
+        _M_BIAS.labels(layer=lname).set(float(vec[len(STATS)]))
 
 
 def _moe_params(cfg, in_infos):
@@ -86,13 +106,30 @@ def _moe_params(cfg, in_infos):
         "wd": ParamSpec((held, I, d), a, fan_in=I),
     }
     if Is is not None:
-        specs.update({
-            "shared_gate": ParamSpec((d, 1), a, fan_in=d),
-            "shared_wg": ParamSpec((d, Is), a, fan_in=d),
-            "shared_wu": ParamSpec((d, Is), a, fan_in=d),
-            "shared_wd": ParamSpec((Is, d), a, fan_in=Is),
-        })
+        if cfg.attr("shared_gate", True):
+            specs["shared_gate"] = ParamSpec((d, 1), a, fan_in=d)
+        specs.update(_mlp_specs(d, Is, a, "shared_"))
+    if cfg.attr("selection_bias", False):
+        # state that the layer's own rule moves; excluded from gradient
+        # updates by the trainer, as batch norm's moving statistics are
+        specs["bias"] = ParamSpec(
+            (E,), ParamAttr(initial_strategy="zero", is_static=True), fan_in=E)
     return specs
+
+
+def _mlp_specs(d, width, a, prefix=""):
+    return {prefix + "wg": ParamSpec((d, width), a, fan_in=d),
+            prefix + "wu": ParamSpec((d, width), a, fan_in=d),
+            prefix + "wd": ParamSpec((width, d), a, fan_in=width)}
+
+
+def _gated_hidden(x, wg, wu):
+    return jax.nn.silu(jnp.matmul(x, wg)) * jnp.matmul(x, wu)
+
+
+def _gated_mlp(x, wg, wu, wd):
+    """(silu(x Wg) * (x Wu)) Wd."""
+    return jnp.matmul(_gated_hidden(x, wg, wu), wd)
 
 
 def _acc(dtype):
@@ -211,11 +248,41 @@ def dispatch_plan(idx, top, valid, first, held, tile):
     return row_w, row_tok, tile_expert, tile_end[-1], stats
 
 
+def route(x, p, cfg, valid):
+    """(idx, top [N, k], new bias or None): the chosen experts of every
+    token of x [N, d], their weights, and what the selection bias becomes
+    after this step."""
+    k, acc, score = cfg.attr("top_k"), _acc(x.dtype), cfg.attr("score", "softmax")
+    enforce(score in ("softmax", "sigmoid"),
+            f"moe_ffn {cfg.name}: score {score!r} is neither softmax nor sigmoid")
+    logits = jnp.matmul(x, p["router"]).astype(acc)
+    probs = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    if "bias" in p:
+        # the bias decides WHICH experts; the weights are the scores' own
+        idx = jax.lax.top_k(probs + p["bias"].astype(acc), k)[1]
+        top = jnp.take_along_axis(probs, idx, axis=-1)
+    else:
+        top, idx = jax.lax.top_k(probs, k)
+    top = top / jnp.sum(top, -1, keepdims=True)
+    scale = cfg.attr("route_scale", 1.0)
+    if scale != 1.0:
+        top = top * scale
+    if "bias" not in p:
+        return idx, top, None
+    hit = (idx[..., None] == jnp.arange(p["bias"].shape[0])) \
+        & valid[:, None, None]
+    counts = jnp.sum(hit, axis=(0, 1), dtype=jnp.float32)
+    bias = p["bias"] + cfg.attr("bias_rate", 1e-3) * jnp.sign(
+        jnp.mean(counts) - counts).astype(p["bias"].dtype)
+    return idx, top, bias
+
+
 @register_layer("moe_ffn", params=_moe_params)
 def _moe_ffn_forward(cfg, params, ins, ctx):
     x_in = ins[0].value
     d = x_in.shape[-1]
-    k, held = cfg.attr("top_k"), cfg.attr("experts_held")
+    held = cfg.attr("experts_held")
     first, tile = cfg.attr("first_expert", 0), cfg.attr("tile", 256)
     valid = jnp.ones(x_in.shape[:-1], bool) if ins[0].mask is None \
         else ins[0].mask > 0
@@ -223,23 +290,49 @@ def _moe_ffn_forward(cfg, params, ins, ctx):
     def moe(x, valid, p):
         x = x.reshape(-1, d)
         acc = _acc(x.dtype)
-        probs = jax.nn.softmax(jnp.matmul(x, p["router"]).astype(acc), axis=-1)
-        top, idx = jax.lax.top_k(probs, k)
-        top = top / jnp.sum(top, -1, keepdims=True)
+        idx, top, bias = route(x, p, cfg, valid.reshape(-1))
         row_w, row_tok, tile_expert, n_tiles, stats = dispatch_plan(
             idx, top, valid.reshape(-1), first, held, tile)
-        routed = grouped_ffn(x, p["wg"], p["wu"], p["wd"], row_w, row_tok,
-                             tile_expert, n_tiles, tile)
-        if "shared_gate" not in p:
-            return routed.reshape(x_in.shape), stats
-        gate = jax.nn.sigmoid(jnp.matmul(x, p["shared_gate"]).astype(acc))
-        h = jax.nn.silu(jnp.matmul(x, p["shared_wg"])) \
-            * jnp.matmul(x, p["shared_wu"])
-        shared = gate.astype(x.dtype) * jnp.matmul(h, p["shared_wd"])
-        return (routed + shared).reshape(x_in.shape), stats
+        out = grouped_ffn(x, p["wg"], p["wu"], p["wd"], row_w, row_tok,
+                          tile_expert, n_tiles, tile)
+        if bias is not None:
+            stats = jnp.concatenate([stats, jnp.max(jnp.abs(bias))[None]])
+        if "shared_wg" in p:
+            # two spellings of one MLP: the gated one keeps the order of
+            # operations the Qwen3-Next cell's step was lowered with
+            if "shared_gate" in p:
+                gate = jax.nn.sigmoid(
+                    jnp.matmul(x, p["shared_gate"]).astype(acc))
+                h = _gated_hidden(x, p["shared_wg"], p["shared_wu"])
+                shared = gate.astype(x.dtype) * jnp.matmul(h, p["shared_wd"])
+            else:
+                shared = _gated_mlp(x, p["shared_wg"], p["shared_wu"],
+                                    p["shared_wd"])
+            out = out + shared
+        return out.reshape(x_in.shape), stats, bias
 
     with jax.named_scope(cfg.attr("scope") or cfg.name):
-        out, stats = jax.checkpoint(moe)(x_in, valid, params)
+        out, stats, bias = jax.checkpoint(moe)(x_in, valid, params)
     ctx.extras.setdefault("step_stats", {}).setdefault(
         "moe_ffn", {})[cfg.name] = jax.lax.stop_gradient(stats)
+    if bias is not None and ctx.training:
+        ctx.extras.setdefault("batch_stats", {})[cfg.name] = {
+            "bias": jax.lax.stop_gradient(bias)}
+    return ins[0].with_value(out)
+
+
+# --- the plain gated MLP ------------------------------------------------------
+
+def _gated_mlp_params(cfg, in_infos):
+    return _mlp_specs(in_infos[0].size, cfg.attr("width"), cfg.param_attr(0))
+
+
+@register_layer("gated_mlp", params=_gated_mlp_params)
+def _gated_mlp_forward(cfg, params, ins, ctx):
+    """(silu(x Wg) * (x Wu)) Wd of width ``width``, no bias: a decoder's dense
+    feed-forward layer. Its two wide activations are computed again in the
+    backward pass, as an expert's are."""
+    with jax.named_scope(cfg.attr("scope") or cfg.name):
+        out = jax.checkpoint(_gated_mlp)(
+            ins[0].value, params["wg"], params["wu"], params["wd"])
     return ins[0].with_value(out)

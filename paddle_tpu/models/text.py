@@ -378,3 +378,65 @@ def sdar_lm_cost(vocab_size=151936, hidden_size=2048, num_hidden_layers=48,
                      bias_attr=False, name=f"{name}_head")
     return layer.classification_cost(
         input=probs, label=ids, weight=weights, name="cost")
+
+
+def kimi_vl_lm_cost(vocab_size=163840, hidden_size=2048,
+                    intermediate_size=11264, moe_intermediate_size=1408,
+                    num_hidden_layers=27, num_attention_heads=16,
+                    n_shared_experts=2, n_routed_experts=64,
+                    routed_scaling_factor=2.446, kv_lora_rank=512,
+                    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                    num_experts_per_tok=6, first_k_dense_replace=1,
+                    rope_theta=800000, rms_norm_eps=1e-5, experts_held=None,
+                    first_expert=0, bias_update_rate=1e-3, seq_len=None,
+                    name="k"):
+    """Next-token cost of Kimi-VL's language model, a DeepSeek-V3-style
+    decoder (docs/kimi_vl.md): blocks of ``h = x + attn(norm(x)); y = h +
+    ffn(norm(h))`` with multi-head latent attention, a dense gated MLP in
+    the first ``first_k_dense_replace`` blocks and, in every later one, a
+    sigmoid-routed MoE chosen through a selection bias that a rule (not a
+    gradient) keeps level, with ``n_shared_experts`` ungated shared experts
+    as one MLP; a final norm and an untied head. Every MoE routes over all
+    ``n_routed_experts`` and holds ``experts_held`` of them from
+    ``first_expert`` on (all by default). Rows are ``seq_len`` positions
+    where given (any one length otherwise). Feeds: ids / next_ids integer
+    sequences."""
+    ids = layer.data(name="ids",
+                     type=data_type.integer_value_sequence(vocab_size))
+    nxt = layer.data(name="next_ids",
+                     type=data_type.integer_value_sequence(vocab_size))
+    x = layer.embedding(input=ids, size=hidden_size, name=f"{name}_emb")
+    for l in range(num_hidden_layers):
+        b = f"{name}_l{l}"
+        normed = layer.rms_norm(input=x, eps=rms_norm_eps, zero_centered=False,
+                                name=f"{b}_in_norm")
+        mixed = layer.mla_attention(
+            input=normed, num_heads=num_attention_heads,
+            qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            kv_lora_rank=kv_lora_rank, rope_theta=rope_theta, eps=rms_norm_eps,
+            mask=("causal", seq_len), scope=f"kimivl/l{l}/attn",
+            name=f"{b}_attn")
+        h = layer.addto(input=[x, mixed], act=act.Linear(), bias_attr=False,
+                        name=f"{b}_h")
+        normed = layer.rms_norm(input=h, eps=rms_norm_eps, zero_centered=False,
+                                name=f"{b}_post_norm")
+        if l < first_k_dense_replace:
+            ffn = layer.gated_mlp(input=normed, size=intermediate_size,
+                                  scope=f"kimivl/l{l}/mlp", name=f"{b}_mlp")
+        else:
+            ffn = layer.moe_ffn(
+                input=normed, num_experts=n_routed_experts,
+                top_k=num_experts_per_tok, expert_size=moe_intermediate_size,
+                shared_size=n_shared_experts * moe_intermediate_size,
+                experts_held=experts_held, first_expert=first_expert,
+                score="sigmoid", selection_bias=True,
+                bias_rate=bias_update_rate, route_scale=routed_scaling_factor,
+                shared_gate=False, scope=f"kimivl/l{l}/moe", name=f"{b}_moe")
+        x = layer.addto(input=[h, ffn], act=act.Linear(), bias_attr=False,
+                        name=f"{b}_out")
+    x = layer.rms_norm(input=x, eps=rms_norm_eps, zero_centered=False,
+                       name=f"{name}_final_norm")
+    probs = layer.fc(input=x, size=vocab_size, act=act.Softmax(),
+                     bias_attr=False, name=f"{name}_head")
+    return layer.classification_cost(input=probs, label=nxt, name="cost")

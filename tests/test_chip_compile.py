@@ -269,6 +269,49 @@ def test_sdar_attention_train_compiles_at_the_cells_size(one_chip, dtype,
     assert sum("flash_attn_bwd" in x for x in names) == 1, names
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kimi_vl_attention_train_compiles_at_the_cells_size(one_chip, dtype,
+                                                            monkeypatch):
+    """value_and_grad of one latent-attention layer as the Kimi-VL cell runs
+    it (2 rows x 8192 positions x 2048, 16 heads of 192 : 128 that go in as
+    256 : 128, the causal mask, one row at a time): one forward and one
+    backward launch, by their names, taken by Mosaic at unequal head
+    sizes."""
+    from paddle_tpu import data_type, layer
+    from paddle_tpu.core.arg import Arg
+    from paddle_tpu.core.topology import Topology
+    from paddle_tpu.kernels import flash_attn
+
+    monkeypatch.setattr(flash_attn, "take_pallas",
+                        lambda who, kernel, eligible=True, why_not="", **kw:
+                        eligible)
+    B, L, d = 2, 8192, 2048
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
+    out = layer.mla_attention(
+        input=x, num_heads=16, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, kv_lora_rank=512, rope_theta=800000, eps=1e-5,
+        mask=("causal", L), name="l")
+    topo = Topology(out)
+    params = {k: _sds(s.shape, dtype, one_chip)
+              for k, s in topo.param_specs().items()}
+
+    def loss(params, x):
+        y = topo.forward(params, {"x": Arg(x, jnp.ones((B, L)))},
+                         training=True)["l"].value
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled, n = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params,
+                           _sds((B, L, d), dtype, one_chip))
+    names = _mosaic_instructions(compiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"mla_attention {jnp.dtype(dtype).name}: {temp:,} bytes of temporaries")
+    assert n == 2 and len(names) == 2, names
+    assert sum("flash_attn_fwd" in x for x in names) == 1, names
+    assert sum("flash_attn_bwd" in x for x in names) == 1, names
+    # the launches take heads of 256 lanes: 16 x 256 a position
+    assert f"[1,{L},4096]" in compiled.as_text()
+
+
 # the LSTM classifier (B64/H512/T100) and the split backward past the
 # in-kernel-dW VMEM gate (H1280)
 @pytest.mark.parametrize("B,H,T,dtype,precision", [

@@ -793,6 +793,22 @@ def _b_gqa_attention():
             {"x": _seq(6, 6, ragged=False)})
 
 
+@build("mla_attention")
+def _b_mla_attention():
+    # query/key heads of 4 + 2 beside value heads of 3, a latent of 5
+    x = _data_seq("x", 6)
+    return (layer.mla_attention(input=x, num_heads=2, qk_nope_head_dim=4,
+                                qk_rope_head_dim=2, v_head_dim=3,
+                                kv_lora_rank=5, mask=("causal", None)),
+            {"x": _seq(5, 6, ragged=False)})
+
+
+@build("gated_mlp")
+def _b_gated_mlp():
+    x = _data_seq("x", 6)
+    return layer.gated_mlp(input=x, size=5), {"x": _seq(4, 6)}
+
+
 @build("noised_half")
 def _b_noised_half():
     x = _data_seq("x", 6)

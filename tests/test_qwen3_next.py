@@ -546,6 +546,36 @@ def test_four_shares_and_one_shared_make_the_uncut_layer(ref):
     _close(total, want)
 
 
+# sha256 of the lowered layer below at the parent commit of PR 34 (b3f4740),
+# by this test's own code: `moe_ffn` learnt Kimi-VL's router there (a sigmoid
+# score, a selection bias, a weight scale, a shared expert without its gate)
+QWEN_MOE_SHA256 = "de18d10babdaf0e445b3eb5c5a45300f925464365a6cdf24a0ff6e43aa8375f8"
+
+
+def test_the_softmax_routed_gated_shared_layer_lowers_as_it_did():
+    """The Qwen3-Next cell's `moe_ffn` (softmax router, no bias, a gated
+    shared expert), forward and every gradient: the text it lowered to
+    before the layer had another router."""
+    import hashlib
+
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(16))
+    out = layer.moe_ffn(input=x, num_experts=8, top_k=3, expert_size=12,
+                        shared_size=12, experts_held=4, first_expert=2, tile=8,
+                        name="l")
+    topo = Topology(out)
+    params = topo.init_params(jax.random.PRNGKey(0))
+    assert "_l.bias" not in params and "_l.shared_gate" in params
+
+    def loss(params, xs):
+        y = topo.forward(params, {"x": Arg(xs, jnp.ones((2, 24)))},
+                         training=True)["l"].value
+        return jnp.sum(y ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, jnp.zeros((2, 24, 16))).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == QWEN_MOE_SHA256
+
+
 def test_moe_counts_held_and_elsewhere_and_skips_padding():
     d, T = 16, 12
     inp = layer.data(name="x", type=data_type.dense_vector_sequence(d))

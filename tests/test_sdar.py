@@ -408,8 +408,10 @@ QWEN_STEP_SHA256 = "fb70acdce71ac225c9b1729811cdd8e745c6d45eec8770cfb2f117f502c9
 
 def test_the_qwen3_next_step_lowers_as_it_did():
     """`moe_ffn` gained an optional shared expert, `rms_norm` a second form
-    and `classification_cost` a weight: a model that uses none of the three
-    lowers to the text it lowered to before them."""
+    and `classification_cost` a weight (PR 32), then a sigmoid score, a
+    selection bias, a weight scale and a shared expert without its gate
+    (PR 34): a model that uses none of them lowers to the text it lowered
+    to before them."""
     from paddle_tpu.trainer.trainer import make_train_step
 
     topo = Topology(qwen3_next_lm_cost(**QWEN_ARGS))
@@ -423,6 +425,47 @@ def test_the_qwen3_next_step_lowers_as_it_did():
     text = jax.jit(step).lower(params, opt.init(params), jax.random.PRNGKey(1),
                                feeds).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == QWEN_STEP_SHA256
+
+
+# sha256 of this cell's own step, and of the kernels' launches as traced, at
+# the parent commit of PR 34 (b3f4740), by the two tests' own code: `moe_ffn`
+# and `flash_attn` changed for Kimi-VL (docs/kimi_vl.md), the SDAR cell's
+# step and launches must not
+SDAR_STEP_SHA256 = "7a48516011d524ae0117de7c1e48ed3207edc27c2f3308c023e31b02a38d5a80"
+SDAR_LAUNCHES_SHA256 = "ff794b75612227160d059cd2d1c96ef506f65c0c410a28e0780a56f12f3edc92"
+
+
+def test_the_sdar_step_lowers_as_it_did():
+    from paddle_tpu.trainer.trainer import make_train_step
+
+    topo = Topology(sdar_lm_cost(**ARGS))
+    params = topo.init_params(jax.random.PRNGKey(0))
+    opt = paddle.optimizer.Adam(learning_rate=3e-4)
+    step = make_train_step(topo.loss_fn(compute_dtype=jnp.bfloat16), opt,
+                           topo.static_map())
+    ids = jnp.zeros((2, 20), jnp.int32)
+    feeds = {"ids": Arg(ids, jnp.ones((2, 20))),
+             "mask_u": Arg(jnp.zeros((2, 20))),
+             "noise_t": Arg(jnp.zeros((2, 5)))}
+    text = jax.jit(step).lower(params, opt.init(params), jax.random.PRNGKey(1),
+                               feeds).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == SDAR_STEP_SHA256
+
+
+def test_the_sdar_launches_trace_as_they_did():
+    """`flash_attn_fwd` / `flash_attn_bwd` at equal head sizes under the
+    block-diffusion rule, with grouped heads: grid, block specs, scratch and
+    both bodies, as `jax.make_jaxpr` prints them."""
+    rule = ("block_diffusion", 256, 4)
+
+    def f(q, k, v):
+        o = flash_attn.flash_attention(q, k, v, rule, 2, 128, 128)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    q = jax.ShapeDtypeStruct((1, 512, 4 * 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 512, 2 * 128), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, k, k))
+    assert hashlib.sha256(text.encode()).hexdigest() == SDAR_LAUNCHES_SHA256
 
 
 # ---- (d) the shares add up --------------------------------------------------------

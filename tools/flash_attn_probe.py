@@ -1,9 +1,11 @@
 """python3 tools/flash_attn_probe.py [TILE ...]
 (on the chip: chiprun -- python3 tools/flash_attn_probe.py 256 512)
 
-Times the block-diffusion attention kernels alone at the SDAR cell's size
-(one layer: 2 rows x 2 x 8192 positions, 32 : 4 heads of 128, block 4,
-bf16): flash_attn_fwd and the pair forward + flash_attn_bwd, with the tile
+Times the attention kernels alone at a cell's size (one layer, bf16; PROBE_CELL
+names the cell): `sdar`, the default: 2 rows x 2 x 8192 positions, 32 : 4 heads
+of 128 under the block-diffusion mask in blocks of 4; `kimivl`: 2 rows x 8192
+positions, 16 : 16 heads of 192 : 128 under the causal mask, the q / k heads
+padded to 256 lanes as `attention` pads them. Timed: flash_attn_fwd and the pair forward + flash_attn_bwd, with the tile
 paddle_tpu/kernels/flash_attn.py `tile_sizes` chooses and with every tile
 named on the command line, and checks the kernels' result and gradients
 against the same tiles in XLA at a row short enough for them
@@ -31,7 +33,13 @@ import jax.numpy as jnp  # noqa: E402
 
 from paddle_tpu.kernels import flash_attn as fa  # noqa: E402
 
-Hkv, G, D, BLOCK = 4, 8, 128, 4
+BLOCK = 4
+CELL = os.environ.get("PROBE_CELL", "sdar")
+# per cell: key/value heads, query heads a key/value head, the q / k head as
+# published and as it goes in, the value head, positions a row of L tokens
+Hkv, G, D_PUB, D, Dv, ROW = {
+    "sdar": (4, 8, 128, 128, 128, 2),
+    "kimivl": (16, 1, 192, 256, 128, 1)}[CELL]
 B = int(os.environ.get("PROBE_ROWS", 2))
 L = int(os.environ.get("PROBE_TOKENS", 8192))
 L_CHECK = int(os.environ.get("PROBE_CHECK_TOKENS", 1024))
@@ -39,21 +47,33 @@ INTERPRET = jax.default_backend() != "tpu"
 PEAK = 197e12
 
 
+def mask_rule(L):
+    return ("causal", L) if CELL == "kimivl" else ("block_diffusion", L, BLOCK)
+
+
 def need():
+    """(forward, backward) (FLOPs, bytes) of the benchmark's count."""
+    name = "mla_attn" if CELL == "kimivl" else "flash_attn"
     spec = importlib.util.spec_from_file_location(
-        "need", os.path.join(ROOT, "benchmark", "kernels", "flash_attn.py"))
+        "need", os.path.join(ROOT, "benchmark", "kernels", name + ".py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod
+    dims = (B, L, Hkv * G, 128, 64, 128, 2) if CELL == "kimivl" \
+        else (B, L, BLOCK, Hkv * G, Hkv, D, 2)
+    return mod.forward(*dims), mod.backward(*dims)
 
 
 def inputs(L, dtype=jnp.bfloat16, seed=0):
+    """q, k with their published head size, zeros behind it where the probe
+    pads; v, do with the value head's."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    T = 2 * L
-    q = jax.random.normal(ks[0], (B, T, Hkv * G * D)) * D ** -0.5
-    k = jax.random.normal(ks[1], (B, T, Hkv * D))
-    v = jax.random.normal(ks[2], (B, T, Hkv * D))
-    do = jax.random.normal(ks[3], (B, T, Hkv * G * D))
+    T = ROW * L
+    q = jax.random.normal(ks[0], (B, T, Hkv * G * D_PUB)) * D_PUB ** -0.5
+    k = jax.random.normal(ks[1], (B, T, Hkv * D_PUB))
+    v = jax.random.normal(ks[2], (B, T, Hkv * Dv))
+    do = jax.random.normal(ks[3], (B, T, Hkv * G * Dv))
+    if D != D_PUB:
+        q, k = fa._pad_heads(q, D_PUB, D), fa._pad_heads(k, D_PUB, D)
     return tuple(x.astype(dtype) for x in (q, k, v, do))
 
 
@@ -93,7 +113,7 @@ def products_a_tile(tile):
     one for partial ones; a step runs one of them)."""
     q, k, v, do = (jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in inputs(L_CHECK))
-    rule = ("block_diffusion", L_CHECK, BLOCK)
+    rule = mask_rule(L_CHECK)
     jaxpr = jax.make_jaxpr(lambda q, k, v, do: jax.vjp(
         lambda *a: fa.flash_attention(*a, rule, Hkv, tile, tile, INTERPRET),
         q, k, v)[1](do))(q, k, v, do)
@@ -102,15 +122,15 @@ def products_a_tile(tile):
 
 
 def check(tile):
-    rule = ("block_diffusion", L_CHECK, BLOCK)
+    rule = mask_rule(L_CHECK)
     q, k, v, do = inputs(L_CHECK)
-    T = 2 * L_CHECK
+    T = ROW * L_CHECK
 
     def xla(q, k, v):
         o = fa.attention_tiles_xla(
             q.reshape(B, T, Hkv, G, D), k.reshape(B, T, Hkv, D),
-            v.reshape(B, T, Hkv, D), rule, tile, tile)
-        return o.reshape(q.shape)
+            v.reshape(B, T, Hkv, Dv), rule, tile, tile)
+        return o.reshape(do.shape)
 
     def kernels(q, k, v):
         return fa.flash_attention(q, k, v, rule, Hkv, tile, tile, INTERPRET)
@@ -125,12 +145,10 @@ def check(tile):
 
 
 def main():
-    tiles = [int(t) for t in sys.argv[1:]] or [fa.tile_sizes(2 * L)[0]]
-    rule = ("block_diffusion", L, BLOCK)
+    tiles = [int(t) for t in sys.argv[1:]] or [fa.tile_sizes(ROW * L)[0]]
+    rule = mask_rule(L)
     q, k, v, do = inputs(L)
-    count = need()
-    f_need = count.forward(B, L, BLOCK, Hkv * G, Hkv, D, 2)
-    b_need = count.backward(B, L, BLOCK, Hkv * G, Hkv, D, 2)
+    f_need, b_need = need()
     for tile in tiles:
         fwd = jax.jit(lambda q, k, v: fa.flash_attention(
             q, k, v, rule, Hkv, tile, tile, INTERPRET))
@@ -144,20 +162,23 @@ def main():
             print(json.dumps({"tile": tile, "refused": str(e)[-400:]}),
                   flush=True)
             continue
-        kept = fa.plan_counts(fa.tile_plan(rule, 2 * L, tile, tile))
+        kept = fa.plan_counts(fa.tile_plan(rule, ROW * L, tile, tile))
         n_f, n_b = products_a_tile(tile)
-        # a product of a kept tile: [tile, D] x [D, tile], every head and row
-        tile_flops = 2 * tile * tile * D * Hkv * G * B * kept[0]
+        # a kept tile's products, every head and row: the forward's two are
+        # D and Dv wide, the backward's five three times D and twice Dv
+        per_width = 2 * tile * tile * Hkv * G * B * kept[0]
+        f_flops = per_width * (D + Dv) * n_f / 2
+        b_flops = per_width * (3 * D + 2 * Dv) * n_b / 5
         print(json.dumps({
+            "cell": CELL, "head_q_k_v": [D_PUB, D, Dv],
             "tile": tile, "tiles_kept_whole_partial_all": kept,
             "fwd_ms": 1e3 * t_f, "bwd_ms": 1e3 * t_b,
             "fwd_share_of_need": f_need[0] / PEAK / t_f,
             "bwd_share_of_need": b_need[0] / PEAK / t_b,
             "fwd_products_a_tile": n_f, "bwd_products_a_tile": n_b,
-            "fwd_tile_tflop": n_f * tile_flops / 1e12,
-            "bwd_tile_tflop": n_b * tile_flops / 1e12,
-            "fwd_share_of_mxu_pace": n_f * tile_flops / PEAK / t_f,
-            "bwd_share_of_mxu_pace": n_b * tile_flops / PEAK / t_b,
+            "fwd_tile_tflop": f_flops / 1e12, "bwd_tile_tflop": b_flops / 1e12,
+            "fwd_share_of_mxu_pace": f_flops / PEAK / t_f,
+            "bwd_share_of_mxu_pace": b_flops / PEAK / t_b,
             "rel_err_o_dq_dk_dv": check(tile),
             "platform": jax.default_backend()}), flush=True)
 

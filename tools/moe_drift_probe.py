@@ -10,7 +10,9 @@ optimizer from the seed), drives `--steps` steps through the public loop and
 reads, at every drained step, the drain-to-drain interval and
 `paddle_moe_tokens_total{result}` summed over the layers. One JSON line: the
 held share of pairs at step 4 and at the last step, their ratio, the median
-interval of steps 4-8 and of the last five, `paddle_moe_dropped_total`.
+interval of steps 4-8 and of the last five, both step by step from step 2
+on, `paddle_moe_dropped_total`, and the two gauges step by step (the
+busiest held expert's load over the mean, the largest selection bias).
 `--learning-rate` overrides the configuration's constant rate, to read the
 drift at another one without editing a benchmark file. A rehearsal cell runs
 on the CPU.
@@ -40,6 +42,18 @@ def counters():
     return by["held"], by["elsewhere"], dropped
 
 
+def gauges():
+    """(largest held-expert load over the mean, largest selection bias) over
+    the layers at the last drained step; 0 where no layer publishes one."""
+    from paddle_tpu.observability import metrics
+
+    snap = metrics.default_registry.snapshot()
+    return tuple(max(snap.get(name, {"series": {}})["series"].values(),
+                     default=0.0)
+                 for name in ("paddle_moe_expert_load_max_over_mean",
+                              "paddle_moe_selection_bias_max_abs"))
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -63,6 +77,7 @@ def main():
     pool = traffic.pool(mix, args, a.seed)
     loop = program.Loop(trainer, config["feeding"])
     seen = []                     # (time, held, elsewhere) at every drain
+    state = []                    # (load skew, largest bias) at every drain
     real = loop._handler
 
     def handler(ev):
@@ -70,6 +85,7 @@ def main():
         real(ev)
         if len(loop.drained) > n:
             seen.append((loop.drained[-1],) + counters()[:2])
+            state.append(gauges())
 
     loop._handler = handler
     loop.run(pool[i % len(pool)][0] for i in range(a.steps))
@@ -84,6 +100,10 @@ def main():
         "last_over_step4": share[-1] / share[2],
         "interval_ms_steps4to8": statistics.median(ms[2:7]),
         "interval_ms_last5": statistics.median(ms[-5:]),
+        "held_share_by_step": [round(x, 5) for x in share],
+        "interval_ms_by_step": [round(x, 1) for x in ms],
+        "held_load_max_over_mean_by_step": [round(x[0], 3) for x in state[1:]],
+        "selection_bias_max_abs_by_step": [round(x[1], 5) for x in state[1:]],
         "dropped": counters()[2],
         "costs_first_last": [loop.costs[0], loop.costs[-1]]}), flush=True)
 
