@@ -124,3 +124,16 @@ def pad_T(x: jax.Array, Tp: int) -> jax.Array:
 
 def round_up(n: int, k: int) -> int:
     return -(-n // k) * k
+
+
+def nt(a, b):
+    """a [m, k] x b [n, k]^T -> [m, n], float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def tn(a, b):
+    """a [k, m]^T x b [k, n] -> [m, n], float32: the operand is read as it
+    is stored and turned inside the kernel, not by XLA in HBM before it."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)

@@ -63,6 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 from paddle_tpu.kernels._pallas_util import (NEG, VMEM_LIMIT_BYTES,
                                              call_kernel, log_once, round_up,
                                              take_pallas)
+from paddle_tpu.kernels._pallas_util import nt as _nt, tn as _tn
 from paddle_tpu.utils.error import enforce
 
 SKIPPED, WHOLE, PARTIAL = 0, 1, 2
@@ -184,18 +185,6 @@ def _steps(plan):
     flags[np.concatenate([[0], edge])] |= _FIRST
     flags[np.concatenate([edge - 1, [len(qi) - 1]])] |= _LAST
     return qi.astype(np.int32), ki.astype(np.int32), flags.astype(np.int32)
-
-
-def _nt(a, b):
-    """a [m, k] x b [n, k]^T -> [m, n], float32."""
-    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-def _tn(a, b):
-    """a [k, m]^T x b [k, n] -> [m, n], float32."""
-    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
 
 
 def _column(block, g):

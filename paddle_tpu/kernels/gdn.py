@@ -73,6 +73,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.kernels._pallas_util import VMEM_LIMIT_BYTES, round_up
+from paddle_tpu.kernels._pallas_util import nt as _nt, tn as _tn
 
 CHUNK = 64
 
@@ -222,19 +223,6 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, state_pass=state_pass_scan):
 
 
 # ---- the state pass as Mosaic kernels --------------------------------------
-
-def _nt(a, b):
-    """a [m, k] x b [n, k]^T -> [m, n], float32."""
-    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-def _tn(a, b):
-    """a [k, m]^T x b [k, n] -> [m, n], float32: the operand is read as it
-    is stored and turned inside the kernel, not by XLA in HBM before it."""
-    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
 
 def _dot(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)

@@ -28,21 +28,45 @@ expert) pairs are laid out expert by expert (a counting sort: a pair's place
 is its expert's offset plus its rank among that expert's pairs), each
 expert's run padded to whole tiles of ``tile`` rows, in a buffer sized for
 the worst case (every token choosing min(k, held) held experts). The grouped
-products walk only the tiles in use, a loop whose trip count is data:
-gather the tile's tokens, three products with the tile's expert, weighted
-scatter-add back. Its backward pass is written out under a custom_vjp (a
-loop with a data-dependent trip count has no automatic transpose).
+products walk only the tiles in use, a loop whose trip count is data; its
+backward pass is written out under a custom_vjp (a loop with a
+data-dependent trip count has no automatic transpose, and no activation is
+kept: the forward is made again).
+
+What a trip does has two forms, chosen by what the code observes
+(``_grouped_products``: the platform and ``kernels/moe_grouped.chunk_plan``,
+one gate on VMEM bytes from the shapes; no attribute, flag or environment
+variable):
+
+``grouped_ffn`` below, the TILE LOOP (off the TPU and past the gate, and what
+    the tests pin the kernels to): a trip is one tile. Gather the tile's
+    tokens, three products with the tile's expert, weighted scatter-add back;
+    backward the same again and six products more. Every trip slices the
+    expert's three matrices out of HBM and, backward, adds into three whole
+    float32 expert matrices there, although the next tile almost always has
+    the same expert; and XLA's scatter-add takes 0.30 us a row on a v5e.
+``kernels/moe_grouped.grouped_ffn``, the CHUNKED KERNELS (on the TPU): a trip
+    is a chunk of 16 tiles at hidden 2048. Their rows are gathered in XLA;
+    one Mosaic launch walks them with each tile's expert as scalar prefetch,
+    so an expert's weights are fetched when the expert CHANGES, its float32
+    weight gradient stays in VMEM over the run of its tiles and is written
+    once a (chunk, expert), and a tile's results are added into the tokens'
+    float32 sums by the launch's own DMAs (a token's row is one 8 KB piece
+    of the sum's layout). Every rounding point is the tile loop's. Bytes and
+    times by cell: docs/qwen3_next.md, docs/sdar.md, docs/kimi_vl.md.
+
+``dispatch_plan`` counts what either form does: ``paddle_moe_tiles_total``
+(tiles in use, padding included) and ``paddle_moe_expert_fetches_total``
+((trip, expert) runs: how often an expert's weights are fetched and its
+gradient written); tiles over fetches is 1.0 for the tile loop.
 
 Why not jax.lax.ragged_dot over the sorted rows: on the TPU its cost follows
 the rows of the buffer, which are static, and not the group sizes, which are
 data; a drop-free buffer is the worst case, 32 times the expected load at
-16 of 512 experts held. Measured on a v5e at 16,384 tokens, forward +
-backward of the routed part (docs/qwen3_next.md has the table): 58.1 ms
-against this loop's 17.3 ms at the expected 5,174 pairs. It also leaves the
-rows past the last group undefined, in its lhs gradient too. Per row computed
-it is the faster of the two (36.6 ms against 63.2 ms at 60,421 pairs in a
-65,536-row buffer): a choice among a few buffer sizes by the pairs in hand
-is left to a perf_opt issue (PERF.md section 7).
+16 of 512 experts held, and a choice among a few buffer sizes makes XLA plan
+the largest one's temporaries (docs/qwen3_next.md has the table PR 27
+measured). It also leaves the rows past the last group undefined, in its lhs
+gradient too.
 """
 
 from __future__ import annotations
@@ -55,10 +79,14 @@ import jax.numpy as jnp
 from paddle_tpu.attr import ParamAttr
 from paddle_tpu.core.layer import (ParamSpec, register_layer,
                                    register_step_stats)
+from paddle_tpu.kernels import moe_grouped
+from paddle_tpu.kernels._pallas_util import (batch_shards, call_kernel,
+                                             log_once, take_pallas)
 from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.utils.error import enforce
 
-STATS = ("held", "elsewhere", "load_max_over_mean", "dropped")
+STATS = ("held", "elsewhere", "load_max_over_mean", "dropped", "tiles",
+         "fetches")
 
 _M_TOKENS = obs_metrics.counter(
     "paddle_moe_tokens_total",
@@ -74,6 +102,18 @@ _M_DROPPED = obs_metrics.counter(
     "paddle_moe_dropped_total",
     "Held (token, expert) pairs the dispatch buffer had no row for; the "
     "buffer is sized for the worst case, so this stays 0")
+_M_TILES = obs_metrics.counter(
+    "paddle_moe_tiles_total",
+    "Tiles of the dispatch buffer a moe_ffn layer's grouped products walked "
+    "(each expert's run padded to whole tiles); times the tile's rows over "
+    "the held pairs is what the padding costs", labels=("layer",))
+_M_FETCHES = obs_metrics.counter(
+    "paddle_moe_expert_fetches_total",
+    "Runs of one expert's tiles inside one trip of the grouped products' "
+    "loop: how often an expert's weights were fetched and its weight "
+    "gradient written. Tiles over fetches is 1 for the tile loop and the "
+    "mean run a chunked launch keeps an expert on the chip for",
+    labels=("layer",))
 _M_BIAS = obs_metrics.gauge(
     "paddle_moe_selection_bias_max_abs",
     "Largest magnitude of a moe_ffn layer's selection bias after the last "
@@ -85,11 +125,14 @@ _M_BIAS = obs_metrics.gauge(
 def _publish_stats(lname, vec):
     """One drained step's STATS vector of one layer into the counters; a
     layer with a selection bias appends its largest magnitude."""
-    held, elsewhere, load, dropped = (float(v) for v in vec[:len(STATS)])
+    held, elsewhere, load, dropped, tiles, fetches = (
+        float(v) for v in vec[:len(STATS)])
     _M_TOKENS.labels(layer=lname, result="held").inc(held)
     _M_TOKENS.labels(layer=lname, result="elsewhere").inc(elsewhere)
     _M_LOAD.labels(layer=lname).set(load)
     _M_DROPPED.inc(dropped)
+    _M_TILES.labels(layer=lname).inc(tiles)
+    _M_FETCHES.labels(layer=lname).inc(fetches)
     if len(vec) > len(STATS):
         _M_BIAS.labels(layer=lname).set(float(vec[len(STATS)]))
 
@@ -211,12 +254,39 @@ def _grouped_bwd(tile, res, dy):
 grouped_ffn.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def dispatch_plan(idx, top, valid, first, held, tile):
+def _grouped_products(who, d, I, tile, dtype):
+    """(the layer's grouped products, the tiles a trip of theirs takes):
+    the chunked Mosaic kernels on the TPU where their gate passes, the tile
+    loop above elsewhere; the log says once a layer which, and what the
+    backward launch holds in VMEM."""
+    plan, why = moe_grouped.chunk_plan(d, I, tile, dtype)
+    if plan is not None and batch_shards() > 1:
+        plan, why = None, "the routed rows are not split by the batch"
+    if not take_pallas(who, "moe_grouped_fwd/bwd", plan is not None, why,
+                       otherwise="the tile loop"):
+        return lambda *args: grouped_ffn(*args, tile), 1
+    chunk, buffers = plan
+    need, grads = moe_grouped.bwd_vmem_bytes(d, I, tile, dtype, buffers)
+    log_once(who, f"moe_grouped_fwd/bwd take chunks of {chunk} tiles of "
+             f"{tile} rows; an expert's weights in {buffers} buffer"
+             f"{'s' if buffers > 1 else ''}, its gradients {grads / 1e6:.1f} "
+             f"MB of {need / 1e6:.1f} MB in VMEM")
+
+    def grouped(*args):
+        return call_kernel(
+            lambda *a: moe_grouped.grouped_ffn(*a, tile, chunk, buffers),
+            args, (0,))
+
+    return grouped, chunk
+
+
+def dispatch_plan(idx, top, valid, first, held, tile, chunk=1):
     """Where each (token, chosen expert) pair goes. idx, top [N, k]: the
     chosen experts and their renormalised weights; valid [N] bool (real
-    tokens). Returns (row_w, row_tok, tile_expert, n_tiles, stats): the
-    buffer's rows, each tile's expert, the tiles in use, and the STATS
-    vector (float32)."""
+    tokens); chunk: the tiles one trip of the grouped products takes.
+    Returns (row_w, row_tok, tile_expert, n_tiles, stats): the buffer's
+    rows, each tile's expert, the tiles in use, and the STATS vector
+    (float32)."""
     N, k = idx.shape
     R = -(-N * min(k, held) // tile) * tile + held * tile
     local = idx - first
@@ -245,7 +315,15 @@ def dispatch_plan(idx, top, valid, first, held, tile):
     stats = jnp.stack([n_held.astype(f32), (n_real - n_held).astype(f32),
                        jnp.max(sizes).astype(f32) / mean,
                        jnp.sum((place >= R) & (local < held)).astype(f32)])
-    return row_w, row_tok, tile_expert, tile_end[-1], stats
+    n_tiles = tile_end[-1]
+    # a trip fetches an expert where its first tile starts: at the trip's
+    # first tile and wherever the expert differs from the tile before
+    t = jnp.arange(R // tile)
+    fetch = (t < n_tiles) & ((t % chunk == 0)
+                             | (tile_expert != jnp.roll(tile_expert, 1)))
+    stats = jnp.concatenate([stats, jnp.stack(
+        [n_tiles.astype(f32), jnp.sum(fetch).astype(f32)])])
+    return row_w, row_tok, tile_expert, n_tiles, stats
 
 
 def route(x, p, cfg, valid):
@@ -286,15 +364,17 @@ def _moe_ffn_forward(cfg, params, ins, ctx):
     first, tile = cfg.attr("first_expert", 0), cfg.attr("tile", 256)
     valid = jnp.ones(x_in.shape[:-1], bool) if ins[0].mask is None \
         else ins[0].mask > 0
+    grouped, chunk = _grouped_products(cfg.name, d, cfg.attr("expert_size"),
+                                       tile, x_in.dtype)
 
     def moe(x, valid, p):
         x = x.reshape(-1, d)
         acc = _acc(x.dtype)
         idx, top, bias = route(x, p, cfg, valid.reshape(-1))
         row_w, row_tok, tile_expert, n_tiles, stats = dispatch_plan(
-            idx, top, valid.reshape(-1), first, held, tile)
-        out = grouped_ffn(x, p["wg"], p["wu"], p["wd"], row_w, row_tok,
-                          tile_expert, n_tiles, tile)
+            idx, top, valid.reshape(-1), first, held, tile, chunk)
+        out = grouped(x, p["wg"], p["wu"], p["wd"], row_w, row_tok,
+                      tile_expert, n_tiles)
         if bias is not None:
             stats = jnp.concatenate([stats, jnp.max(jnp.abs(bias))[None]])
         if "shared_wg" in p:

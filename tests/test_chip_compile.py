@@ -312,6 +312,56 @@ def test_kimi_vl_attention_train_compiles_at_the_cells_size(one_chip, dtype,
     assert f"[1,{L},4096]" in compiled.as_text()
 
 
+@pytest.mark.parametrize("cell,B,T,I,E,held,k,extra", [
+    ("sdar", 2, 16384, 768, 128, 16, 8, {}),
+    ("kimivl", 2, 8192, 1408, 64, 8, 6,
+     dict(score="sigmoid", selection_bias=True, route_scale=2.446)),
+    ("qwen3next", 4, 4096, 512, 512, 16, 10, {}),
+])
+def test_moe_ffn_train_compiles_at_the_cells_size(one_chip, monkeypatch, cell,
+                                                  B, T, I, E, held, k, extra):
+    """value_and_grad of one `moe_ffn` layer's routed part as the three MoE
+    cells run it (hidden 2048, bf16, tiles of 256 rows, chunks of 16): the
+    chunked grouped products are one `moe_grouped_fwd` and one
+    `moe_grouped_bwd` launch (the forward made again by the layer's
+    `jax.checkpoint` is dead code: the backward launch makes its own), taken
+    by Mosaic with an expert's three float32 gradients resident in VMEM, at
+    Kimi-VL's width with one buffer for the weights' blocks."""
+    from paddle_tpu import data_type, layer
+    from paddle_tpu.core.arg import Arg
+    from paddle_tpu.core.topology import Topology
+    from paddle_tpu.kernels import moe_grouped
+    from paddle_tpu.layers import moe
+
+    monkeypatch.setattr(moe, "take_pallas",
+                        lambda who, kernel, eligible=True, why_not="", **kw:
+                        eligible)
+    d, dtype = 2048, jnp.bfloat16
+    plan, why = moe_grouped.chunk_plan(d, I, 256, dtype)
+    assert plan == (16, 1 if cell == "kimivl" else 2), (plan, why)
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
+    out = layer.moe_ffn(input=x, num_experts=E, top_k=k, expert_size=I,
+                        experts_held=held, name="l", **extra)
+    topo = Topology(out)
+    params = {n: _sds(s.shape, jnp.float32 if n.endswith(".bias") else dtype,
+                      one_chip)
+              for n, s in topo.param_specs().items()}
+
+    def loss(params, x):
+        y = topo.forward(params, {"x": Arg(x, jnp.ones((B, T)))},
+                         training=True)["l"].value
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled, n = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params,
+                           _sds((B, T, d), dtype, one_chip))
+    names = _mosaic_instructions(compiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"moe_ffn {cell}: {temp:,} bytes of temporaries")
+    assert sum("moe_grouped_fwd" in x for x in names) == 1, names
+    assert sum("moe_grouped_bwd" in x for x in names) == 1, names
+    assert len(names) == 2, names
+
+
 # the LSTM classifier (B64/H512/T100) and the split backward past the
 # in-kernel-dW VMEM gate (H1280)
 @pytest.mark.parametrize("B,H,T,dtype,precision", [

@@ -351,7 +351,7 @@ def test_the_bias_moves_by_gamma_against_the_load_and_skips_padding(ref):
     assert np.array_equal(new, np.asarray(
         ref.next_bias(p["bias"], jnp.asarray(counts), a)))
     stats = np.asarray(ctx.extras["step_stats"]["moe_ffn"]["l"])
-    held, elsewhere, _, dropped, bias_max = stats
+    held, elsewhere, _, dropped, _, _, bias_max = stats
     assert (held, elsewhere, dropped) == ((T + 5) * 3, 0, 0)
     assert bias_max == np.float32(GAMMA)
     # outside training nothing is handed to the trainer
@@ -411,8 +411,8 @@ def test_eight_shares_and_the_shared_expert_once_make_the_uncut_layer(ref):
         part = outs["l"].value[0] - ref.shared(full, x[0], a, _ident)
         _close(part, ref.routed(full, x[0], a, _ident, first=8 * s, held=8))
         total = total + part
-        held, elsewhere, _, dropped, _ = np.asarray(
-            ctx.extras["step_stats"]["moe_ffn"]["l"])
+        held, elsewhere, _, dropped = np.asarray(
+            ctx.extras["step_stats"]["moe_ffn"]["l"])[:4]
         assert held + elsewhere == T * 6 and dropped == 0
         pairs += held
         # every share counts ALL experts' pairs of its own tokens alike

@@ -540,7 +540,8 @@ def test_four_shares_and_one_shared_make_the_uncut_layer(ref):
         part = outs["l"].value[0] - ref.shared(full, x[0], a, _ident)
         _close(part, ref.routed(full, x[0], a, _ident, first=2 * s, held=2))
         total = total + part
-        held, elsewhere, _, dropped = np.asarray(ctx.extras["step_stats"]["moe_ffn"]["l"])
+        held, elsewhere, _, dropped = np.asarray(
+            ctx.extras["step_stats"]["moe_ffn"]["l"])[:4]
         assert held + elsewhere == T * a["num_experts_per_tok"]
         assert dropped == 0
     _close(total, want)
@@ -548,7 +549,10 @@ def test_four_shares_and_one_shared_make_the_uncut_layer(ref):
 
 # sha256 of the lowered layer below at the parent commit of PR 34 (b3f4740),
 # by this test's own code: `moe_ffn` learnt Kimi-VL's router there (a sigmoid
-# score, a selection bias, a weight scale, a shared expert without its gate)
+# score, a selection bias, a weight scale, a shared expert without its gate).
+# It holds through PR 35: off the TPU the layer takes the tile loop, which the
+# chunked kernels did not touch, and the two entries the step statistics
+# gained do not reach this program (only the layer's value does)
 QWEN_MOE_SHA256 = "de18d10babdaf0e445b3eb5c5a45300f925464365a6cdf24a0ff6e43aa8375f8"
 
 
@@ -586,10 +590,14 @@ def test_moe_counts_held_and_elsewhere_and_skips_padding():
     mask = jnp.asarray([[1.0] * T, [1.0] * 5 + [0.0] * (T - 5)])
     _, ctx = topo.forward(params, {"x": Arg(_normal(2, 2, T, d), mask)},
                           training=True, return_ctx=True)
-    held, elsewhere, load, dropped = np.asarray(ctx.extras["step_stats"]["moe_ffn"]["l"])
+    held, elsewhere, load, dropped, tiles, fetches = np.asarray(
+        ctx.extras["step_stats"]["moe_ffn"]["l"])
     # every expert is held: every real token's two choices are computed here
     assert (held, elsewhere, dropped) == ((T + 5) * 2, 0, 0)
     assert load >= 1.0
+    # tiles of 4 rows hold the 34 pairs, padding included; the tile loop
+    # fetches an expert every tile
+    assert (T + 5) * 2 / 4 <= tiles == fetches <= (T + 5) * 2 / 4 + 8
 
 
 def test_grouped_ffn_walks_only_the_tiles_in_use():
@@ -606,7 +614,7 @@ def test_grouped_ffn_walks_only_the_tiles_in_use():
     assert int(n_tiles) == N // tile                     # what is in use
     assert np.asarray(row_tok[:N]).tolist() == list(range(N))
     assert np.all(np.asarray(row_tok[N:]) == N)          # padding rows
-    assert np.asarray(stats).tolist() == [N, N, 4.0, 0.0]
+    assert np.asarray(stats).tolist() == [N, N, 4.0, 0.0, N // tile, N // tile]
 
 
 # ---- through the public trainer ---------------------------------------------

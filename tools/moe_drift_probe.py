@@ -11,8 +11,12 @@ reads, at every drained step, the drain-to-drain interval and
 `paddle_moe_tokens_total{result}` summed over the layers. One JSON line: the
 held share of pairs at step 4 and at the last step, their ratio, the median
 interval of steps 4-8 and of the last five, both step by step from step 2
-on, `paddle_moe_dropped_total`, and the two gauges step by step (the
-busiest held expert's load over the mean, the largest selection bias).
+on, `paddle_moe_dropped_total`, the two gauges step by step (the
+busiest held expert's load over the mean, the largest selection bias), and
+from `paddle_moe_tiles_total` / `paddle_moe_expert_fetches_total` how far
+the chunked grouped products engage: tiles over fetches (1.0 is the tile
+loop) and the rows the tiles' padding adds to the held pairs, over the run
+and at step 4 and the last step.
 `--learning-rate` overrides the configuration's constant rate, to read the
 drift at another one without editing a benchmark file. A rehearsal cell runs
 on the CPU.
@@ -37,9 +41,11 @@ def counters():
     for labels, v in snap.get("paddle_moe_tokens_total",
                               {"series": {}})["series"].items():
         by[dict(labels)["result"]] += v
-    dropped = sum(snap.get("paddle_moe_dropped_total",
-                           {"series": {}})["series"].values())
-    return by["held"], by["elsewhere"], dropped
+    dropped, tiles, fetches = (
+        sum(snap.get(name, {"series": {}})["series"].values())
+        for name in ("paddle_moe_dropped_total", "paddle_moe_tiles_total",
+                     "paddle_moe_expert_fetches_total"))
+    return by["held"], by["elsewhere"], dropped, tiles, fetches
 
 
 def gauges():
@@ -78,13 +84,16 @@ def main():
     loop = program.Loop(trainer, config["feeding"])
     seen = []                     # (time, held, elsewhere) at every drain
     state = []                    # (load skew, largest bias) at every drain
+    walked = []                   # (tiles, fetches, held) at every drain
     real = loop._handler
 
     def handler(ev):
         n = len(loop.drained)
         real(ev)
         if len(loop.drained) > n:
-            seen.append((loop.drained[-1],) + counters()[:2])
+            c = counters()
+            seen.append((loop.drained[-1],) + c[:2])
+            walked.append(c[3:] + c[:1])
             state.append(gauges())
 
     loop._handler = handler
@@ -93,6 +102,15 @@ def main():
              for (_, h0, e0), (_, h1, e1) in zip(seen, seen[1:])]
     ms = [1e3 * (b[0] - a_[0]) for a_, b in zip(seen, seen[1:])]
     # share[i], ms[i] belong to step i + 2 (the first drain has no interval)
+    step = [tuple(b - a_ for a_, b in zip(w0, w1))
+            for w0, w1 in zip(walked, walked[1:])]
+    tile_rows = args.get("tile", 256)
+
+    def engaged(tiles, fetches, held):
+        return {"tiles_over_fetches": round(tiles / max(fetches, 1.0), 3),
+                "padding_over_held": round(
+                    tiles * tile_rows / max(held, 1.0) - 1, 4)}
+
     print(json.dumps({
         "cell": a.workload, "seed": a.seed, "steps": a.steps,
         "learning_rate": config["optimizer"]["learning_rate"],
@@ -105,6 +123,10 @@ def main():
         "held_load_max_over_mean_by_step": [round(x[0], 3) for x in state[1:]],
         "selection_bias_max_abs_by_step": [round(x[1], 5) for x in state[1:]],
         "dropped": counters()[2],
+        "tiles_a_step": statistics.mean(t for t, _, _ in step),
+        "grouped_products": engaged(*(sum(x) for x in zip(*step))),
+        "grouped_products_step4": engaged(*step[2]),
+        "grouped_products_last": engaged(*step[-1]),
         "costs_first_last": [loop.costs[0], loop.costs[-1]]}), flush=True)
 
 
