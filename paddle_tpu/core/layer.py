@@ -216,6 +216,14 @@ class Layer:
     def attr(self, key: str, default=None):
         return self.cfg.get(key, default)
 
+    @property
+    def scope(self) -> str:
+        """The ``jax.named_scope`` the layer graph runs this layer under: its
+        ``scope`` attribute (a model's own path, ``qwen3next/l0/mixer``), else
+        its name. A profile read by scope
+        (``paddle_tpu.observability.profile``) groups device time by it."""
+        return self.cfg.get("scope") or self.name
+
     def param_attr(self, i: int = 0) -> ParamAttr:
         if i < len(self.param_attrs):
             return self.param_attrs[i]

@@ -289,7 +289,11 @@ class Topology:
             ctx.layer_param_names = self._layer_params[l.name]
             ins = [ctx.outputs[i.name] for i in l.inputs]
             try:
-                ctx.outputs[l.name] = l.forward(lparams, ins, ctx)
+                # the one site that names a layer's ops: metadata only (an
+                # op's `op_name`), so the jaxpr, the lowered text without
+                # debug info and the compile-cache key are what they were
+                with jax.named_scope(l.scope):
+                    ctx.outputs[l.name] = l.forward(lparams, ins, ctx)
             except Exception as e:
                 # CustomStackTrace analog (paddle/utils/CustomStackTrace.h:26,
                 # NeuralNetwork.cpp:244-293): say where in the MODEL we died,
@@ -363,11 +367,14 @@ class Topology:
         def loss(params, feeds, rng=None, training=True, mesh=None,
                  sparse_tangents=None, sparse_collect=None):
             if compute_dtype is not None:
-                params = {k: (v.astype(compute_dtype)
-                              if v.dtype == jnp.float32 and not static.get(k)
-                              else v)
-                          for k, v in params.items()}
-                feeds = {k: cast_arg(v) for k, v in feeds.items()}
+                # the casts belong to no layer: a scope of their own, so a
+                # profile read by scope does not file them under `(none)`
+                with jax.named_scope("precision_cast"):
+                    params = {k: (v.astype(compute_dtype)
+                                  if v.dtype == jnp.float32
+                                  and not static.get(k) else v)
+                              for k, v in params.items()}
+                    feeds = {k: cast_arg(v) for k, v in feeds.items()}
             outs, ctx = self.forward(params, feeds, training=training, rng=rng,
                                      mesh=mesh, return_ctx=True,
                                      sparse_tangents=sparse_tangents,

@@ -214,8 +214,7 @@ def _gated_attention_forward(cfg, params, ins, ctx):
         o = o.reshape(T, H * D) * jax.nn.sigmoid(gate.reshape(T, H * D))
         return jnp.matmul(o, p["wo"])
 
-    with jax.named_scope(cfg.attr("scope") or cfg.name):
-        out = rows_one_at_a_time(mixer, x, params)
+    out = rows_one_at_a_time(mixer, x, params)
     return ins[0].with_value(out)
 
 
@@ -290,9 +289,8 @@ def _gqa_attention_forward(cfg, params, ins, ctx):
 
     # a row's backward pass computes its projections again from the layer's
     # input; what the kernels' forward launch made of the row it keeps
-    with jax.named_scope(cfg.attr("scope") or cfg.name):
-        out = rows_one_at_a_time(mixer, x, params,
-                                 kept=("flash_attn_o", "flash_attn_lse"))
+    out = rows_one_at_a_time(mixer, x, params,
+                             kept=("flash_attn_o", "flash_attn_lse"))
     return ins[0].with_value(out)
 
 
@@ -363,7 +361,6 @@ def _mla_attention_forward(cfg, params, ins, ctx):
             kv[..., Dn:].reshape(1, T, H * Dv), rule, H)
         return jnp.matmul(o[0], p["wo"])
 
-    with jax.named_scope(cfg.attr("scope") or cfg.name):
-        out = rows_one_at_a_time(mixer, x, params,
-                                 kept=("flash_attn_o", "flash_attn_lse"))
+    out = rows_one_at_a_time(mixer, x, params,
+                             kept=("flash_attn_o", "flash_attn_lse"))
     return ins[0].with_value(out)

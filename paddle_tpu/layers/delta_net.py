@@ -90,6 +90,5 @@ def _gated_delta_net_forward(cfg, params, ins, ctx):
         o = o * jax.nn.silu(z.reshape(T, Hv, dv))
         return jnp.matmul(o.reshape(T, nv), p["wout"])
 
-    with jax.named_scope(cfg.attr("scope") or cfg.name):
-        out = rows_one_at_a_time(mixer, x, params)
+    out = rows_one_at_a_time(mixer, x, params)
     return ins[0].with_value(out)

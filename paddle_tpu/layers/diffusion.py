@@ -70,14 +70,13 @@ def _noise_forward(cfg, params, ins, ctx):
             f"block_diffusion_noise {cfg.name}: packed rows need the blocks "
             "cut per document, which this layer does not do")
     ids = ins[0]
-    with jax.named_scope(cfg.attr("scope") or cfg.name):
-        masked, _ = masked_and_level(ids, ins[1].value, ins[2].value,
-                                     cfg.attr("block"))
-        x0 = ids.value.astype(jnp.int32)
-        xt = jnp.where(masked, jnp.int32(cfg.attr("mask_id")), x0)
-        f32 = jnp.float32
-        stats = jnp.stack([jnp.sum(masked.astype(f32)),
-                           2.0 * jnp.sum(ids.mask.astype(f32))])
+    masked, _ = masked_and_level(ids, ins[1].value, ins[2].value,
+                                 cfg.attr("block"))
+    x0 = ids.value.astype(jnp.int32)
+    xt = jnp.where(masked, jnp.int32(cfg.attr("mask_id")), x0)
+    f32 = jnp.float32
+    stats = jnp.stack([jnp.sum(masked.astype(f32)),
+                       2.0 * jnp.sum(ids.mask.astype(f32))])
     ctx.extras.setdefault("step_stats", {}).setdefault(
         "block_diffusion_noise", {})[cfg.name] = stats
     return Arg(jnp.concatenate([xt, x0], axis=1),

@@ -348,11 +348,12 @@ def route(x, p, cfg, valid):
         top = top * scale
     if "bias" not in p:
         return idx, top, None
-    hit = (idx[..., None] == jnp.arange(p["bias"].shape[0])) \
-        & valid[:, None, None]
-    counts = jnp.sum(hit, axis=(0, 1), dtype=jnp.float32)
-    bias = p["bias"] + cfg.attr("bias_rate", 1e-3) * jnp.sign(
-        jnp.mean(counts) - counts).astype(p["bias"].dtype)
+    with jax.named_scope("aux_update"):
+        hit = (idx[..., None] == jnp.arange(p["bias"].shape[0])) \
+            & valid[:, None, None]
+        counts = jnp.sum(hit, axis=(0, 1), dtype=jnp.float32)
+        bias = p["bias"] + cfg.attr("bias_rate", 1e-3) * jnp.sign(
+            jnp.mean(counts) - counts).astype(p["bias"].dtype)
     return idx, top, bias
 
 
@@ -391,8 +392,7 @@ def _moe_ffn_forward(cfg, params, ins, ctx):
             out = out + shared
         return out.reshape(x_in.shape), stats, bias
 
-    with jax.named_scope(cfg.attr("scope") or cfg.name):
-        out, stats, bias = jax.checkpoint(moe)(x_in, valid, params)
+    out, stats, bias = jax.checkpoint(moe)(x_in, valid, params)
     ctx.extras.setdefault("step_stats", {}).setdefault(
         "moe_ffn", {})[cfg.name] = jax.lax.stop_gradient(stats)
     if bias is not None and ctx.training:
@@ -412,7 +412,6 @@ def _gated_mlp_forward(cfg, params, ins, ctx):
     """(silu(x Wg) * (x Wu)) Wd of width ``width``, no bias: a decoder's dense
     feed-forward layer. Its two wide activations are computed again in the
     backward pass, as an expert's are."""
-    with jax.named_scope(cfg.attr("scope") or cfg.name):
-        out = jax.checkpoint(_gated_mlp)(
-            ins[0].value, params["wg"], params["wu"], params["wd"])
+    out = jax.checkpoint(_gated_mlp)(
+        ins[0].value, params["wg"], params["wu"], params["wd"])
     return ins[0].with_value(out)

@@ -106,10 +106,11 @@ def _batch_norm(cfg, params, ins, ctx):
             mean = xs.mean(axis=axes)
             var = jnp.maximum((xs * xs).mean(axis=axes) - mean * mean, 0.0)
         # EMA update folded into the jitted step via ctx.extras
-        ctx.extras.setdefault("batch_stats", {})[cfg.name] = {
-            "wmean": momentum * params["wmean"] + (1 - momentum) * mean,
-            "wvar": momentum * params["wvar"] + (1 - momentum) * var,
-        }
+        with jax.named_scope("aux_update"):
+            ctx.extras.setdefault("batch_stats", {})[cfg.name] = {
+                "wmean": momentum * params["wmean"] + (1 - momentum) * mean,
+                "wvar": momentum * params["wvar"] + (1 - momentum) * var,
+            }
     mean_b, var_b = mean.reshape(shape), var.reshape(shape)
     g, b = params["w0"].reshape(shape), params["wbias"].reshape(shape)
     # fold to per-channel scale/shift in f32, then apply in the input

@@ -1,6 +1,6 @@
 """Observability subsystem: metrics registry, trace spans, exporters.
 
-Three small host-side modules (docs/observability.md is the catalog):
+Four small host-side modules (docs/observability.md is the catalog):
 
 - ``metrics``  — thread-safe typed registry (Counter/Gauge/Histogram with
   fixed log-spaced buckets, optional labels), consistent snapshots,
@@ -10,7 +10,11 @@ Three small host-side modules (docs/observability.md is the catalog):
   into the profiler's own trace (jax.profiler.TraceAnnotation), where it
   lies beside the device's ops,
 - ``exporter`` — opt-in background HTTP server (/metrics, /healthz,
-  /trace) + periodic file exporter for headless runs.
+  /trace) + periodic file exporter for headless runs,
+- ``profile``  — the reader of a ``jax.profiler`` trace: device time by
+  the scopes the program writes, idle gaps by ``paddle:`` span
+  (``python -m paddle_tpu.observability.profile <dir>``; standard library
+  only, and not imported here: ``-m`` runs it as a fresh module).
 
 Instrumentation is host-side only: enabling any of it changes no jaxpr
 (pinned by tests/test_observability.py).
@@ -18,9 +22,9 @@ Instrumentation is host-side only: enabling any of it changes no jaxpr
 
 from paddle_tpu.observability import exporter, metrics, trace  # noqa: F401
 from paddle_tpu.observability.metrics import (DEFAULT_BUCKETS,  # noqa: F401
-                                              MetricsRegistry, bench_extras,
-                                              counter, default_registry,
-                                              gauge, histogram, log_buckets)
+                                              MetricsRegistry, counter,
+                                              default_registry, gauge,
+                                              histogram, log_buckets)
 from paddle_tpu.observability.trace import (global_tracer, span)  # noqa: F401
 from paddle_tpu.observability.exporter import (FileExporter,  # noqa: F401
                                                MetricsHTTPServer, configure,

@@ -440,23 +440,3 @@ def gauge(name: str, help_str: str = "", labels: Sequence[str] = ()):
 def histogram(name: str, help_str: str = "", labels: Sequence[str] = (),
               buckets: Optional[Sequence[float]] = None):
     return default_registry.histogram(name, help_str, labels, buckets)
-
-
-def bench_extras(delta: Optional[dict] = None,
-                 registry: Optional[MetricsRegistry] = None) -> dict:
-    """Compact nonzero-only summary of a snapshot or a delta: counter
-    totals, gauge values, histogram (count, sum). Keys flatten to
-    'name{k=v}'."""
-    reg = registry or default_registry
-    snap = delta if delta is not None else reg.snapshot()
-    out = {}
-    for name, entry in snap.items():
-        for key, v in entry["series"].items():
-            flat = name + (_label_str(key) if key else "")
-            if entry["type"] == "histogram":
-                if v["count"]:
-                    out[flat] = {"count": v["count"],
-                                 "sum_s": round(v["sum"], 6)}
-            elif v:
-                out[flat] = round(v, 6) if isinstance(v, float) else v
-    return out

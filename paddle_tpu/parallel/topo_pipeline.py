@@ -853,7 +853,8 @@ class PipelinedTopology:
             lparams = {suffix: params[pname]
                        for suffix, pname in topo._layer_params[l.name].items()}
             ins = [ctx.outputs[i.name] for i in l.inputs]
-            ctx.outputs[l.name] = l.forward(lparams, ins, ctx)
+            with jax.named_scope(l.scope):     # as Topology.forward does
+                ctx.outputs[l.name] = l.forward(lparams, ins, ctx)
         return ctx.outputs
 
     # --- public API -------------------------------------------------------

@@ -16,6 +16,7 @@ MultiGradientMachine.
 from __future__ import annotations
 
 import functools
+import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence
@@ -47,8 +48,10 @@ _M_STEP_SECONDS = obs_metrics.histogram(
     "in hand -> feeds ready for the step) with its children feed_convert "
     "(feeder stacking) and feed_h2d (device_put until the call returns), "
     "compile (build + first call of a new shape key; its count is the "
-    "compile counter), dispatch (jitted step enqueue of a known shape), "
-    "drain (blocked fetching that batch's cost)",
+    "compile counter) and what JAX says of its stages while the span is "
+    "open: compile_trace, compile_lower, compile_backend (XLA's compile "
+    "on a cache miss, the load on a hit), dispatch (jitted step enqueue "
+    "of a known shape), drain (blocked fetching that batch's cost)",
     labels=("phase",))
 _M_BATCHES = obs_metrics.counter(
     "paddle_train_batches_total", "Batches trained by SGD.train")
@@ -98,6 +101,113 @@ class _phase(timer_scope):
         if exc_type is None:
             self._hist.observe(self.seconds)
         return False
+
+
+# --- what building a step cost, by stage -----------------------------------
+# JAX reports each stage of a compilation through jax.monitoring when the
+# stage ends, with its duration. While the calling thread is inside a
+# `paddle:compile` span the listeners keep each report as an interval; the
+# span's end turns them into paddle_train_step_seconds{phase=compile_*}.
+# These three are durations told after the fact: no span of their own in
+# the profile. Outside a span (another program's jit, another thread) a
+# report is not the step's and is dropped. The listeners run only when JAX
+# compiles: a step that is already compiled never reaches them.
+_COMPILE_STAGES = {        # in the order a compilation goes through them
+    "/jax/core/compile/jaxpr_trace_duration": "compile_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile_lower",
+    # XLA's compile on a miss; the cache's retrieval and load on a hit
+    "/jax/core/compile/backend_compile_duration": "compile_backend",
+}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_M_COMPILE_CACHE = obs_metrics.counter(
+    "paddle_train_compile_cache_total",
+    "Persistent compile-cache lookups made while building a train step "
+    "(inside a paddle:compile span), by result: hit (the executable was "
+    "loaded) or miss (XLA compiled it)",
+    labels=("result",))
+_building = threading.local()
+
+
+def _on_compile_stage(event, duration, **_):
+    span = getattr(_building, "span", None)
+    stage = _COMPILE_STAGES.get(event)
+    if span is not None and stage is not None:
+        end = time.perf_counter()
+        span.stages[stage].append((end - duration, end))
+
+
+def _on_cache_event(event, **_):
+    span = getattr(_building, "span", None)
+    result = _CACHE_EVENTS.get(event)
+    if span is not None and result is not None:
+        _M_COMPILE_CACHE.labels(result=result).inc()
+        span.cache[result] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_stage)
+jax.monitoring.register_event_listener(_on_cache_event)
+
+
+def _merged(intervals, lo, hi):
+    """Sorted, disjoint [(start, end)] of the intervals' parts in [lo, hi]."""
+    out = []
+    for s, e in sorted((max(s, lo), min(e, hi)) for s, e in intervals):
+        if e <= s:
+            continue
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+class _compile_phase(_phase):
+    """The `compile` phase, which also hears what JAX says of its stages.
+    An inner jit (a kernel's launch wrapper) reports its trace before the
+    outer one ends, inside it, and a lowering may trace again: a stage is
+    the union of its reports, and a second that two stages cover counts
+    for the later stage (backend, then lower, then trace), so the three
+    never sum to more than the span."""
+
+    __slots__ = ("stages", "cache", "stage_seconds")
+
+    def __init__(self, step, **args):
+        super().__init__("compile", step, **args)
+        self.stages = {stage: [] for stage in _COMPILE_STAGES.values()}
+        self.cache = {"hit": 0, "miss": 0}
+        self.stage_seconds = {}
+
+    def __enter__(self):
+        super().__enter__()
+        _building.span = self
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        _building.span = None
+        super().__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            lo, hi = self._t0, self._t0 + self.seconds
+            taken, covered = [], 0.0
+            for stage in reversed(_COMPILE_STAGES.values()):
+                mine = _merged(self.stages[stage], lo, hi)
+                taken = _merged(mine + taken, lo, hi)
+                total = sum(e - s for s, e in taken)
+                if mine:
+                    self.stage_seconds[stage] = total - covered
+                    _M_STEP_SECONDS.labels(phase=stage).observe(
+                        total - covered)
+                covered = total
+        return False
+
+    def summary(self):
+        """`trace 1.2 s, lower 0.4 s, backend 8.0 s, cache hit` for the log."""
+        parts = [f"{stage[len('compile_'):]} {self.stage_seconds[stage]:.2f} s"
+                 for stage in _COMPILE_STAGES.values()
+                 if stage in self.stage_seconds]
+        parts += [f"cache {r}" + (f" x{n}" if n > 1 else "")
+                  for r, n in self.cache.items() if n]
+        return ", ".join(parts) or "no stage reported"
 
 
 class _TimedBatches:
@@ -266,8 +376,11 @@ def make_train_step(loss, optimizer, static, lr_mults=None, evaluators=None,
                 loss, has_aux=True)(params, feeds, rng=rng, training=True)
         host_grads = {hn: grads.pop(hn) for hn in host_tables
                       if hn in grads}
-        new_params, new_opt_state = optimizer.update(grads, opt_state, params,
-                                                     lr_mults, static)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt_state = optimizer.update(
+                grads, opt_state, params, lr_mults, static)
+        # batch-norm / static-state fold-in: assignments, no op; the values'
+        # arithmetic is in their layers, under `<layer>/aux_update`
         for pname, val in aux.items():
             new_params[pname] = val
         metrics = _compute_metrics(evaluators, outs, loss, feeds)
@@ -288,8 +401,9 @@ def make_train_step(loss, optimizer, static, lr_mults=None, evaluators=None,
                 params, opt_state, acc = operand
                 mean = jax.tree_util.tree_map(
                     lambda a: a / float(accum_steps), acc)
-                new_params, new_opt = optimizer.update(mean, opt_state, params,
-                                                       lr_mults, static)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt = optimizer.update(
+                        mean, opt_state, params, lr_mults, static)
                 zero = jax.tree_util.tree_map(jnp.zeros_like, acc)
                 return new_params, new_opt, zero, jnp.zeros((), jnp.int32)
 
@@ -1218,7 +1332,7 @@ class SGD:
                     # (which traces and compiles) are one `compile` span;
                     # the count of {phase=compile} is the compile counter
                     logger.info("compiling train step for shapes %s", key)
-                    run = _phase("compile", step, key=str(key))
+                    run = _compile_phase(step, key=str(key))
                 else:
                     # a StepTraceAnnotation (step_num): the profile groups
                     # the device's ops by the program's own steps
@@ -1241,6 +1355,9 @@ class SGD:
                         # finish, so the span means executed, not
                         # enqueued (drain_one's float() is then a no-op)
                         cost = float(cost)
+                if compiling:
+                    logger.info("compiled train step in %.2f s: %s",
+                                run.seconds, run.summary())
                 dispatch_s = run.seconds
                 disp_step += 1
                 stats_dev = None

@@ -442,6 +442,36 @@ def test_fused_gru_under_data_parallel_compiles(topo):
         jax.jit(_gru_train).lower(*args)
 
 
+def test_a_scope_around_a_mosaic_launch_changes_nothing_in_the_lowered_text(
+        one_chip):
+    """The layer graph's `jax.named_scope` (core/topology.py) is metadata on
+    the TPU too: with and without it the lowered text is the same, the
+    Mosaic kernel's serialized body in the custom call's `backend_config`
+    included. (That body does hold the launch's source positions, so an
+    edit that moves a line above a `pallas_call` changes the text, and with
+    it the compile-cache key: PERF.md section 6, PR 36.)"""
+    import contextlib
+
+    from jax.experimental import pallas as pl
+
+    def twice(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2.0
+
+    def layer(x, scoped):
+        with (jax.named_scope("layer_a") if scoped
+              else contextlib.nullcontext()):
+            return pl.pallas_call(
+                twice, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+                name="twice")(x) + 1
+
+    x = _sds((256, 256), jnp.float32, one_chip)
+    plain, scoped = (
+        jax.jit(lambda x, s=s: layer(x, s)).lower(x).as_text()
+        for s in (False, True))
+    assert "tpu_custom_call" in plain
+    assert plain == scoped
+
+
 def test_libtpu_api_negotiation():
     """libtpu.so exports GetPjrtApi and speaks the vendored header's PJRT
     API major version; on this chip-less host client creation then fails

@@ -53,6 +53,24 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 pytestmark = pytest.mark.chaos
 
 
+def _nonzero_summary(delta):
+    """Compact nonzero-only summary of a registry delta: counter totals,
+    gauge values, histogram (count, sum). Keys flatten to 'name{k=v}'."""
+    from paddle_tpu.observability.metrics import _label_str
+
+    out = {}
+    for name, entry in delta.items():
+        for key, v in entry["series"].items():
+            flat = name + (_label_str(key) if key else "")
+            if entry["type"] == "histogram":
+                if v["count"]:
+                    out[flat] = {"count": v["count"],
+                                 "sum_s": round(v["sum"], 6)}
+            elif v:
+                out[flat] = round(v, 6) if isinstance(v, float) else v
+    return out
+
+
 def _policy(**kw):
     import random
 
@@ -188,7 +206,7 @@ def test_torn_snapshot_falls_back_to_previous_valid(tmp_path):
     """Truncate the newest snapshot's state.pkl (and, separately, drop
     the meta.json commit record): restore lands on the previous valid
     snapshot and counts the invalid ones."""
-    from paddle_tpu.observability.metrics import bench_extras, default_registry
+    from paddle_tpu.observability.metrics import default_registry
 
     snap = str(tmp_path / "snap")
     srv = _server(snap).start()
@@ -220,7 +238,7 @@ def test_torn_snapshot_falls_back_to_previous_valid(tmp_path):
 
     default_registry.delta()
     srv2 = _server(snap).start()
-    delta = bench_extras(default_registry.delta())
+    delta = _nonzero_summary(default_registry.delta())
     assert srv2.restored_from == snaps[0][1]
     for k in good_params:
         np.testing.assert_array_equal(srv2.params[k], good_params[k])
@@ -232,7 +250,7 @@ def test_snapshot_cadence_and_metrics(tmp_path):
     """snapshot_every_applies takes snapshots synchronously on the apply
     cadence (no SNAP command needed) and the paddle_pserver_snapshot_*
     series record each one."""
-    from paddle_tpu.observability.metrics import bench_extras, default_registry
+    from paddle_tpu.observability.metrics import default_registry
 
     default_registry.delta()
     snap = str(tmp_path / "snap")
@@ -247,7 +265,7 @@ def test_snapshot_cadence_and_metrics(tmp_path):
     cl.push(g, v + 2)
     cl.push(g, v + 3)                            # 4th apply -> snapshot
     assert len(checkpoint.list_state_snapshots(snap, "pserver")) == 2
-    delta = bench_extras(default_registry.delta())
+    delta = _nonzero_summary(default_registry.delta())
     assert delta.get('paddle_pserver_snapshots_total{ok="true"}', 0) >= 2
     assert any(k.startswith("paddle_pserver_snapshot_seconds")
                for k in delta)
@@ -346,7 +364,7 @@ def test_pserver_restart_under_live_lease_and_client_failover(tmp_path):
     (lease still live), relaunches on a NEW port, re-registers by
     superseding its own seat — and a client mid-conversation fails over
     through the registry without caller intervention."""
-    from paddle_tpu.observability.metrics import bench_extras, default_registry
+    from paddle_tpu.observability.metrics import default_registry
 
     snap = str(tmp_path / "snap")
     root = str(tmp_path / "disc")
@@ -372,7 +390,7 @@ def test_pserver_restart_under_live_lease_and_client_failover(tmp_path):
     default_registry.delta()
     _p2, v2 = cl.pull()                          # transparent failover
     assert v2 == srv2.version
-    delta = bench_extras(default_registry.delta())
+    delta = _nonzero_summary(default_registry.delta())
     if srv2.port != old_port:
         assert delta.get("paddle_pserver_client_failovers_total", 0) >= 1
     cl.close()
