@@ -262,9 +262,14 @@ def _gqa_attention_forward(cfg, params, ins, ctx):
     on the whole head at the position the mask's rule gives each step,
     softmax(q k^T / sqrt(D)) under the rule's mask, no gate, no bias. The
     rule (``mask``) is known at trace time and the scores are computed tile
-    by tile where it keeps something (kernels/flash_attn.py). A row's
-    padding is computed as tokens: the rule is over whole rows."""
-    from paddle_tpu.kernels import flash_attn
+    by tile where it keeps something (kernels/flash_attn.py). On the TPU,
+    where a head is one group of 128 lanes, the norm and rotary of q and of k
+    are one Mosaic launch each way, ``head_norm_rotary_fwd`` /
+    ``head_norm_rotary_bwd`` (kernels/head_norm_rotary.py), on the
+    projection as the matmul laid it out, [T, heads * D]; elsewhere they are
+    ``_head_norm`` and ``rotary_at`` on [1, T, heads, D]. A row's padding is
+    computed as tokens: the rule is over whole rows."""
+    from paddle_tpu.kernels import flash_attn, head_norm_rotary
 
     enforce(not getattr(ctx, "packed", False),
             f"gqa_attention {cfg.name}: packed rows need a segment rule "
@@ -274,21 +279,33 @@ def _gqa_attention_forward(cfg, params, ins, ctx):
     H, Hkv, D = cfg.attr("num_heads"), cfg.attr("num_kv_heads"), cfg.attr("head_dim")
     eps, theta, rule = cfg.attr("eps", 1e-6), cfg.attr("rope_theta"), cfg.attr("mask")
     pos = flash_attn.positions(rule, T)
+    one_pass = head_norm_rotary.taken(cfg.name, T, H * D, D, D, x.dtype)
+    if one_pass:
+        # once a layer, outside the rows' loop
+        cos, sin = head_norm_rotary.tables(pos, theta, D, x.dtype)
 
     def mixer(x, p):
         """One row [T, d]."""
-        q = jnp.matmul(x, p["wq"]).reshape(1, T, H, D)
-        k = jnp.matmul(x, p["wk"]).reshape(1, T, Hkv, D)
-        v = jnp.matmul(x, p["wv"])[None]
         # 1 / sqrt(D) rides on the query's norm weight: one rounding
-        q = rotary_at(_head_norm(q, p["q_norm"], eps, D ** -0.5), pos, theta)
-        k = rotary_at(_head_norm(k, p["k_norm"], eps), pos, theta)
-        o = flash_attn.attention(cfg.name, q.reshape(1, T, H * D),
-                                 k.reshape(1, T, Hkv * D), v, rule, Hkv)
+        if one_pass:
+            q = head_norm_rotary.normed_rotated(
+                jnp.matmul(x, p["wq"]), p["q_norm"], cos, sin, eps, D ** -0.5)
+            k = head_norm_rotary.normed_rotated(
+                jnp.matmul(x, p["wk"]), p["k_norm"], cos, sin, eps)
+            v = jnp.matmul(x, p["wv"])[None]
+        else:
+            q = jnp.matmul(x, p["wq"]).reshape(1, T, H, D)
+            k = jnp.matmul(x, p["wk"]).reshape(1, T, Hkv, D)
+            v = jnp.matmul(x, p["wv"])[None]
+            q = rotary_at(_head_norm(q, p["q_norm"], eps, D ** -0.5), pos, theta)
+            k = rotary_at(_head_norm(k, p["k_norm"], eps), pos, theta)
+            q, k = q.reshape(1, T, H * D), k.reshape(1, T, Hkv * D)
+        o = flash_attn.attention(cfg.name, q, k, v, rule, Hkv)
         return jnp.matmul(o[0], p["wo"])
 
-    # a row's backward pass computes its projections again from the layer's
-    # input; what the kernels' forward launch made of the row it keeps
+    # a row's backward pass computes its projections, norms and rotary again
+    # from the layer's input; what the kernels' forward launch made of the
+    # row it keeps
     out = rows_one_at_a_time(mixer, x, params,
                              kept=("flash_attn_o", "flash_attn_lse"))
     return ins[0].with_value(out)
@@ -326,7 +343,7 @@ def _mla_attention_forward(cfg, params, ins, ctx):
     (kernels/flash_attn.py, head sizes Dn + Dr : Dv). No bias. The absorbed
     form over a latent cache is generation's, which this layer does not
     have."""
-    from paddle_tpu.kernels import flash_attn
+    from paddle_tpu.kernels import flash_attn, head_norm_rotary
 
     enforce(not getattr(ctx, "packed", False),
             f"mla_attention {cfg.name}: packed rows need a segment rule "
@@ -341,6 +358,10 @@ def _mla_attention_forward(cfg, params, ins, ctx):
         rule = ("causal", T)
     pos = flash_attn.positions(rule, T)
     f32 = jnp.promote_types(x.dtype, jnp.float32)
+    # rotary is on Dr of a head's Dn + Dr lanes and the norm is the latent's:
+    # outside head_norm_rotary's gate, which the log then says as
+    # gqa_attention's does; both stay `_head_norm` and `rotary_at`
+    head_norm_rotary.taken(cfg.name, T, H * (Dn + Dr), Dn + Dr, Dr, x.dtype)
 
     def mixer(x, p):
         """One row [T, d]."""

@@ -15,6 +15,7 @@ lives here for the same reason. Compiles run in this process, with the
 persistent compile cache off around them.
 """
 
+import hashlib
 import os
 import re
 
@@ -228,23 +229,39 @@ def test_qwen3_next_mixer_train_compiles_at_the_cells_size(one_chip, kind,
         assert names == [] and temp == 535_163_904, (names, temp)
 
 
+def _kernels_taken(monkeypatch, *modules):
+    """Held to the CPU, `take_pallas` would hand a layer its XLA form."""
+    for mod in modules:
+        monkeypatch.setattr(mod, "take_pallas",
+                            lambda who, kernel, eligible=True, why_not="",
+                            **kw: eligible)
+
+
+def _without_kernel_bodies(text):
+    """A lowered program's text without its Mosaic launches' serialized
+    bodies, which hold the source positions of every frame above the
+    `pallas_call` (PERF.md section 6, PR 36)."""
+    return re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
+                  'backend_config = ""', text)
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_sdar_attention_train_compiles_at_the_cells_size(one_chip, dtype,
                                                          monkeypatch):
     """value_and_grad of one attention layer as the SDAR cell runs it (2
     rows x 2 x 8192 positions x 2048, 32 : 4 heads of 128, the
     block-diffusion mask in blocks of 4, one row at a time): a row's
-    backward pass keeps what `flash_attn_fwd` made, so the compiled layer
-    holds one forward and one backward launch, by their names."""
+    backward pass keeps what `flash_attn_fwd` made and makes q's and k's
+    norm and rotary again, so the compiled layer holds one forward and one
+    backward flash launch, four `head_norm_rotary_fwd` and two
+    `head_norm_rotary_bwd`, by their names, and nowhere a projection laid
+    out again as `[.., heads, 128]`."""
     from paddle_tpu import data_type, layer
     from paddle_tpu.core.arg import Arg
     from paddle_tpu.core.topology import Topology
-    from paddle_tpu.kernels import flash_attn
+    from paddle_tpu.kernels import flash_attn, head_norm_rotary
 
-    # held to the CPU, `take_pallas` would hand the layer the tiles in XLA
-    monkeypatch.setattr(flash_attn, "take_pallas",
-                        lambda who, kernel, eligible=True, why_not="", **kw:
-                        eligible)
+    _kernels_taken(monkeypatch, flash_attn, head_norm_rotary)
     B, L, d = 2, 8192, 2048
     x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
     out = layer.gqa_attention(input=x, num_heads=32, num_kv_heads=4,
@@ -259,14 +276,25 @@ def test_sdar_attention_train_compiles_at_the_cells_size(one_chip, dtype,
                          training=True)["l"].value
         return jnp.sum(y.astype(jnp.float32) ** 2)
 
-    compiled, n = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params,
+    compiled, _ = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params,
                            _sds((B, 2 * L, d), dtype, one_chip))
     names = _mosaic_instructions(compiled)
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(f"gqa_attention {jnp.dtype(dtype).name}: {temp:,} bytes of temporaries")
-    assert n == 2 and len(names) == 2, names
+    assert len(names) == 8, names
     assert sum("flash_attn_fwd" in x for x in names) == 1, names
     assert sum("flash_attn_bwd" in x for x in names) == 1, names
+    assert sum("head_norm_rotary_fwd" in x for x in names) == 4, names
+    assert sum("head_norm_rotary_bwd" in x for x in names) == 2, names
+    text = compiled.as_text()
+    assert f"{2 * L},32,128]" not in text and f"{2 * L},4,128]" not in text
+
+
+# sha256 of the Kimi-VL attention layer's lowered text below, the launches'
+# bodies cut out, by this test's own code at the parent of PR 37 (f2d410a)
+KIMI_VL_ATTENTION_SHA256 = {
+    "bfloat16": "df4759c03a423b9c041ebd7c6ad2ca8a0202367af7ddc20ea4b98bbc6a0f8176",
+    "float32": "855b0e393c3177a5a4618f9cdc2bf2a41b13de28e08905c795338126583c448f"}
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -282,9 +310,7 @@ def test_kimi_vl_attention_train_compiles_at_the_cells_size(one_chip, dtype,
     from paddle_tpu.core.topology import Topology
     from paddle_tpu.kernels import flash_attn
 
-    monkeypatch.setattr(flash_attn, "take_pallas",
-                        lambda who, kernel, eligible=True, why_not="", **kw:
-                        eligible)
+    _kernels_taken(monkeypatch, flash_attn)
     B, L, d = 2, 8192, 2048
     x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
     out = layer.mla_attention(
@@ -310,6 +336,13 @@ def test_kimi_vl_attention_train_compiles_at_the_cells_size(one_chip, dtype,
     assert sum("flash_attn_bwd" in x for x in names) == 1, names
     # the launches take heads of 256 lanes: 16 x 256 a position
     assert f"[1,{L},4096]" in compiled.as_text()
+    # `_head_norm` and `rotary_at` serve this layer too, on a latent of 512
+    # and heads of 64 lanes: outside `head_norm_rotary`'s gate, so the layer
+    # lowers to the text it lowered to at the parent of PR 37 (f2d410a)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, _sds((B, L, d), dtype, one_chip)).as_text()
+    assert hashlib.sha256(_without_kernel_bodies(text).encode()).hexdigest() \
+        == KIMI_VL_ATTENTION_SHA256[jnp.dtype(dtype).name]
 
 
 @pytest.mark.parametrize("cell,B,T,I,E,held,k,extra", [

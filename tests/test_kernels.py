@@ -199,8 +199,8 @@ def test_maxpool_backward_on_ties_has_one_winner():
 
 #: every module under paddle_tpu/kernels/ that holds a pl.pallas_call. Wiring
 #: or deleting the one exception has to touch this list.
-_KERNEL_MODULES = ["crf", "flash_attn", "gdn", "gru", "lstm", "moe_grouped",
-                   "vocab_xent"]
+_KERNEL_MODULES = ["crf", "flash_attn", "gdn", "gru", "head_norm_rotary",
+                   "lstm", "moe_grouped", "vocab_xent"]
 _UNWIRED = {"vocab_xent": "ROADMAP S3 decides: measure at the NMT cell's "
                           "shape, then wire or delete"}
 
