@@ -106,12 +106,17 @@ def _gated_attention_flops(l, shapes, T) -> float:
 
 
 def _gqa_attention_flops(l, shapes, T) -> float:
-    """Per row of the 2L positions the layer's mask rule is over (whatever
-    ``T`` the caller counts the other layers at): the four projections, and
-    the scores and weighted sum of the pairs the block-diffusion rule keeps,
-    L^2 + sum of the squared block sizes of the 4 L^2."""
+    """Per row of the positions the layer's mask rule is over (whatever
+    ``T`` the caller counts the other layers at, under the block-diffusion
+    rule): the four projections, and the scores and weighted sum of the
+    pairs the rule keeps: L^2 + the sum of the squared block sizes of the
+    4 L^2 under block diffusion, T (T + 1) / 2 causal."""
     H, D = l.attr("num_heads"), l.attr("head_dim")
-    _, L, b = l.attr("mask")
+    rule = l.attr("mask")
+    if rule[0] == "causal":
+        proj = 2.0 * T * _numel(shapes, "wq", "wk", "wv", "wo")
+        return proj + 2 * 2.0 * H * D * T * (T + 1) / 2
+    _, L, b = rule
     kept = L * L + (L // b) * b * b + (L % b) ** 2
     proj = 2.0 * 2 * L * _numel(shapes, "wq", "wk", "wv", "wo")
     return proj + 2 * 2.0 * H * D * kept

@@ -6,7 +6,9 @@ time, computed tile by tile and only where the mask keeps something
 
 q arrives scaled (the layer folds 1/sqrt(D) into the query). The head size of
 q and k (Dk) may differ from that of v and o (Dv): latent attention's heads
-are 192 : 128 (docs/kimi_vl.md), padded to 256 : 128 by the layer. The mask is
+are 192 : 128 (docs/kimi_vl.md), padded to 256 : 128 by `attention`; LFM2's
+heads of 64 (docs/lfm2.md) go in as 128 : 128, the output's zero lanes cut
+off. The mask is
 a RULE, not an array: ``("causal", L)`` over the L positions of a row,
 keep(i, j) = j <= i, or ``("block_diffusion", L, b)`` over the 2L positions
 ``[noised ; clean]`` of a block-diffusion training row,
@@ -457,9 +459,11 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
 def kernel_supported(D, dtype, Dv=None):
-    """A value head of whole lanes (the q / k head goes in padded to them,
-    `attention`), in bf16 or float32."""
-    return (Dv or D) % 128 == 0 and dtype in (jnp.bfloat16, jnp.float32)
+    """A value head of whole lanes, or of 64 (it goes in padded to 128, as
+    the q / k head goes in padded to whole lanes, `attention`), in bf16 or
+    float32."""
+    return ((Dv or D) % 128 == 0 or (Dv or D) == 64) \
+        and dtype in (jnp.bfloat16, jnp.float32)
 
 
 # what the backward launch may hold of VMEM_LIMIT_BYTES by the estimate
@@ -487,13 +491,14 @@ def bwd_vmem_bytes(T, D, G, dtype, Dv=None):
 
 def kernel_gate(T, D, G, dtype, Dv=None):
     """(eligible, why not): the kernels cover value heads that fill the
-    lanes in bf16 / float32, on rows whose dK / dV (the q / k head padded to
-    whole lanes) fit in VMEM."""
+    lanes (or of 64, padded to them) in bf16 / float32, on rows whose dK /
+    dV (the heads padded to whole lanes) fit in VMEM."""
     if not kernel_supported(D, dtype, Dv):
         sizes = D if Dv in (None, D) else f"{D} : {Dv}"
         return False, (f"head size {sizes}, {jnp.dtype(dtype).name} is "
                        "outside the kernel's gate")
-    need = bwd_vmem_bytes(T, round_up(D, 128), G, dtype, Dv)[0]
+    need = bwd_vmem_bytes(T, round_up(D, 128), G, dtype,
+                          Dv and round_up(Dv, 128))[0]
     return need <= _VMEM_BUDGET, (
         f"over a row of {T} positions flash_attn_bwd would hold "
         f"{need / 1e6:.1f} MB in VMEM, dK and dV whole, against the "
@@ -516,9 +521,10 @@ def attention(who, q, k, v, rule, Hkv):
     B, T, _ = q.shape
     Dk, Dv, G = _head_sizes(q, k, v, Hkv)
     # the kernels read a head as whole lanes: a q / k head that is not
-    # (latent attention's 192) goes in with zeros behind it, which add
-    # nothing to a score
-    Dp = round_up(Dk, 128)
+    # (latent attention's 192, LFM2's 64) goes in with zeros behind it, which
+    # add nothing to a score; a value head of 64 goes in the same way, and
+    # its zero lanes are cut off the output
+    Dp, Dvp = round_up(Dk, 128), round_up(Dv, 128)
     bq, bk = tile_sizes(T)
     kept, whole, partial, every = plan_counts(tile_plan(rule, T, bq, bk))
     pallas = take_pallas(who, "flash_attn_fwd/bwd",
@@ -527,15 +533,21 @@ def attention(who, q, k, v, rule, Hkv):
     log_once(who, f"mask {rule}: {kept} of {every} tiles of {bq} x {bk} kept "
              f"({whole} whole, {partial} partial)")
     if pallas:
-        need, pair = bwd_vmem_bytes(T, Dp, G, q.dtype, Dv)
+        need, pair = bwd_vmem_bytes(T, Dp, G, q.dtype, Dvp)
         log_once(who, f"flash_attn_bwd: one walk of {kept} tiles, dK/dV "
                  f"{pair / 1e6:.1f} MB of {need / 1e6:.1f} MB in VMEM")
         if Dp != Dk:
             log_once(who, f"q and k heads of {Dk} go in as {Dp} lanes")
             q, k = _pad_heads(q, Dk, Dp), _pad_heads(k, Dk, Dp)
-        return call_kernel(
+        if Dvp != Dv:
+            log_once(who, f"value heads of {Dv} go in as {Dvp} lanes")
+            v = _pad_heads(v, Dv, Dvp)
+        o = call_kernel(
             lambda q, k, v: flash_attention(q, k, v, rule, Hkv, bq, bk),
             (q, k, v), range(3))
+        if Dvp == Dv:
+            return o
+        return o.reshape(B, T, -1, Dvp)[..., :Dv].reshape(B, T, -1)
     o = attention_tiles_xla(q.reshape(B, T, Hkv, G, Dk),
                             k.reshape(B, T, Hkv, Dk), v.reshape(B, T, Hkv, Dv),
                             rule, bq, bk)

@@ -90,22 +90,26 @@ def bwd_vmem_bytes(d, I, tile, dtype, buffers):
 
 
 def chunk_plan(d, I, tile, dtype):
-    """((chunk, buffers), "") where the kernels cover the shape, else
+    """((chunk, buffers, slices), "") where the kernels cover the shape, else
     (None, why not): an expert width of whole lanes, a hidden width whose
     row in a token sum is whole float32 tiles (8 sublane rows of 128), tiles
     of whole sublane groups, bf16 or float32, and an expert whose gradients
-    fit in VMEM beside its weights."""
+    fit in VMEM beside its weights: whole where they do (``slices`` 1),
+    else the backward launch walks the expert's width in ``slices`` slices
+    of whole lanes, each slice's gradients resident, the fewest that fit."""
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.bfloat16, jnp.float32) or d % 1024 or I % 128 \
             or tile % (32 // dtype.itemsize):
         return None, (f"widths {d} x {I}, tiles of {tile} rows, {dtype.name} "
                       "are outside the kernels' gate")
     chunk = max(1, CHUNK_BYTES // (tile * d * dtype.itemsize))
-    for buffers in (2, 1):
-        need = bwd_vmem_bytes(d, I, tile, dtype, buffers)[0]
-        if need <= _VMEM_BUDGET:
-            return (chunk, buffers), ""
-    return None, (f"an expert of {d} x {I} would hold {need / 1e6:.1f} MB in "
+    whole = bwd_vmem_bytes(d, I, tile, dtype, 1)[0]
+    for slices in (n for n in range(1, I // 128 + 1) if I % (128 * n) == 0):
+        for buffers in (2, 1):
+            need = bwd_vmem_bytes(d, I // slices, tile, dtype, buffers)[0]
+            if need <= _VMEM_BUDGET:
+                return (chunk, buffers, slices), ""
+    return None, (f"an expert of {d} x {I} would hold {whole / 1e6:.1f} MB in "
                   f"VMEM, its gradients whole, against the "
                   f"{_VMEM_BUDGET / 1e6:.1f} MB of the kernels' gate")
 
@@ -224,10 +228,24 @@ def _fwd_kernel(te_ref, meta_ref, tok_ref, x_ref, w_ref, wg_ref, wu_ref,
         sums.add(t, same, live, w_ref[...] * y)
 
 
+def _window(ref, expert, part, up):
+    """An expert's matrix of ``ref`` ([E, d, I] where ``up``, else [E, I,
+    d]), or slice s of S of its width (an up matrix's columns, a down
+    matrix's rows) where ``part`` is (s, S) with S > 1."""
+    s, S = part
+    if S == 1:
+        return ref.at[expert]
+    if up:
+        width = ref.shape[2] // S
+        return ref.at[expert, :, pl.ds(s * width, width)]
+    width = ref.shape[1] // S
+    return ref.at[expert, pl.ds(s * width, width), :]
+
+
 def _bwd_kernel(te_ref, meta_ref, tok_ref, x_ref, dy_ref, w_ref, wg_ref,
                 wu_ref, wd_ref, dx_in, dwg_in, dwu_in, dwd_in, drow_ref,
                 dx_out, dwg_out, dwu_out, dwd_out, dwg_acc, dwu_acc, dwd_acc,
-                sem, dx_buf, dx_sem, *, tile):
+                sem, dx_buf, dx_sem, *, tile, part):
     del dx_in                                 # aliased to dx_out
     t = pl.program_id(0)
     live, carried = meta_ref[0], meta_ref[1]
@@ -241,7 +259,8 @@ def _bwd_kernel(te_ref, meta_ref, tok_ref, x_ref, dy_ref, w_ref, wg_ref,
     def leave(expert):
         """The three DMAs that take the resident sums to ``expert``'s rows
         of the results."""
-        return [pltpu.make_async_copy(acc, out.at[expert], sem.at[i])
+        return [pltpu.make_async_copy(acc, _window(out, expert, part, i < 2),
+                                      sem.at[i])
                 for i, (acc, out) in enumerate(
                     zip(accs, (dwg_out, dwu_out, dwd_out)))]
 
@@ -269,7 +288,8 @@ def _bwd_kernel(te_ref, meta_ref, tok_ref, x_ref, dy_ref, w_ref, wg_ref,
 
         @pl.when(first & (t == 0) & (carried != 0))
         def _():
-            come = [pltpu.make_async_copy(src.at[e], acc, sem.at[i])
+            come = [pltpu.make_async_copy(_window(src, e, part, i < 2), acc,
+                                          sem.at[i])
                     for i, (src, acc) in enumerate(
                         zip((dwg_in, dwu_in, dwd_in), accs))]
             for copy in come:
@@ -310,9 +330,10 @@ def _params(interpret):
         dimension_semantics=("arbitrary",))}
 
 
-def _specs(d, I, tile, buffers):
+def _specs(d, I, tile, buffers, part=(0, 1)):
     """BlockSpecs by what a block follows: the tile (held at the last live
-    one past the tiles in use) or the tile's expert."""
+    one past the tiles in use) or the tile's expert (slice s of S of its
+    width where ``part`` is (s, S))."""
     vm = pltpu.VMEM
 
     def at(t, meta):
@@ -323,14 +344,17 @@ def _specs(d, I, tile, buffers):
                             lambda t, te, meta, tok: (at(t, meta), 0),
                             memory_space=vm)
 
-    def expert(shape):
+    s, S = part
+
+    def expert(shape, at_slice):
         mode = {} if buffers == 2 else {"pipeline_mode": pl.Buffered(buffers)}
         return pl.BlockSpec((1,) + shape,
-                            lambda t, te, meta, tok: (te[at(t, meta)], 0, 0),
-                            memory_space=vm, **mode)
+                            lambda t, te, meta, tok: (te[at(t, meta)],)
+                            + at_slice, memory_space=vm, **mode)
 
-    return {"x": rows(d), "col": rows(1), "up": expert((d, I)),
-            "down": expert((I, d)), "hbm": pl.BlockSpec(memory_space=pl.ANY)}
+    return {"x": rows(d), "col": rows(1), "up": expert((d, I // S), (0, s)),
+            "down": expert((I // S, d), (s, 0)),
+            "hbm": pl.BlockSpec(memory_space=pl.ANY)}
 
 
 # jitted so that a step traces and lowers each launch once, however many
@@ -358,24 +382,27 @@ def _fwd_call(te, meta, tok, xt, w, wg, wu, wd, y, tile, buffers, interpret):
             te, meta, tok, xt, w, wg, wu, wd, y)
 
 
-@functools.partial(jax.jit, static_argnums=(13, 14, 15))
+@functools.partial(jax.jit, static_argnums=(13, 14, 15, 16))
 def _bwd_call(te, meta, tok, xt, dyt, w, wg, wu, wd, dx, dwg, dwu, dwd, tile,
-              buffers, interpret):
+              buffers, interpret, part=(0, 1)):
     """(d row_w [chunk * tile, 1], dx, dwg, dwu, dwd): the chunk's
     row-weight gradient, and the float32 token sums ``dx`` and the three
-    float32 gradient arrays with the chunk's tiles added."""
+    float32 gradient arrays with the chunk's tiles added, over slice s of S
+    of the experts' width where ``part`` is (s, S) (its part of d row_w and
+    dx; its window of the gradients)."""
     (rows, d), I = xt.shape, wg.shape[-1]
-    spec = _specs(d, I, tile, buffers)
+    spec = _specs(d, I, tile, buffers, part)
+    Is = I // part[1]
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, tile=tile),
+        functools.partial(_bwd_kernel, tile=tile, part=part),
         name="moe_grouped_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(rows // tile,),
             in_specs=[spec["x"], spec["x"], spec["col"], spec["up"],
                       spec["up"], spec["down"]] + [spec["hbm"]] * 4,
             out_specs=[spec["col"]] + [spec["hbm"]] * 4,
-            scratch_shapes=[pltpu.VMEM((d, I), f32), pltpu.VMEM((d, I), f32),
-                            pltpu.VMEM((I, d), f32),
+            scratch_shapes=[pltpu.VMEM((d, Is), f32),
+                            pltpu.VMEM((d, Is), f32), pltpu.VMEM((Is, d), f32),
                             pltpu.SemaphoreType.DMA((3,))]
             + _RowSums.scratch(tile, d)),
         out_shape=[jax.ShapeDtypeStruct((rows, 1), f32)]
@@ -411,17 +438,20 @@ def _chunks(row_w, row_tok, tile_expert, n_tiles, tile, chunk, N):
     return row_tok.shape[0], chunk, -(-n_tiles // chunk), of
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
 def grouped_ffn(x, wg, wu, wd, row_w, row_tok, tile_expert, n_tiles, tile,
-                chunk, buffers, interpret=False):
+                chunk, buffers, interpret=False, slices=1):
     """``layers.moe.grouped_ffn`` by ``moe_grouped_fwd`` / ``moe_grouped_bwd``
-    over chunks of ``chunk`` tiles: the same arguments, the same sum."""
+    over chunks of ``chunk`` tiles: the same arguments, the same sum. The
+    forward launch takes an expert whole; with ``slices`` > 1 the backward
+    launch runs once a slice of the experts' width a chunk, the slices'
+    dx added into the same token sums and their d row_w summed."""
     return _grouped_fwd(x, wg, wu, wd, row_w, row_tok, tile_expert, n_tiles,
-                        tile, chunk, buffers, interpret)[0]
+                        tile, chunk, buffers, interpret, slices)[0]
 
 
 def _grouped_fwd(x, wg, wu, wd, row_w, row_tok, tile_expert, n_tiles, tile,
-                 chunk, buffers, interpret):
+                 chunk, buffers, interpret, slices):
     N, d = x.shape
     _, _, n_chunks, of = _chunks(row_w, row_tok, tile_expert, n_tiles, tile,
                                  chunk, N)
@@ -438,7 +468,7 @@ def _grouped_fwd(x, wg, wu, wd, row_w, row_tok, tile_expert, n_tiles, tile,
                                        tile_expert, n_tiles)
 
 
-def _grouped_bwd(tile, chunk, buffers, interpret, res, dy):
+def _grouped_bwd(tile, chunk, buffers, interpret, slices, res, dy):
     x, wg, wu, wd, row_w, row_tok, tile_expert, n_tiles = res
     N, d = x.shape
     Rp, chunk, n_chunks, of = _chunks(row_w, row_tok, tile_expert, n_tiles,
@@ -449,9 +479,12 @@ def _grouped_bwd(tile, chunk, buffers, interpret, res, dy):
         tok, w, te, meta = of(c)
         xt = jnp.take(x, tok, axis=0, mode="clip")
         dyt = jnp.take(dy, tok, axis=0, mode="fill", fill_value=0)
-        drt, dx, dwg, dwu, dwd = _bwd_call(
-            te, meta, tok, xt, dyt, w[:, None], wg, wu, wd, dx, dwg, dwu, dwd,
-            tile, buffers, interpret)
+        drt = None
+        for s in range(slices):
+            part, dx, dwg, dwu, dwd = _bwd_call(
+                te, meta, tok, xt, dyt, w[:, None], wg, wu, wd, dx, dwg, dwu,
+                dwd, tile, buffers, interpret, (s, slices))
+            drt = part if drt is None else drt + part
         # rows of a tile past the tiles in use were never written
         drow = jax.lax.dynamic_update_slice_in_dim(
             drow, jnp.where(tok < N, drt[:, 0], 0), c * chunk * tile, 0)

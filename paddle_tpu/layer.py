@@ -865,14 +865,25 @@ def gated_delta_net(input, num_k_heads, num_v_heads, head_k_dim, head_v_dim,
                  param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
 
 
+def short_conv(input, conv_kernel=3, scope=None, name=None, param_attr=None,
+               layer_attr=None):
+    """The gated short convolution (LFM2's conv mixer): [B ; C ; x~] = x W_in,
+    y = (C * causal depthwise conv over time of B * x~, ``conv_kernel``
+    taps, zeros before the row) W_out; no bias, no activation."""
+    return Layer("short_conv", [input], name=name, conv_kernel=conv_kernel,
+                 scope=scope, param_attrs=[to_param_attr(param_attr)],
+                 extra=layer_attr)
+
+
 def gqa_attention(input, num_heads, num_kv_heads, head_dim, mask,
                   rope_theta=10000.0, eps=1e-6, scope=None, name=None,
                   param_attr=None, layer_attr=None):
     """Grouped-query self-attention with per-head q/k RMS norm (weight w,
     from 1), rotary positions on the whole head and no gate, under a
     structured mask the layer knows by rule: ``mask=("block_diffusion", L,
-    b)`` for rows of 2L positions [noised ; clean] in blocks of b tokens.
-    Only the tiles the rule keeps are computed."""
+    b)`` for rows of 2L positions [noised ; clean] in blocks of b tokens,
+    ``mask=("causal", L)`` for rows of L positions. Only the tiles the rule
+    keeps are computed."""
     return Layer("gqa_attention", [input], name=name, num_heads=num_heads,
                  num_kv_heads=num_kv_heads, head_dim=head_dim,
                  rope_theta=rope_theta, eps=eps, mask=tuple(mask), scope=scope,
@@ -924,8 +935,8 @@ def noised_half(input, name=None):
 def moe_ffn(input, num_experts, top_k, expert_size, shared_size=None,
             experts_held=None, first_expert=0, tile=256, score="softmax",
             selection_bias=False, bias_rate=1e-3, route_scale=1.0,
-            shared_gate=True, scope=None, name=None, param_attr=None,
-            layer_attr=None):
+            shared_gate=True, route_norm_eps=0.0, scope=None, name=None,
+            param_attr=None, layer_attr=None):
     """Top-k mixture of gated-MLP experts, with a shared expert of
     ``shared_size`` where one is given (behind a sigmoid gate unless
     ``shared_gate=False``). The router is over all ``num_experts``, by
@@ -934,19 +945,20 @@ def moe_ffn(input, num_experts, top_k, expert_size, shared_size=None,
     ``selection_bias`` the top k are chosen by score + bias, the weights
     staying the scores' own; the bias is state that moves by ``bias_rate``
     against each expert's load after every step. The renormalised weights
-    are scaled by ``route_scale``."""
+    (over their sum + ``route_norm_eps``) are scaled by ``route_scale``."""
     return Layer("moe_ffn", [input], name=name, num_experts=num_experts,
                  top_k=top_k, expert_size=expert_size, shared_size=shared_size,
                  experts_held=experts_held or num_experts,
                  first_expert=first_expert, tile=tile, score=score,
                  selection_bias=selection_bias, bias_rate=bias_rate,
-                 route_scale=route_scale, shared_gate=shared_gate, scope=scope,
+                 route_scale=route_scale, shared_gate=shared_gate,
+                 route_norm_eps=route_norm_eps, scope=scope,
                  param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
 
 
 __all__ += ["rms_norm", "gated_attention", "gated_delta_net", "moe_ffn",
             "gqa_attention", "block_diffusion_noise", "noised_half",
-            "mla_attention", "gated_mlp"]
+            "mla_attention", "gated_mlp", "short_conv"]
 
 
 # --- detection (SSD) ------------------------------------------------------

@@ -16,6 +16,7 @@ from paddle_tpu.layers import recurrent_group  # noqa: F401
 from paddle_tpu.layers import crf_ctc     # noqa: F401
 from paddle_tpu.layers import attention   # noqa: F401
 from paddle_tpu.layers import delta_net   # noqa: F401
+from paddle_tpu.layers import short_conv  # noqa: F401
 from paddle_tpu.layers import moe         # noqa: F401
 from paddle_tpu.layers import diffusion   # noqa: F401
 from paddle_tpu.layers import detection   # noqa: F401

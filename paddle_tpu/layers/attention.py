@@ -257,12 +257,14 @@ def _head_norm(x, w, eps, scale=1.0):
 
 @register_layer("gqa_attention", params=_gqa_params)
 def _gqa_attention_forward(cfg, params, ins, ctx):
-    """Grouped-query self-attention as SDAR's blocks have it (docs/sdar.md):
-    per-head RMS norm of q and k with a learned weight w, rotate-half rotary
-    on the whole head at the position the mask's rule gives each step,
-    softmax(q k^T / sqrt(D)) under the rule's mask, no gate, no bias. The
-    rule (``mask``) is known at trace time and the scores are computed tile
-    by tile where it keeps something (kernels/flash_attn.py). On the TPU,
+    """Grouped-query self-attention as SDAR's blocks (docs/sdar.md) and
+    LFM2's attention blocks (docs/lfm2.md, heads of 64 under the causal
+    rule) have it: per-head RMS norm of q and k with a learned weight w,
+    rotate-half rotary on the whole head at the position the mask's rule
+    gives each step, softmax(q k^T / sqrt(D)) under the rule's mask, no
+    gate, no bias. The rule (``mask``) is known at trace time and the scores
+    are computed tile by tile where it keeps something
+    (kernels/flash_attn.py). On the TPU,
     where a head is one group of 128 lanes, the norm and rotary of q and of k
     are one Mosaic launch each way, ``head_norm_rotary_fwd`` /
     ``head_norm_rotary_bwd`` (kernels/head_norm_rotary.py), on the
@@ -278,6 +280,8 @@ def _gqa_attention_forward(cfg, params, ins, ctx):
     B, T, _ = x.shape
     H, Hkv, D = cfg.attr("num_heads"), cfg.attr("num_kv_heads"), cfg.attr("head_dim")
     eps, theta, rule = cfg.attr("eps", 1e-6), cfg.attr("rope_theta"), cfg.attr("mask")
+    if rule == ("causal", None):         # rows of any one length
+        rule = ("causal", T)
     pos = flash_attn.positions(rule, T)
     one_pass = head_norm_rotary.taken(cfg.name, T, H * D, D, D, x.dtype)
     if one_pass:

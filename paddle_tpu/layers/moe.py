@@ -10,8 +10,9 @@ layer (docs/qwen3_next.md), and the plain gated MLP an expert is.
     shared expert: no ``shared_*`` leaf, no shared term
 
 With ``score="sigmoid"`` p = sigmoid(x Wr); with ``selection_bias`` the top k
-are chosen by p + b while the weights stay the chosen p, renormalised and
-scaled by ``route_scale``; ``shared_gate=False`` leaves the shared expert's
+are chosen by p + b while the weights stay the chosen p, renormalised (over
+their sum + ``route_norm_eps``, LFM2's 1e-6, where one is given) and scaled by
+``route_scale``; ``shared_gate=False`` leaves the shared expert's
 gate out (docs/kimi_vl.md). b is state, not a parameter: no gradient reaches
 it (it only decides an index), it starts at 0, and after every step
 b_e += bias_rate * sign(mean(c) - c_e) from the step's own counts c_e of
@@ -265,16 +266,22 @@ def _grouped_products(who, d, I, tile, dtype):
     if not take_pallas(who, "moe_grouped_fwd/bwd", plan is not None, why,
                        otherwise="the tile loop"):
         return lambda *args: grouped_ffn(*args, tile), 1
-    chunk, buffers = plan
-    need, grads = moe_grouped.bwd_vmem_bytes(d, I, tile, dtype, buffers)
+    chunk, buffers, slices = plan
+    need, grads = moe_grouped.bwd_vmem_bytes(d, I // slices, tile, dtype,
+                                             buffers)
     log_once(who, f"moe_grouped_fwd/bwd take chunks of {chunk} tiles of "
              f"{tile} rows; an expert's weights in {buffers} buffer"
              f"{'s' if buffers > 1 else ''}, its gradients {grads / 1e6:.1f} "
-             f"MB of {need / 1e6:.1f} MB in VMEM")
+             f"MB of {need / 1e6:.1f} MB in VMEM"
+             + (f" a slice: moe_grouped_bwd walks the width of {I} in "
+                f"{slices} slices of {I // slices}" if slices > 1 else ""))
+
+    sliced = (False, slices) if slices > 1 else ()
 
     def grouped(*args):
         return call_kernel(
-            lambda *a: moe_grouped.grouped_ffn(*a, tile, chunk, buffers),
+            lambda *a: moe_grouped.grouped_ffn(*a, tile, chunk, buffers,
+                                               *sliced),
             args, (0,))
 
     return grouped, chunk
@@ -342,7 +349,9 @@ def route(x, p, cfg, valid):
         top = jnp.take_along_axis(probs, idx, axis=-1)
     else:
         top, idx = jax.lax.top_k(probs, k)
-    top = top / jnp.sum(top, -1, keepdims=True)
+    eps = cfg.attr("route_norm_eps", 0.0)
+    top = top / (jnp.sum(top, -1, keepdims=True) + eps if eps
+                 else jnp.sum(top, -1, keepdims=True))
     scale = cfg.attr("route_scale", 1.0)
     if scale != 1.0:
         top = top * scale

@@ -440,3 +440,94 @@ def kimi_vl_lm_cost(vocab_size=163840, hidden_size=2048,
     probs = layer.fc(input=x, size=vocab_size, act=act.Softmax(),
                      bias_attr=False, name=f"{name}_head")
     return layer.classification_cost(input=probs, label=nxt, name="cost")
+
+
+def lfm2_moe_lm_cost(vocab_size=65536, hidden_size=2048,
+                     intermediate_size=7168, moe_intermediate_size=1792,
+                     num_hidden_layers=24, num_attention_heads=32,
+                     num_key_value_heads=8, layer_types=None,
+                     layers_held=None, num_dense_layers=2, num_experts=32,
+                     num_experts_per_tok=4, routed_scaling_factor=1.0,
+                     conv_L_cache=3, rope_theta=1000000, norm_eps=1e-5,
+                     experts_held=None, first_expert=0, bias_update_rate=1e-3,
+                     conv_layers=None, attention_layers=None, seq_len=None,
+                     name="f"):
+    """Next-token cost of an LFM2-MoE decoder (docs/lfm2.md): blocks of
+    ``h = x + mixer(norm(x)); y = h + ffn(norm(h))`` whose mixer is the gated
+    short convolution where ``layer_types`` says ``conv`` and grouped-query
+    attention (per-head q / k norms, rotary on the whole head, causal) where
+    it says ``full_attention``; the ffn is a dense gated MLP in the first
+    ``num_dense_layers`` blocks and a sigmoid-routed MoE chosen through a
+    selection bias (a rule, not a gradient, keeps it level), no shared
+    expert, in the others; a final norm and a head tied to the embedding.
+    ``layers_held`` names the blocks of ``layer_types`` built here (the
+    first ``num_hidden_layers`` by default), and ``conv_layers`` /
+    ``attention_layers`` (where given) must count their mixers. Every MoE
+    routes over all ``num_experts`` and holds ``experts_held`` of them from
+    ``first_expert`` on (all by default). Rows are ``seq_len`` positions
+    where given (any one length otherwise). Feeds: ids / next_ids integer
+    sequences."""
+    from paddle_tpu.utils.error import enforce
+
+    layer_types = list(layer_types or ["conv"] * num_hidden_layers)
+    held = list(range(num_hidden_layers) if layers_held is None
+                else layers_held)
+    enforce(len(held) == num_hidden_layers
+            and all(0 <= l < len(layer_types) for l in held),
+            f"lfm2: layers_held {held} are not {num_hidden_layers} blocks of "
+            f"the {len(layer_types)} in layer_types")
+    kinds = [layer_types[l] for l in held]
+    enforce(set(kinds) <= {"conv", "full_attention"},
+            f"lfm2: layer types {sorted(set(kinds))} are not conv / "
+            "full_attention")
+    for want, kind in ((conv_layers, "conv"),
+                       (attention_layers, "full_attention")):
+        enforce(want is None or want == kinds.count(kind),
+                f"lfm2: the blocks held have {kinds.count(kind)} {kind} "
+                f"mixers, the arguments say {want}")
+    ids = layer.data(name="ids",
+                     type=data_type.integer_value_sequence(vocab_size))
+    nxt = layer.data(name="next_ids",
+                     type=data_type.integer_value_sequence(vocab_size))
+    table = ParamAttr(name=f"_{name}_emb.w0")
+    x = layer.embedding(input=ids, size=hidden_size, param_attr=table,
+                        name=f"{name}_emb")
+    head_dim = hidden_size // num_attention_heads
+    for l in held:
+        b = f"{name}_l{l}"
+        normed = layer.rms_norm(input=x, eps=norm_eps, zero_centered=False,
+                                name=f"{b}_in_norm")
+        if layer_types[l] == "conv":
+            mixed = layer.short_conv(input=normed, conv_kernel=conv_L_cache,
+                                     scope=f"lfm2/l{l}/conv", name=f"{b}_conv")
+        else:
+            mixed = layer.gqa_attention(
+                input=normed, num_heads=num_attention_heads,
+                num_kv_heads=num_key_value_heads, head_dim=head_dim,
+                rope_theta=rope_theta, eps=norm_eps, mask=("causal", seq_len),
+                scope=f"lfm2/l{l}/attn", name=f"{b}_attn")
+        h = layer.addto(input=[x, mixed], act=act.Linear(), bias_attr=False,
+                        name=f"{b}_h")
+        normed = layer.rms_norm(input=h, eps=norm_eps, zero_centered=False,
+                                name=f"{b}_post_norm")
+        if l < num_dense_layers:
+            ffn = layer.gated_mlp(input=normed, size=intermediate_size,
+                                  scope=f"lfm2/l{l}/mlp", name=f"{b}_mlp")
+        else:
+            ffn = layer.moe_ffn(
+                input=normed, num_experts=num_experts,
+                top_k=num_experts_per_tok, expert_size=moe_intermediate_size,
+                experts_held=experts_held, first_expert=first_expert,
+                score="sigmoid", selection_bias=True,
+                bias_rate=bias_update_rate,
+                route_scale=routed_scaling_factor, route_norm_eps=1e-6,
+                scope=f"lfm2/l{l}/moe", name=f"{b}_moe")
+        x = layer.addto(input=[h, ffn], act=act.Linear(), bias_attr=False,
+                        name=f"{b}_out")
+    x = layer.rms_norm(input=x, eps=norm_eps, zero_centered=False,
+                       name=f"{name}_final_norm")
+    probs = layer.mixed(
+        size=vocab_size, act=act.Softmax(), bias_attr=False,
+        input=layer.trans_full_matrix_projection(x, param_attr=table),
+        name=f"{name}_head")
+    return layer.classification_cost(input=probs, label=nxt, name="cost")

@@ -350,6 +350,8 @@ def test_kimi_vl_attention_train_compiles_at_the_cells_size(one_chip, dtype,
     ("kimivl", 2, 8192, 1408, 64, 8, 6,
      dict(score="sigmoid", selection_bias=True, route_scale=2.446)),
     ("qwen3next", 4, 4096, 512, 512, 16, 10, {}),
+    ("lfm2", 2, 8192, 1792, 32, 8, 4,
+     dict(score="sigmoid", selection_bias=True, route_norm_eps=1e-6)),
 ])
 def test_moe_ffn_train_compiles_at_the_cells_size(one_chip, monkeypatch, cell,
                                                   B, T, I, E, held, k, extra):
@@ -371,7 +373,8 @@ def test_moe_ffn_train_compiles_at_the_cells_size(one_chip, monkeypatch, cell,
                         eligible)
     d, dtype = 2048, jnp.bfloat16
     plan, why = moe_grouped.chunk_plan(d, I, 256, dtype)
-    assert plan == (16, 1 if cell == "kimivl" else 2), (plan, why)
+    assert plan == {"kimivl": (16, 1, 1), "lfm2": (16, 2, 2)}.get(
+        cell, (16, 2, 1)), (plan, why)
     x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
     out = layer.moe_ffn(input=x, num_experts=E, top_k=k, expert_size=I,
                         experts_held=held, name="l", **extra)
@@ -390,9 +393,89 @@ def test_moe_ffn_train_compiles_at_the_cells_size(one_chip, monkeypatch, cell,
     names = _mosaic_instructions(compiled)
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(f"moe_ffn {cell}: {temp:,} bytes of temporaries")
+    slices = plan[2]
     assert sum("moe_grouped_fwd" in x for x in names) == 1, names
-    assert sum("moe_grouped_bwd" in x for x in names) == 1, names
+    assert sum("moe_grouped_bwd" in x for x in names) == slices, names
+    assert len(names) == 1 + slices, names
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_lfm2_short_conv_train_compiles_at_the_cells_size(one_chip, dtype,
+                                                          monkeypatch):
+    """value_and_grad of one gated short convolution as the LFM2 cell runs
+    it (2 rows x 8192 positions x 2048, 3 taps): `short_conv_fwd` in the
+    forward pass and again in the backward pass (the mixer keeps its input
+    alone; W_out's gradient needs y), and one `short_conv_bwd`, by their
+    names, and no other launch."""
+    from paddle_tpu import data_type, layer
+    from paddle_tpu.core.arg import Arg
+    from paddle_tpu.core.topology import Topology
+    from paddle_tpu.kernels import short_conv
+
+    _kernels_taken(monkeypatch, short_conv)
+    B, L, d = 2, 8192, 2048
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
+    topo = Topology(layer.short_conv(input=x, conv_kernel=3, name="l"))
+    params = {k: _sds(s.shape, dtype, one_chip)
+              for k, s in topo.param_specs().items()}
+
+    def loss(params, x):
+        y = topo.forward(params, {"x": Arg(x, jnp.ones((B, L)))},
+                         training=True)["l"].value
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled, _ = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params,
+                           _sds((B, L, d), dtype, one_chip))
+    names = _mosaic_instructions(compiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"short_conv {jnp.dtype(dtype).name}: {temp:,} bytes of "
+          f"temporaries, {names}")
+    assert sum("short_conv_bwd" in x for x in names) == 1, names
+    assert sum("short_conv_fwd" in x for x in names) == 2, names
+    assert len(names) == 3, names
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_lfm2_attention_train_compiles_at_the_cells_size(one_chip, dtype,
+                                                         monkeypatch):
+    """value_and_grad of one LFM2 attention layer as the cell runs it (2 rows
+    x 8192 positions x 2048, 32 : 8 heads of 64, the causal mask, one row at
+    a time): one forward and one backward flash launch, by their names, with
+    q, k and v heads of 64 padded to 128 lanes (32 x 128 a position of q);
+    q's and k's norm and rotary stay in XLA (`head_norm_rotary` takes heads
+    of 128 lanes only)."""
+    from paddle_tpu import data_type, layer
+    from paddle_tpu.core.arg import Arg
+    from paddle_tpu.core.topology import Topology
+    from paddle_tpu.kernels import flash_attn, head_norm_rotary
+
+    _kernels_taken(monkeypatch, flash_attn, head_norm_rotary)
+    B, L, d = 2, 8192, 2048
+    assert flash_attn.kernel_gate(L, 64, 4, dtype) == (True, flash_attn.
+                                                       kernel_gate(L, 64, 4, dtype)[1])
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
+    out = layer.gqa_attention(input=x, num_heads=32, num_kv_heads=8,
+                              head_dim=64, rope_theta=1e6, eps=1e-5,
+                              mask=("causal", L), name="l")
+    topo = Topology(out)
+    params = {k: _sds(s.shape, dtype, one_chip)
+              for k, s in topo.param_specs().items()}
+
+    def loss(params, x):
+        y = topo.forward(params, {"x": Arg(x, jnp.ones((B, L)))},
+                         training=True)["l"].value
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled, _ = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params,
+                           _sds((B, L, d), dtype, one_chip))
+    names = _mosaic_instructions(compiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"lfm2 gqa_attention {jnp.dtype(dtype).name}: {temp:,} bytes of "
+          f"temporaries")
     assert len(names) == 2, names
+    assert sum("flash_attn_fwd" in x for x in names) == 1, names
+    assert sum("flash_attn_bwd" in x for x in names) == 1, names
+    assert f"[1,{L},4096]" in compiled.as_text()
 
 
 # the LSTM classifier (B64/H512/T100) and the split backward past the

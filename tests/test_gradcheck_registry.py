@@ -803,6 +803,14 @@ def _b_mla_attention():
             {"x": _seq(5, 6, ragged=False)})
 
 
+@build("short_conv")
+def _b_short_conv():
+    # 3 taps over 5 positions: the first two see zeros before the row
+    x = _data_seq("x", 6)
+    return (layer.short_conv(input=x, conv_kernel=3),
+            {"x": _seq(5, 6, ragged=False)})
+
+
 @build("gated_mlp")
 def _b_gated_mlp():
     x = _data_seq("x", 6)

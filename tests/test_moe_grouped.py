@@ -86,6 +86,25 @@ def test_the_kernels_match_the_tile_loop(plan, I, dtype):
     power of two (256) and are not (3 x 128 and 11 x 128 lanes, as 768 and
     1408 are); the plan drops nothing and its two counters read what a walk
     of `tile_expert` gives."""
+    _check_against_the_tile_loop(plan, I, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("I,slices", [(256, 2), (384, 3), (896, 7)])
+@pytest.mark.parametrize("plan", ["mixed", "long", "short"])
+def test_a_width_walked_in_slices_matches_the_tile_loop(plan, I, slices,
+                                                        dtype):
+    """The backward launch over the experts' width in slices of whole lanes
+    (what `chunk_plan` gives an expert whose gradients do not fit whole, as
+    LFM2's 1792 in 2 x 896): each slice's window of the three gradients,
+    the slices' dx in one token sum and their d row_w summed are the tile
+    loop's, over runs that cross a chunk's edge (the carried sums read back
+    slice by slice)."""
+    _check_against_the_tile_loop(plan, I, dtype, slices)
+
+
+def _check_against_the_tile_loop(plan, I, dtype, slices=1):
     sizes = PLANS[plan]
     idx, top, valid = _plan(sizes)
     N = idx.shape[0]
@@ -105,8 +124,8 @@ def test_the_kernels_match_the_tile_loop(plan, I, dtype):
     (_, (y0, te, nt, stats0)), g0 = loss(
         lambda *a: moe.grouped_ffn(*a, TILE), 1)(x, wg, wu, wd, top)
     (_, (y1, _, _, stats1)), g1 = loss(
-        lambda *a: moe_grouped.grouped_ffn(*a, TILE, CHUNK, 2, True), CHUNK)(
-            x, wg, wu, wd, top)
+        lambda *a: moe_grouped.grouped_ffn(*a, TILE, CHUNK, 2, True, slices),
+        CHUNK)(x, wg, wu, wd, top)
     nt = int(nt)
     assert nt == sum(-(-s // TILE) for s in sizes)
     _close(y1, y0, dtype)
@@ -141,12 +160,17 @@ def test_one_buffer_for_the_weights_gives_the_same_numbers():
 
 
 @pytest.mark.parametrize("d,I,tile,dtype,want", [
-    (2048, 768, 256, jnp.bfloat16, (16, 2)),     # the SDAR cell's expert
-    (2048, 512, 256, jnp.bfloat16, (16, 2)),     # the Qwen3-Next cell's
-    (2048, 1408, 256, jnp.bfloat16, (16, 1)),    # Kimi-VL's: one buffer
-    (2048, 768, 256, jnp.float32, (8, 2)),
-    (1024, 512, 128, jnp.bfloat16, (64, 2)),
-    (2048, 2816, 256, jnp.bfloat16, None),       # gradients past VMEM
+    # the cells' experts keep the plan they had before a width could be
+    # sliced: whole, in the same buffers
+    (2048, 768, 256, jnp.bfloat16, (16, 2, 1)),  # the SDAR cell's expert
+    (2048, 512, 256, jnp.bfloat16, (16, 2, 1)),  # the Qwen3-Next cell's
+    (2048, 1408, 256, jnp.bfloat16, (16, 1, 1)),  # Kimi-VL's: one buffer
+    (2048, 768, 256, jnp.float32, (8, 2, 1)),
+    (1024, 512, 128, jnp.bfloat16, (64, 2, 1)),
+    # gradients past VMEM whole: the fewest slices of whole lanes that fit
+    (2048, 1792, 256, jnp.bfloat16, (16, 2, 2)),  # LFM2's: 2 x 896
+    (2048, 2816, 256, jnp.bfloat16, (16, 1, 2)),  # 2 x 1408, one buffer
+    (2048, 1792, 256, jnp.float32, (8, 1, 2)),
     (2048, 768, 200, jnp.bfloat16, None),        # a tile off the sublanes
     (2048, 700, 256, jnp.bfloat16, None),        # a width off the lanes
     (1152, 768, 256, jnp.bfloat16, None),        # a token's row off the tiles
@@ -157,8 +181,14 @@ def test_the_gate_follows_the_shapes(d, I, tile, dtype, want):
     assert plan == want, (plan, why)
     assert (why == "") == (want is not None)
     if want is not None:
-        need, grads = moe_grouped.bwd_vmem_bytes(d, I, tile, dtype, want[1])
-        assert grads == 3 * d * I * 4 < need <= moe_grouped._VMEM_BUDGET
+        chunk, buffers, slices = want
+        need, grads = moe_grouped.bwd_vmem_bytes(d, I // slices, tile, dtype,
+                                                 buffers)
+        assert grads == 3 * d * I // slices * 4 < need \
+            <= moe_grouped._VMEM_BUDGET
+        if slices > 1:     # whole, one buffer, it would not fit
+            assert moe_grouped.bwd_vmem_bytes(d, I, tile, dtype, 1)[0] \
+                > moe_grouped._VMEM_BUDGET
 
 
 def test_a_layer_takes_the_kernels_where_the_gate_passes(monkeypatch):

@@ -101,7 +101,7 @@ def main():
         plan, why = moe_grouped.chunk_plan(d, I, tile, jnp.bfloat16)
         if plan is None:
             print(json.dumps({"cell": cell, "kernels": why}), flush=True)
-        chunk, buffers = plan or (1, 2)
+        chunk, buffers, slices = plan or (1, 2, 1)
         chunk, buffers = a.chunk or chunk, a.buffers or buffers
         ks = jax.random.split(jax.random.PRNGKey(0), 6)
         x = jax.random.normal(ks[0], (N, d), jnp.bfloat16)
@@ -113,7 +113,7 @@ def main():
         forms = {
             "tile_loop": lambda *p: moe.grouped_ffn(*p, tile),
             "kernels": lambda *p: moe_grouped.grouped_ffn(
-                *p, tile, chunk, buffers, not on_tpu),
+                *p, tile, chunk, buffers, not on_tpu, slices),
         }
         for bonus in bonuses:
             logits = jax.random.gumbel(ks[5], (N, E), f32) \
