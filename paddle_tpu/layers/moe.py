@@ -56,6 +56,20 @@ variable):
     of the sum's layout). Every rounding point is the tile loop's. Bytes and
     times by cell: docs/qwen3_next.md, docs/sdar.md, docs/kimi_vl.md.
 
+The layer runs under ``jax.checkpoint``: its backward pass makes the
+forward again, the shared expert's products included, but not the routing
+plan. The checkpoint's policy keeps what the forward decided: top_k's
+chosen experts, and by the names in ``PLAN_NAMES`` their scores before
+renormalisation [N, k], each pair's place in the buffer [N k], the buffer's
+tokens and weights [R], each tile's expert and the tiles in use; a few MB a
+layer, integers but for the scores and the rows' weights. So the backward
+pass runs the router's product and score function again (their VJP needs
+the scores), and of the plan only the two transposes: the gather of d
+row_w at ``place`` and the scatter of the top-k's gradient into the
+scores; no top_k, cumulative sum, rank gather or scatter of the plan. The
+plan runs under ``jax.named_scope("moe_plan")``, so a profile shows what
+each direction spends on it.
+
 ``dispatch_plan`` counts what either form does: ``paddle_moe_tiles_total``
 (tiles in use, padding included) and ``paddle_moe_expert_fetches_total``
 ((trip, expert) runs: how often an expert's weights are fetched and its
@@ -76,6 +90,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.attr import ParamAttr
 from paddle_tpu.core.layer import (ParamSpec, register_layer,
@@ -86,6 +101,15 @@ from paddle_tpu.kernels._pallas_util import (batch_shards, call_kernel,
 from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.utils.error import enforce
 
+# what the layer's checkpoint keeps: top_k's results (the chosen experts:
+# top_k's JVP gathers the scores' tangent at the indices top_k itself made,
+# which no name given afterwards reaches) and the rest of the routing plan
+# by name
+PLAN_NAMES = ("moe_plan_top", "moe_plan_place", "moe_plan_row_tok",
+              "moe_plan_row_w", "moe_plan_tile_expert", "moe_plan_n_tiles")
+_KEEP_THE_PLAN = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.save_only_these_names(*PLAN_NAMES),
+    lambda prim, *_, **__: prim is jax.lax.top_k_p)
 STATS = ("held", "elsewhere", "load_max_over_mean", "dropped", "tiles",
          "fetches")
 
@@ -287,6 +311,20 @@ def _grouped_products(who, d, I, tile, dtype):
     return grouped, chunk
 
 
+def _buffer_rows(N, k, held, tile):
+    """Rows of the dispatch buffer: every token choosing min(k, held) held
+    experts, each expert's run padded to whole tiles."""
+    return -(-N * min(k, held) // tile) * tile + held * tile
+
+
+def plan_bytes(N, k, held, tile):
+    """Bytes of the routing plan the layer's checkpoint keeps for N tokens
+    (the chosen experts and PLAN_NAMES): three arrays of N k and two of R
+    values of 4 bytes, each tile's expert and the count of tiles."""
+    R = _buffer_rows(N, k, held, tile)
+    return 4 * (3 * N * k + 2 * R + R // tile + 1)
+
+
 def dispatch_plan(idx, top, valid, first, held, tile, chunk=1):
     """Where each (token, chosen expert) pair goes. idx, top [N, k]: the
     chosen experts and their renormalised weights; valid [N] bool (real
@@ -295,7 +333,7 @@ def dispatch_plan(idx, top, valid, first, held, tile, chunk=1):
     rows, each tile's expert, the tiles in use, and the STATS vector
     (float32)."""
     N, k = idx.shape
-    R = -(-N * min(k, held) // tile) * tile + held * tile
+    R = _buffer_rows(N, k, held, tile)
     local = idx - first
     here = (local >= 0) & (local < held) & valid[:, None]
     local = jnp.where(here, local, held).reshape(-1)              # [N * k]
@@ -306,15 +344,22 @@ def dispatch_plan(idx, top, valid, first, held, tile, chunk=1):
     tiles = -(-sizes // tile)
     tile_end = jnp.cumsum(tiles)
     offset = (tile_end - tiles) * tile
-    place = jnp.where(local < held,
-                      offset[jnp.minimum(local, held - 1)] + rank, R)
+    # every place is unique, so the two scatters below may say so: a held
+    # pair's is its row, a pair held elsewhere gets a place of its own past
+    # the buffer, which the scatters drop. So row_w's tangent is a scatter at
+    # `place` and its transpose a gather there, with no pass that finds
+    # which of two updates of a row won
+    place = checkpoint_name(jnp.where(
+        local < held, offset[jnp.minimum(local, held - 1)] + rank,
+        R + jnp.arange(N * k, dtype=jnp.int32)), "moe_plan_place")
     token = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
-    row_tok = jnp.full((R,), N, jnp.int32).at[place].set(token, mode="drop")
-    row_w = jnp.zeros((R,), top.dtype).at[place].set(top.reshape(-1),
-                                                     mode="drop")
-    tile_expert = jnp.minimum(
+    row_tok = checkpoint_name(jnp.full((R,), N, jnp.int32).at[place].set(
+        token, mode="drop", unique_indices=True), "moe_plan_row_tok")
+    row_w = checkpoint_name(jnp.zeros((R,), top.dtype).at[place].set(
+        top.reshape(-1), mode="drop", unique_indices=True), "moe_plan_row_w")
+    tile_expert = checkpoint_name(jnp.minimum(
         jnp.searchsorted(tile_end, jnp.arange(R // tile), side="right"),
-        held - 1).astype(jnp.int32)
+        held - 1).astype(jnp.int32), "moe_plan_tile_expert")
     n_held = jnp.sum(sizes)
     n_real = jnp.sum(valid.astype(jnp.int32)) * k
     f32 = jnp.float32
@@ -322,7 +367,7 @@ def dispatch_plan(idx, top, valid, first, held, tile, chunk=1):
     stats = jnp.stack([n_held.astype(f32), (n_real - n_held).astype(f32),
                        jnp.max(sizes).astype(f32) / mean,
                        jnp.sum((place >= R) & (local < held)).astype(f32)])
-    n_tiles = tile_end[-1]
+    n_tiles = checkpoint_name(tile_end[-1], "moe_plan_n_tiles")
     # a trip fetches an expert where its first tile starts: at the trip's
     # first tile and wherever the expert differs from the tile before
     t = jnp.arange(R // tile)
@@ -349,6 +394,7 @@ def route(x, p, cfg, valid):
         top = jnp.take_along_axis(probs, idx, axis=-1)
     else:
         top, idx = jax.lax.top_k(probs, k)
+    top = checkpoint_name(top, "moe_plan_top")
     eps = cfg.attr("route_norm_eps", 0.0)
     top = top / (jnp.sum(top, -1, keepdims=True) + eps if eps
                  else jnp.sum(top, -1, keepdims=True))
@@ -380,9 +426,10 @@ def _moe_ffn_forward(cfg, params, ins, ctx):
     def moe(x, valid, p):
         x = x.reshape(-1, d)
         acc = _acc(x.dtype)
-        idx, top, bias = route(x, p, cfg, valid.reshape(-1))
-        row_w, row_tok, tile_expert, n_tiles, stats = dispatch_plan(
-            idx, top, valid.reshape(-1), first, held, tile, chunk)
+        with jax.named_scope("moe_plan"):
+            idx, top, bias = route(x, p, cfg, valid.reshape(-1))
+            row_w, row_tok, tile_expert, n_tiles, stats = dispatch_plan(
+                idx, top, valid.reshape(-1), first, held, tile, chunk)
         out = grouped(x, p["wg"], p["wu"], p["wd"], row_w, row_tok,
                       tile_expert, n_tiles)
         if bias is not None:
@@ -401,7 +448,11 @@ def _moe_ffn_forward(cfg, params, ins, ctx):
             out = out + shared
         return out.reshape(x_in.shape), stats, bias
 
-    out, stats, bias = jax.checkpoint(moe)(x_in, valid, params)
+    kept = plan_bytes(valid.size, cfg.attr("top_k"), held, tile)
+    log_once(cfg.name, "the routing plan kept across the checkpoint (idx, "
+             f"top, place, rows: {kept / 1e6:.1f} MB)")
+    out, stats, bias = jax.checkpoint(moe, policy=_KEEP_THE_PLAN)(
+        x_in, valid, params)
     ctx.extras.setdefault("step_stats", {}).setdefault(
         "moe_ffn", {})[cfg.name] = jax.lax.stop_gradient(stats)
     if bias is not None and ctx.training:

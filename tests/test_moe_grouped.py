@@ -1,6 +1,7 @@
 """`kernels/moe_grouped.py` (the routed experts' products, a chunk of tiles a
 Mosaic launch) pinned to the tile loop of `layers/moe.py` on the CPU, the
-kernels in interpret mode; the gate; the two counters the chunks bring."""
+kernels in interpret mode; the gate; the two counters the chunks bring; the
+routing plan the layer's checkpoint keeps, made once a step."""
 
 import jax
 import jax.numpy as jnp
@@ -236,3 +237,155 @@ def test_a_layer_takes_the_kernels_where_the_gate_passes(monkeypatch):
         _close(got, want, jnp.float32)
     assert np.asarray(stats1[:5]).tolist() == np.asarray(stats0[:5]).tolist()
     assert 0 < stats1[5] < stats1[4]
+
+
+# --- the routing plan kept across the layer's checkpoint ----------------------
+
+# the layer's four forms, as the cells build them: SDAR's, Qwen3-Next's,
+# Kimi-VL's, LFM2's
+FORMS = {
+    "softmax": dict(),
+    "softmax_gated_shared": dict(shared_size=12),
+    "sigmoid_bias_shared_scaled": dict(score="sigmoid", selection_bias=True,
+                                       shared_size=12, shared_gate=False,
+                                       route_scale=2.446),
+    "sigmoid_bias_norm_eps": dict(score="sigmoid", selection_bias=True,
+                                  route_norm_eps=1e-6),
+}
+
+
+def _form_layer(form, dtype=jnp.float32):
+    """(topology, params, the loss's value_and_grad) of a small `moe_ffn` of
+    one form: 8 experts, 4 held from the third, top 3, a padded row; a biased
+    form starts from a bias that changes the choice."""
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(16))
+    out = layer.moe_ffn(input=x, num_experts=8, top_k=3, expert_size=12,
+                        experts_held=4, first_expert=2, tile=8, name="l",
+                        **FORMS[form])
+    topo = Topology(out)
+    params = topo.init_params(jax.random.PRNGKey(0))
+    if "_l.bias" in params:
+        params["_l.bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(5),
+                                                     (8,))
+    params = {k: v if k == "_l.bias" else v.astype(dtype)
+              for k, v in params.items()}
+    xs = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 16)).astype(dtype)
+    mask = jnp.ones((2, 24)).at[1, 19:].set(0)
+
+    def loss(params, xs):
+        outs, ctx = topo.forward(params, {"x": Arg(xs, mask)}, training=True,
+                                 return_ctx=True)
+        return (jnp.sum(outs["l"].value.astype(jnp.float32) ** 2),
+                (ctx.extras["step_stats"],
+                 ctx.extras.get("batch_stats", {})))
+
+    return topo, params, xs, jax.value_and_grad(loss, argnums=(0, 1),
+                                                has_aux=True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_the_kept_plan_gives_the_same_numbers_to_the_bit(form, dtype,
+                                                        monkeypatch):
+    """The loss, every gradient, the step statistics and the new selection
+    bias with the plan kept across the checkpoint are those of a plain
+    `jax.checkpoint`, which makes the plan again in the backward pass."""
+    _, params, xs, vg = _form_layer(form, dtype)
+    kept = jax.jit(vg)(params, xs)
+    monkeypatch.setattr(moe, "_KEEP_THE_PLAN", None)
+    again = jax.jit(vg)(params, xs)
+    leaves = jax.tree_util.tree_leaves(kept)
+    assert len(leaves) == len(jax.tree_util.tree_leaves(again)) >= 7
+    if "selection_bias" in FORMS[form]:
+        assert float(jnp.abs(kept[0][1][1]["l"]["bias"]).max()) > 0
+    for got, want in zip(leaves, jax.tree_util.tree_leaves(again)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _primitives(jaxpr, counts):
+    """Every equation's primitive, sub-jaxprs (loops, calls, the checkpoint
+    and its transpose) walked."""
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    _primitives(sub.jaxpr, counts)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    _primitives(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_the_plan_is_made_once_a_step(form):
+    """In the jaxpr of the layer's value and gradients the plan's top_k, its
+    two cumulative sums and its two scatters appear as often as in the
+    forward pass alone: the backward pass makes none of them again (a plain
+    checkpoint doubles each)."""
+    import jax.extend  # noqa: F401  (jax.extend.core below)
+
+    topo, params, xs, vg = _form_layer(form)
+
+    def forward(params, xs):
+        return topo.forward(params, {"x": Arg(xs, jnp.ones((2, 24)))},
+                            training=True)["l"].value
+
+    once = _primitives(jax.make_jaxpr(forward)(params, xs).jaxpr, {})
+    step = _primitives(jax.make_jaxpr(vg)(params, xs).jaxpr, {})
+    assert (once["top_k"], once["cumsum"], once["scatter"]) == (1, 2, 2)
+    for prim in ("top_k", "cumsum", "scatter", "sort"):
+        assert step.get(prim, 0) == once.get(prim, 0), (prim, step, once)
+
+
+# the four cells' layers: (tokens a step, top k, experts, held, width,
+# expert width, shared width, the form)
+CELL_LAYERS = {
+    "sdar": (32768, 8, 128, 16, 2048, 768, None, "softmax"),
+    "qwen3next": (16384, 10, 512, 16, 2048, 512, 512,
+                  "softmax_gated_shared"),
+    "kimivl": (16384, 6, 64, 8, 2048, 1408, 2816,
+               "sigmoid_bias_shared_scaled"),
+    "lfm2": (16384, 4, 32, 8, 2048, 1792, None, "sigmoid_bias_norm_eps"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_LAYERS))
+def test_the_checkpoint_keeps_the_plan_and_nothing_wide(cell):
+    """What the layer's checkpoint keeps at a cell's own shapes, traced and
+    never run: beside the layer's arguments only the routing plan, the bytes
+    `plan_bytes` counts (what the log says), under 8 MB a layer, and no value
+    with a dimension of the model's, an expert's or the shared expert's width
+    or of the router's scores."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from paddle_tpu.kernels import _pallas_util
+
+    N, k, E, held, d, I, Is, form = CELL_LAYERS[cell]
+    kw = {key: v for key, v in FORMS[form].items() if key != "shared_size"}
+    x = layer.data(name="x", type=data_type.dense_vector_sequence(d))
+    out = layer.moe_ffn(input=x, num_experts=E, top_k=k, expert_size=I,
+                        shared_size=Is, experts_held=held, tile=256,
+                        name="l_" + cell, **kw)
+    topo = Topology(out)
+    params = {name: jax.ShapeDtypeStruct(v.shape, jnp.float32 if
+                                         name.endswith(".bias")
+                                         else jnp.bfloat16)
+              for name, v in jax.eval_shape(topo.init_params,
+                                            jax.random.PRNGKey(0)).items()}
+    xs = jax.ShapeDtypeStruct((2, N // 2, d), jnp.bfloat16)
+
+    def forward(params, xs):
+        return topo.forward(params, {"x": Arg(xs, jnp.ones((2, N // 2)))},
+                            training=True)["l_" + cell].value
+
+    kept = [aval for aval, why in saved_residuals(forward, params, xs)
+            if not why.startswith("from the argument")]
+    size = sum(a.size * a.dtype.itemsize for a in kept)
+    assert size == moe.plan_bytes(N, k, held, 256) < 8e6, kept
+    wide = {d, I, E} | ({Is} if Is else set())
+    assert not [a for a in kept if wide & set(a.shape)], kept
+    line = f"(idx, top, place, rows: {size / 1e6:.1f} MB)"
+    assert any(who == "l_" + cell and line in text
+               for who, text in _pallas_util._LOGGED_DECISIONS)

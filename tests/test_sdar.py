@@ -411,7 +411,12 @@ QWEN_ARGS = dict(vocab_size=50, hidden_size=16, num_hidden_layers=4,
 # them alone: with the five lines of `dispatch_plan` that count and append
 # them taken out, the step lowers to fb70acdc... again (off the TPU the
 # layer takes the tile loop, which PR 35 did not touch)
-QWEN_STEP_SHA256 = "6215b9cd3b557aaa180cfab7bcf69e7ebf5996ef74a8a94666e9b95d078ece1d"
+# It moved again (from 6215b9cd...) when `moe_ffn`'s checkpoint came to keep
+# the routing plan: the checkpoint's policy, the plan's unique places
+# (`dispatch_plan`) and the plan's `checkpoint_name`s, which lower to nothing
+# but renumber the module's private functions; with the three taken out the
+# step lowers to 6215b9cd... again, and this test's SDAR twin to ee60a363...
+QWEN_STEP_SHA256 = "e9054c00576f500cfa5635ff8a08e356afe73df65a53d1ec17502dbeec3c21ce"
 
 
 def test_the_qwen3_next_step_lowers_as_it_did():
@@ -440,8 +445,10 @@ def test_the_qwen3_next_step_lowers_as_it_did():
 # and `flash_attn` changed for Kimi-VL (docs/kimi_vl.md), the SDAR cell's
 # step and launches must not. The step's hash moved at PR 35 (from
 # 7a485160...) for the two entries appended to `moe_ffn`'s step statistics
-# alone, as the Qwen3-Next step's above did, by the same check
-SDAR_STEP_SHA256 = "ee60a363ff944fcad397e88f830f854ef0b50eb33be1a235790f97e3b1f392cf"
+# alone, as the Qwen3-Next step's above did, by the same check, and again
+# (from ee60a363...) for the routing plan kept across `moe_ffn`'s checkpoint,
+# as the Qwen3-Next step's did
+SDAR_STEP_SHA256 = "da73df3694f4d935f65d0691f781bf6e874ac1a922bafd8e5d83dc78d82a9133"
 SDAR_LAUNCHES_SHA256 = "ff794b75612227160d059cd2d1c96ef506f65c0c410a28e0780a56f12f3edc92"
 
 
