@@ -841,15 +841,17 @@ def rms_norm(input, eps=1e-6, zero_centered=True, name=None, param_attr=None,
 
 
 def gated_attention(input, num_heads, num_kv_heads, head_dim, rotary_dim,
-                    rope_theta=10000.0, eps=1e-6, query_block=512, scope=None,
-                    name=None, param_attr=None, layer_attr=None):
+                    rope_theta=10000.0, eps=1e-6, scope=None, name=None,
+                    param_attr=None, layer_attr=None):
     """Causal grouped-query self-attention with per-head q/k RMS norm,
     rotary positions on the first ``rotary_dim`` of each head and a sigmoid
-    output gate; computed ``query_block`` queries at a time."""
+    output gate; the scores tile by tile under the causal rule
+    (kernels/flash_attn.py), a row's forward launch kept for its backward
+    pass."""
     return Layer("gated_attention", [input], name=name, num_heads=num_heads,
                  num_kv_heads=num_kv_heads, head_dim=head_dim,
                  rotary_dim=rotary_dim, rope_theta=rope_theta, eps=eps,
-                 query_block=query_block, scope=scope,
+                 scope=scope,
                  param_attrs=[to_param_attr(param_attr)], extra=layer_attr)
 
 

@@ -144,32 +144,6 @@ def rotary(x, theta, rot):
     return jnp.concatenate([out, xp], -1)
 
 
-def causal_gqa(q, k, v, block):
-    """Causal softmax attention, q [B, T, Hkv, G, D] against k, v
-    [B, T, Hkv, D], one block of ``block`` queries at a time against the
-    keys at or before the block's last query: the scores alive at once are
-    [B, Hkv, G, block, <=T], float32, and every block computes its own
-    again in the backward pass."""
-    B, T, N, G, D = q.shape
-    acc = jnp.promote_types(q.dtype, jnp.float32)
-    scale = D ** -0.5
-
-    @jax.checkpoint
-    def one(qb, kb, vb, start):
-        s = jnp.einsum("bqngd,bknd->bngqk", qb, kb,
-                       preferred_element_type=acc) * scale
-        qpos = start + jnp.arange(qb.shape[1])
-        keep = qpos[:, None] >= jnp.arange(kb.shape[1])[None, :]
-        a = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
-        return jnp.einsum("bngqk,bknd->bqngd", a.astype(vb.dtype), vb)
-
-    outs = []
-    for start in range(0, T, block):
-        end = min(start + block, T)
-        outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
-    return jnp.concatenate(outs, axis=1)
-
-
 def rows_one_at_a_time(mixer, x, params, kept=("gdn_T",)):
     """``mixer(row [T, d], params)`` over the rows of x [B, T, d], one after
     the other, each row's forward computed again in its backward pass: what
@@ -178,8 +152,7 @@ def rows_one_at_a_time(mixer, x, params, kept=("gdn_T",)):
     forward is what the mixer names as ``kept`` says: ``gdn_T``
     (kernels/gdn.py: the delta rule's chunk inverses), ``flash_attn_o`` /
     ``flash_attn_lse`` (kernels/flash_attn.py: what the forward launch
-    made); ``gated_attention`` names nothing, so all of its forward is
-    computed again."""
+    made)."""
     keep = jax.checkpoint_policies.save_only_these_names(*kept)
     return jax.lax.map(
         jax.checkpoint(lambda row: mixer(row, params), policy=keep), x)
@@ -189,8 +162,11 @@ def rows_one_at_a_time(mixer, x, params, kept=("gdn_T",)):
 def _gated_attention_forward(cfg, params, ins, ctx):
     """Causal grouped-query self-attention with per-head q/k RMS norm
     (1 + w), partial rotary positions and a sigmoid output gate read from
-    the query projection. No bias. Padding follows the real tokens of a
-    row, so causality keeps it out of them."""
+    the query projection. No bias. The scores are computed tile by tile
+    under the rule ``("causal", T)`` (kernels/flash_attn.py). Padding
+    follows the real tokens of a row, so causality keeps it out of them."""
+    from paddle_tpu.kernels import flash_attn
+
     enforce(not getattr(ctx, "packed", False),
             f"gated_attention {cfg.name}: packed rows need a segment mask "
             "this layer does not have")
@@ -204,17 +180,24 @@ def _gated_attention_forward(cfg, params, ins, ctx):
         qg = jnp.matmul(x, p["wq"]).reshape(1, T, H, 2 * D)
         q, gate = qg[..., :D], qg[..., D:]
         k = jnp.matmul(x, p["wk"]).reshape(1, T, Hkv, D)
-        v = jnp.matmul(x, p["wv"]).reshape(1, T, Hkv, D)
-        q = rms_normalize(q, eps) * (1 + p["q_norm"]).astype(x.dtype)
+        v = jnp.matmul(x, p["wv"]).reshape(1, T, Hkv * D)
+        # 1 / sqrt(D) rides on the query's norm weight: a power of two at
+        # D = 256, so no rounding moves
+        q = rms_normalize(q, eps) * ((1 + p["q_norm"]) * D ** -0.5).astype(x.dtype)
         k = rms_normalize(k, eps) * (1 + p["k_norm"]).astype(x.dtype)
         q = rotary(q, cfg.attr("rope_theta"), rot)
         k = rotary(k, cfg.attr("rope_theta"), rot)
-        o = causal_gqa(q.reshape(1, T, Hkv, H // Hkv, D), k, v,
-                       cfg.attr("query_block", 512))
-        o = o.reshape(T, H * D) * jax.nn.sigmoid(gate.reshape(T, H * D))
+        o = flash_attn.attention(cfg.name, q.reshape(1, T, H * D),
+                                 k.reshape(1, T, Hkv * D), v, ("causal", T),
+                                 Hkv)
+        o = o[0] * jax.nn.sigmoid(gate.reshape(T, H * D))
         return jnp.matmul(o, p["wo"])
 
-    out = rows_one_at_a_time(mixer, x, params)
+    # a row's backward pass computes its projections, norms, rotary and gate
+    # again from the layer's input; what the kernels' forward launch made of
+    # the row it keeps
+    out = rows_one_at_a_time(mixer, x, params,
+                             kept=("flash_attn_o", "flash_attn_lse"))
     return ins[0].with_value(out)
 
 

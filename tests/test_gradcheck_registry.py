@@ -780,7 +780,7 @@ def _b_rms_norm():
 def _b_gated_attention():
     x = _data_seq("x", 6)
     return (layer.gated_attention(input=x, num_heads=4, num_kv_heads=2,
-                                  head_dim=4, rotary_dim=2, query_block=2),
+                                  head_dim=4, rotary_dim=2),
             {"x": _seq(5, 6, ragged=False)})
 
 

@@ -93,7 +93,7 @@ def _layer_case(name, a, chunk=8):
             input=x, num_heads=a["num_attention_heads"],
             num_kv_heads=a["num_key_value_heads"], head_dim=a["head_dim"],
             rotary_dim=int(a["head_dim"] * a["partial_rotary_factor"]),
-            rope_theta=a["rope_theta"], query_block=8, name="l"), \
+            rope_theta=a["rope_theta"], name="l"), \
             lambda ref, p, row: ref.gated_attention(p, row, a, _ident)
     if name == "gated_delta_net":
         return layer.gated_delta_net(
@@ -140,11 +140,15 @@ def _check_layer_against_the_reference(ref, name, x, chunk=8):
         _close(g[0][k], g_ref[0][k])
 
 
-@pytest.mark.parametrize("name", ["rms_norm", "gated_attention",
-                                  "gated_delta_net", "moe_ffn"])
-def test_layer_matches_the_reference(ref, name):
+# a row of 640 positions is two query tiles of 512 (`flash_attn.tile_sizes`),
+# the second holding 128 positions (the kernels pad it to 512)
+@pytest.mark.parametrize("name,T", [
+    *(pytest.param(n, 21, id=n) for n in ("rms_norm", "gated_attention",
+                                          "gated_delta_net", "moe_ffn")),
+    pytest.param("gated_attention", 640, id="gated_attention-640")])
+def test_layer_matches_the_reference(ref, name, T):
     _check_layer_against_the_reference(
-        ref, name, _normal(77, 2, 21, ARGS["hidden_size"]))
+        ref, name, _normal(77, 2, T, ARGS["hidden_size"]))
 
 
 # ---- the chunked delta rule against the token-by-token form ----------------
